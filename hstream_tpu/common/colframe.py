@@ -4,7 +4,7 @@ The protobuf Append path costs one full ``HStreamRecord`` parse on the
 gRPC boundary plus one re-``SerializeToString()`` per record before the
 bytes reach the store — at columnar batch sizes (megabytes per
 micro-batch) that host staging work, not the engine, bounds the served
-ingest rate (BENCH_r05: kernel 22.6M ev/s, served 1.04M). The framed
+ingest rate. The framed
 path ships the staging layout itself: the client encodes exactly the
 columnar block the encode workers already consume (``HSCB1``: ts vector
 + named fixed-width columns + null masks, ``common/columnar.py``),

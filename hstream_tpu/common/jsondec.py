@@ -39,6 +39,12 @@ CLS_RAW = 1    # RAW-flagged record: route by payload magic in Python
 CLS_PY = 2     # Python fallback (nested values, type conflicts, bad bytes)
 
 
+def build(force: bool = False) -> str:
+    """Compile engine/cpp/jsondec.cpp -> libjsondec.so if stale (or
+    always, with `force`); returns the .so path. Raises when it cannot."""
+    return build_so(SRC, SO, opt="-O3", force=force)
+
+
 def load() -> C.CDLL | None:
     global _lib, _tried
     with _lock:
@@ -46,7 +52,7 @@ def load() -> C.CDLL | None:
             return _lib
         _tried = True
         try:
-            lib = C.CDLL(build_so(SRC, SO, opt="-O3"))
+            lib = C.CDLL(build())
         except Exception:
             return None
         lib.jd_scan.argtypes = [_p_u8, _p_i64, C.c_int64, _p_i64,

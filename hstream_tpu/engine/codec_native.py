@@ -29,6 +29,12 @@ _p_u8 = C.POINTER(C.c_uint8)
 _p_f32 = C.POINTER(C.c_float)
 
 
+def build(force: bool = False) -> str:
+    """Compile cpp/encode.cpp -> cpp/libencode.so if stale (or always,
+    with `force`); returns the .so path. Raises when it cannot."""
+    return build_so(SRC, SO, opt="-O3", force=force)
+
+
 def load() -> C.CDLL | None:
     """The native codec library, built on first use; None if unbuildable."""
     global _lib, _tried
@@ -37,7 +43,7 @@ def load() -> C.CDLL | None:
             return _lib
         _tried = True
         try:
-            lib = C.CDLL(build_so(SRC, SO, opt="-O3"))
+            lib = C.CDLL(build())
         except Exception:
             return None
         lib.enc_pack_i64.argtypes = [_p_i64, _i64, _i64, C.c_int,

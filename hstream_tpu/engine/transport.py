@@ -1,9 +1,7 @@
 """Bit-packed columnar host->device transport (v3).
 
-The ingest wall on real deployments is the host->device link: every byte
-of a record batch crosses PCIe (or, on tunneled dev chips, a far slower
-link), so wire bytes per event — not host CPU and not device FLOPs — set
-the throughput ceiling. This module is the engine's answer: a
+Every byte of a record batch crosses the host->device link (PCIe), so
+the engine keeps wire bytes per event low. This module is how: a
 Parquet-style adaptive columnar codec that encodes each micro-batch into
 ONE uint32 buffer, decoded on-device inside the jitted step (shifts and
 masks on the VPU, fused into the aggregation kernel by XLA).

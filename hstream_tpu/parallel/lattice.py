@@ -56,21 +56,6 @@ from hstream_tpu.engine.lattice import (
     plane_merge_kinds,
 )
 
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across jax versions: new enough builds export it
-    top-level (`check_vma`); older ones ship the same transform as
-    jax.experimental.shard_map (`check_rep`). One wrapper keeps every
-    sharded kernel importable — and testable on the CPU mesh — on both."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
 _MERGE = {
     "sum": jax.lax.psum,
     "min": jax.lax.pmin,
@@ -184,7 +169,7 @@ class ShardedLattice:
             return {k: v[None] for k, v in new.items()}
 
         # packed batch [rows, B]: rows replicated, records sharded on data
-        self.step = jax.jit(shard_map(
+        self.step = jax.jit(jax.shard_map(
             step_local, mesh=mesh,
             in_specs=(spec_tree, P(), P(None, data_axis)),
             out_specs=spec_tree, check_vma=False))
@@ -206,7 +191,7 @@ class ShardedLattice:
                                              ws, outs)
 
         # packed [2+n_aggs, K] — key axis concatenated over shards
-        self.extract_slot = jax.jit(shard_map(
+        self.extract_slot = jax.jit(jax.shard_map(
             extract_local, mesh=mesh,
             in_specs=(spec_tree, P()),
             out_specs=P(None, key_axis), check_vma=False))
@@ -227,7 +212,7 @@ class ShardedLattice:
                 EMPTY_START)
             return out
 
-        self.reset_slot = jax.jit(shard_map(
+        self.reset_slot = jax.jit(jax.shard_map(
             reset_local, mesh=mesh,
             in_specs=(spec_tree, P()),
             out_specs=spec_tree, check_vma=False))
@@ -276,18 +261,18 @@ class ShardedLattice:
             packed = _extract_slots_local(state, slots)
             return _reset_slots_local(state, slots), packed
 
-        self.extract_reset_slots = jax.jit(shard_map(
+        self.extract_reset_slots = jax.jit(jax.shard_map(
             extract_reset_local, mesh=mesh,
             in_specs=(spec_tree, P()),
             out_specs=(spec_tree, P(None, None, key_axis)),
             check_vma=False))
 
-        self.extract_slots = jax.jit(shard_map(
+        self.extract_slots = jax.jit(jax.shard_map(
             _extract_slots_local, mesh=mesh,
             in_specs=(spec_tree, P()),
             out_specs=P(None, None, key_axis), check_vma=False))
 
-        self.reset_slots = jax.jit(shard_map(
+        self.reset_slots = jax.jit(jax.shard_map(
             _reset_slots_local, mesh=mesh,
             in_specs=(spec_tree, P()),
             out_specs=spec_tree, check_vma=False))
@@ -320,7 +305,7 @@ class ShardedLattice:
             return out_state, packed[None]
 
         # packed per-key-shard buffers stacked on a leading axis
-        self.extract_touched = jax.jit(shard_map(
+        self.extract_touched = jax.jit(jax.shard_map(
             touched_local, mesh=mesh,
             in_specs=(spec_tree,),
             out_specs=(spec_tree, P(key_axis)), check_vma=False))
@@ -414,7 +399,7 @@ class ShardedJoinLattice:
         # match buffers concatenate along the COLUMN axis: global
         # [rows, n_shards * match_cap], per-shard headers at column
         # s * match_cap
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             probe_insert_local, mesh=mesh,
             in_specs=(store_spec, store_spec, P(), P(), P(), P()),
             out_specs=(store_spec, P(None, key_axis)),
@@ -433,7 +418,7 @@ class ShardedJoinLattice:
                                        bcap, match_cap, nm,
                                        owned=owned)
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             probe_only_local, mesh=mesh,
             in_specs=(store_spec, P(), P(), P(), P()),
             out_specs=P(None, key_axis), check_vma=False))
@@ -461,7 +446,7 @@ class ShardedJoinLattice:
                 ns.append(n)
             return outs[0], outs[1], jnp.stack(ns)[None]
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             evict_local, mesh=mesh,
             in_specs=(store_spec, store_spec, P(), P()),
             out_specs=(store_spec, store_spec, P(key_axis)),
@@ -520,7 +505,7 @@ class ShardedJoinLattice:
                     {k: v[None] for k, v in new_inner.items()},
                     total[None])
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             join_step_local, mesh=mesh,
             in_specs=(store_spec, store_spec, P(), P(), P(), P(),
                       spec_tree, P(), P()),
@@ -685,7 +670,7 @@ class ShardedSessionLattice:
             new = base(loc, routed, gap, close_cut, delta)
             return {k: v[None] for k, v in new.items()}
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             session_step_local, mesh=mesh,
             in_specs=(aspec, P(), P(), P(), P()),
             out_specs=aspec, check_vma=False))
@@ -708,7 +693,7 @@ class ShardedSessionLattice:
             new = base(loc, s2, gap, close_cut, delta)
             return {k: v[None] for k, v in new.items()}
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             session_merge_local, mesh=mesh,
             in_specs=(aspec, seg_spec, P(), P(), P()),
             out_specs=aspec, check_vma=False))
@@ -722,7 +707,7 @@ class ShardedSessionLattice:
             loc = {k: v[0] for k, v in arena.items()}
             return base(loc, slots[0])[None]
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             session_extract_local, mesh=mesh,
             in_specs=(aspec, P(key_axis)),
             out_specs=P(key_axis), check_vma=False))
@@ -737,7 +722,7 @@ class ShardedSessionLattice:
             new = base(loc, lut)
             return {k: v[None] for k, v in new.items()}
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             session_remap_local, mesh=mesh,
             in_specs=(aspec, P()),
             out_specs=aspec, check_vma=False))

@@ -10,7 +10,9 @@ subscription registry and the running-task maps.
 from __future__ import annotations
 
 import threading
+import time
 
+from hstream_tpu.common.logger import get_logger
 from hstream_tpu.server.persistence import (
     MemPersistence,
     Persistence,
@@ -29,6 +31,11 @@ DEFAULT_ENCODE_WORKERS = 2
 # append-front lanes behind the framed columnar append path (ignored
 # on stores with their own completion queue — see server/appendfront)
 DEFAULT_APPEND_LANES = 2
+# how long shutdown() waits for stopped tasks' final snapshots before
+# it gives up on them (and then leaves an owned store open)
+FINAL_SNAPSHOT_WAIT_S = 600.0
+
+log = get_logger("context")
 
 
 class ServerContext:
@@ -325,13 +332,21 @@ class ServerContext:
                 httpd.server_close()  # release the listening socket
             except Exception:
                 pass
-        for task in list(self.running_queries.values()):
+        tasks = list(self.running_queries.values())
+        for task in tasks:
             try:
                 # detach: snapshot state but leave status RUNNING so the
-                # next boot's resume_persisted relaunches the query
-                task.stop(detach=True)
+                # next boot's resume_persisted relaunches the query.
+                # Signal every task before waiting on any, so their
+                # final snapshots overlap.
+                if getattr(task, "packed", False):
+                    task.stop(detach=True)  # no thread, no snapshot
+                else:
+                    task.stop(0.0, detach=True)
             except Exception:
                 pass
+        abandoned = self._await_final_snapshots(
+            [t for t in tasks if not getattr(t, "packed", False)])
         for task in list(self.running_connectors.values()):
             try:
                 task.stop()
@@ -345,5 +360,35 @@ class ServerContext:
             # worker mid-append against a closed store would fail an
             # acknowledged-in-flight batch
             front.close()
-        if self.owns_store:
+        if self.owns_store and not abandoned:
             self.store.close()
+
+    def _await_final_snapshots(self, tasks: list) -> list[str]:
+        """Wait until no stopped task can still write to the store: a
+        final snapshot is a device fetch plus a store write of the
+        whole operator state, seconds to minutes at a real key count,
+        and closing the store under it fails the write and aborts the
+        process at interpreter exit. Returns the ids of tasks still
+        writing after FINAL_SNAPSHOT_WAIT_S; their snapshot is
+        knowingly abandoned (resume replays from the last periodic
+        one) and the caller must leave the store open."""
+        deadline = time.monotonic() + FINAL_SNAPSHOT_WAIT_S
+        abandoned = []
+        for task in tasks:
+            qid = task.info.query_id
+            while not task.wait_written(
+                    min(5.0, max(0.0, deadline - time.monotonic()))):
+                if time.monotonic() >= deadline:
+                    abandoned.append(qid)
+                    break
+                log.info("shutdown: query %s is still writing its "
+                         "final snapshot", qid)
+        if abandoned:
+            log.error("shutdown: final snapshot of %s not finished "
+                      "after %.0fs; abandoning it and leaving the store "
+                      "open", abandoned, FINAL_SNAPSHOT_WAIT_S)
+            self.events.append(
+                "final_snapshot_abandoned",
+                f"shutdown gave up on the final snapshot of "
+                f"{', '.join(abandoned)}", queries=abandoned)
+        return abandoned

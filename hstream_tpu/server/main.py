@@ -162,8 +162,12 @@ def serve(host: str = "127.0.0.1", port: int = 6570,
                                            port=metrics_port)
         log.info("metrics exporter on %s:%d (/metrics, /events)",
                  host, ctx.metrics_httpd.server_port)
-    log.info("hstream-tpu server listening on %s:%d (store %s)",
-             host, bound, store_uri)
+    from hstream_tpu.common.jaxenv import device_summary
+
+    dev = device_summary()
+    log.info("hstream-tpu server listening on %s:%d (store %s) on "
+             "%s %s x%d", host, bound, store_uri, dev["platform"],
+             dev["kind"], dev["count"])
     return server, ctx
 
 
@@ -356,6 +360,10 @@ def main(argv=None) -> None:
             raise SystemExit(f"invalid log_level {cfg['log_level']!r}")
         # project logs ride the non-propagating 'hstream_tpu' logger
         logging.getLogger("hstream_tpu").setLevel(level)
+    from hstream_tpu.common.jaxenv import place_compile_cache
+
+    # here, not in serve(): in-process test servers write no cache
+    log.info("compile cache at %s", place_compile_cache())
     server, ctx = serve(
         cfg["host"], cfg["port"], cfg["store"],
         max_workers=cfg["workers"], mesh_shape=cfg["mesh"],

@@ -426,7 +426,12 @@ class QueryTask(threading.Thread):
         RUNNING so boot-time resume_persisted relaunches the query.
         crash=True — fault injection (tests): no snapshot, no status
         update, like a killed process; resume replays from the last
-        periodic snapshot."""
+        periodic snapshot.
+
+        Returns after at most `timeout` seconds whether or not the
+        thread has finished — the final snapshot of a large state takes
+        longer. A caller about to close the store must wait until
+        `wait_written()` is true."""
         if crash:
             self._crash = True
         if detach:
@@ -434,6 +439,19 @@ class QueryTask(threading.Thread):
         self._stop_ev.set()
         if self.is_alive():
             self.join(timeout)
+
+    def wait_written(self, timeout: float) -> bool:
+        """Wait up to `timeout` seconds for the task thread and its
+        snapshot persist worker to end; True once neither is alive, so
+        nothing of this task can be in the middle of a store write."""
+        deadline = time.monotonic() + timeout
+        self.join(max(0.0, timeout))
+        with self._persist_cv:
+            worker = self._persist_thread
+        if worker is not None:
+            worker.join(max(0.0, deadline - time.monotonic()))
+        return not self.is_alive() and (worker is None
+                                        or not worker.is_alive())
 
     def run(self) -> None:
         ctx = self.ctx
@@ -533,7 +551,7 @@ class QueryTask(threading.Thread):
             with self._persist_cv:
                 self._persist_stop = True
                 self._persist_cv.notify_all()
-            t = self._persist_thread
+                t = self._persist_thread
             if t is not None:
                 # reap the persist worker HERE, not at interpreter
                 # teardown: a daemon thread caught mid device fetch

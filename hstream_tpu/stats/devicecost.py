@@ -17,8 +17,9 @@ this module makes the device a first-class subsystem of /metrics:
   `kernel_family` scope — jit compiles synchronously inside the
   triggering call), shape key (crc32 of the MLIR module text), compile
   milliseconds, and `cost_analysis()` flops / bytes-accessed when the
-  backend reports them. The wrapper degrades to a no-op if the private
-  seam moves; the recompile *counters* (PR 12) keep working either way.
+  backend reports them. The seam is private: if an installed JAX moves
+  it, `install()` raises at server boot rather than leave an empty
+  inventory.
 
 * **Per-dispatch device time** — `DEVICE_TIME` is the deterministic
   1/N sampler `common.tracing.kernel_family` consults: on a sampled
@@ -140,25 +141,18 @@ class ProgramInventory:
         self._rows: "OrderedDict[str, dict]" = OrderedDict()
         self._lock = threading.Lock()
         self._installed = False
-        self._install_failed = False
         self.evicted = 0
 
-    def install(self) -> bool:
-        """Wrap the compile funnel once (idempotent). Returns False and
-        leaves the inventory empty-but-harmless if the private seam is
-        absent in this jax build."""
+    def install(self) -> None:
+        """Wrap the compile funnel once (idempotent). The seam is the
+        private `jax._src.compiler.compile_or_get_cached`; a JAX that
+        lacks it is an error at install, not an empty inventory."""
         with self._lock:
             if self._installed:
-                return True
-            if self._install_failed:
-                return False
-            try:
-                from jax._src import compiler as _compiler
+                return
+            from jax._src import compiler as _compiler
 
-                orig = _compiler.compile_or_get_cached
-            except Exception:  # noqa: BLE001 — private seam moved:
-                self._install_failed = True    # degrade, don't break
-                return False
+            orig = _compiler.compile_or_get_cached
             inv = self
 
             def _record_and_compile(*args, **kwargs):
@@ -173,7 +167,6 @@ class ProgramInventory:
 
             _compiler.compile_or_get_cached = _record_and_compile
             self._installed = True
-            return True
 
     def _record(self, exe, compile_ms: float, args) -> None:
         from hstream_tpu.common.tracing import current_kernel_family

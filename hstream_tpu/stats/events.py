@@ -68,6 +68,9 @@ EVENT_KINDS = [
                            # crash-loop breaker opening) — the pointer
                            # an operator follows to GET
                            # /queries/<id>/flightrec
+    "final_snapshot_abandoned",  # shutdown stopped waiting for a
+                                 # query's final snapshot and left the
+                                 # store open under it
 ]
 
 

@@ -418,13 +418,21 @@ struct Store {
     fs::path p = root / "meta.wal";
     FILE* f = std::fopen(p.c_str(), "rb");
     if (f) {
+      std::error_code ec;
+      uint64_t size = fs::file_size(p, ec);
+      if (ec) size = 0;
       while (true) {
         uint8_t op;
         uint32_t klen, vlen;
         if (std::fread(&op, 1, 1, f) != 1) break;
         if (std::fread(&klen, 4, 1, f) != 1) break;
         if (std::fread(&vlen, 4, 1, f) != 1) break;
-        if (klen > (64u << 20) || vlen > (64u << 20)) break;  // corrupt
+        // a record cannot be longer than what is left of the file: a
+        // torn tail or a garbage length stops the replay here. (A
+        // fixed 64 MiB cap used to stand in for this check, and cut
+        // the replay at the first operator-state snapshot above it —
+        // dropping that snapshot and every key written after it.)
+        if ((uint64_t)klen + vlen > size - (meta_wal_bytes + 9)) break;
         std::string k(klen, '\0'), v(vlen, '\0');
         if (klen && std::fread(&k[0], 1, klen, f) != klen) break;
         if (vlen && std::fread(&v[0], 1, vlen, f) != vlen) break;

@@ -334,3 +334,86 @@ def test_kill_restart_server_native(tmp_path):
         channel.close()
         server.stop(grace=1)
         ctx.shutdown()
+
+
+# ---- shutdown vs a slow final snapshot (ISSUE 21) ---------------------------
+
+
+def _view_with_unsnapshotted_state(store_dir, name):
+    """A native-store server whose view holds processed rows that no
+    periodic snapshot has captured (cadence far in the future), so the
+    final snapshot at shutdown is the only thing that can persist them."""
+    server, ctx = serve("127.0.0.1", 0, store_dir,
+                        snapshot_interval_ms=1 << 30)
+    stub, channel = _stub_for((server, ctx))
+    stub.CreateStream(pb.Stream(stream_name=f"{name}s"))
+    stub.ExecuteQuery(pb.CommandQuery(
+        stmt_text=f"CREATE VIEW {name} AS SELECT city, COUNT(*) AS c "
+                  f"FROM {name}s GROUP BY city, "
+                  "TUMBLING (INTERVAL 10 SECOND) "
+                  "GRACE BY INTERVAL 0 SECOND;"))
+    task = wait_attached(ctx, f"view-{name}")
+    append_rows(stub, f"{name}s", [{"city": "sf"}, {"city": "sf"}],
+                [BASE, BASE + 10])
+    _poll_view(stub, name, lambda rs: any(r.get("c") == 2 for r in rs))
+    channel.close()
+    server.stop(grace=1)
+    return ctx, task
+
+
+def test_shutdown_waits_out_a_slow_final_snapshot(tmp_path):
+    """The final snapshot is held (a FAULTS delay at the persist site)
+    well past the moment shutdown() has signalled the task. shutdown()
+    must not close the owned store under it: the snapshot lands, no
+    StoreError, the task ends clean."""
+    from hstream_tpu.common.faultinject import FAULTS
+    from hstream_tpu.store import open_store
+
+    store_dir = str(tmp_path / "store")
+    ctx, task = _view_with_unsnapshotted_state(store_dir, "slowv")
+    qid = "view-slowv"
+    assert ctx.store.meta_get(snapshot_key(qid)) is None
+    FAULTS.arm("snapshot.persist", "delay:1500")
+    try:
+        t0 = time.monotonic()
+        ctx.shutdown()
+        held = time.monotonic() - t0
+    finally:
+        FAULTS.disarm()
+    assert held >= 1.4, f"shutdown did not wait for the snapshot ({held})"
+    assert not task.is_alive() and task.error is None, task.error
+    assert not ctx.events.query(kind="final_snapshot_abandoned")
+    re = open_store(store_dir)  # the store was closed cleanly
+    try:
+        assert re.meta_get(snapshot_key(qid)) is not None
+    finally:
+        re.close()
+
+
+def test_shutdown_abandons_a_final_snapshot_past_its_deadline(
+        tmp_path, monkeypatch):
+    """Held past shutdown's own deadline, the final snapshot is
+    knowingly abandoned: shutdown() returns, journals it, and leaves
+    the store OPEN so the straggling write still succeeds instead of
+    failing against a closed store (and aborting the process at
+    interpreter exit)."""
+    from hstream_tpu.common.faultinject import FAULTS
+    from hstream_tpu.server import context as context_mod
+
+    store_dir = str(tmp_path / "store")
+    ctx, task = _view_with_unsnapshotted_state(store_dir, "lostv")
+    qid = "view-lostv"
+    monkeypatch.setattr(context_mod, "FINAL_SNAPSHOT_WAIT_S", 0.3)
+    FAULTS.arm("snapshot.persist", "delay:1500")
+    try:
+        ctx.shutdown()
+        assert task.is_alive()  # still inside its held snapshot
+        ev = ctx.events.query(kind="final_snapshot_abandoned")
+        assert len(ev) == 1 and qid in ev[0]["message"]
+        assert task.wait_written(30)
+    finally:
+        FAULTS.disarm()
+    # the write went to the still-open store: no error, snapshot there
+    assert task.error is None, task.error
+    assert ctx.store.meta_get(snapshot_key(qid)) is not None
+    ctx.store.close()

@@ -157,7 +157,8 @@ def test_program_inventory_one_row_per_shape_key():
     from hstream_tpu.common.tracing import RetraceGuard, kernel_family
     from hstream_tpu.stats.devicecost import PROGRAMS
 
-    assert PROGRAMS.install(), "compile funnel seam absent"
+    PROGRAMS.install()  # raises if the compile funnel seam is absent
+    assert PROGRAMS.summary()["installed"]
     fn = jax.jit(lambda x: x * 2.0 + 1.0)
     # build inputs OUTSIDE the guarded regions: the ones-fill is its
     # own (cached) compile and must not pollute the counts

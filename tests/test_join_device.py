@@ -1,8 +1,7 @@
 """Device-resident interval join: equivalence against the retained
 host reference path (_FlatIntervalStore batch probing), dispatch/fetch
 contracts (join_stats), epoch rebase, store growth, match-buffer
-overflow redo, columnar changelog decode, and the key-sharded mirror
-(skip-guarded where jax.shard_map is absent, like test_close_batched).
+overflow redo, columnar changelog decode, and the key-sharded mirror.
 
 The host path IS the reference: every scenario runs twice — once with
 `use_device_join=False` (host), once on the device path — and the
@@ -486,19 +485,6 @@ def test_join_projection_stays_columnar():
 # ---- sharded mirror --------------------------------------------------------
 
 
-def _has_shard_map() -> bool:
-    # the parallel package shims jax.shard_map across jax versions
-    # (jax.experimental.shard_map on older builds), so the gate only
-    # needs the shim to import — not a top-level jax.shard_map
-    try:
-        from hstream_tpu.parallel.lattice import shard_map  # noqa: F401
-    except Exception:  # noqa: BLE001 — no usable shard_map transform
-        return False
-    return True
-
-
-@pytest.mark.skipif(not _has_shard_map(),
-                    reason="jax.shard_map unavailable in this jax")
 def test_sharded_join_kernels_match_single_chip():
     """Key-sharded probe/insert/evict vs the single-chip kernels: same
     batches, same matches (order within the concat may differ by

@@ -410,8 +410,12 @@ def test_pull_query_cached_end_to_end(server_stub):
         resp = stub.ExecuteQuery(pb.CommandQuery(
             stmt_text="SELECT * FROM rpview;"))
         rows = [rec.struct_to_dict(s) for s in resp.result_set]
+        # the closer lands in two engine steps (the gap guard closes
+        # BASE's window, then the closer's own row goes live): wait
+        # for both, or the quiesce below can settle between them
         if any(r.get("winStart") == BASE and r.get("city") == "la"
-               and r.get("c") == 2 for r in rows):
+               and r.get("c") == 2 for r in rows) \
+                and any(r.get("city") == "zz" for r in rows):
             break
         time.sleep(0.2)
     closed = {r["city"]: r["c"] for r in rows

@@ -152,6 +152,25 @@ def test_meta_wal_compaction_replay(tmp_path):
     re.close()
 
 
+def test_meta_value_over_64mib_survives_reopen(tmp_path):
+    """An operator-state snapshot at a real key count is one meta
+    value of hundreds of MB (an HLL plane alone is 1 KiB per key and
+    window slot). The replay used to treat any value over 64 MiB as
+    corruption and stop there — losing that snapshot AND every key the
+    compacted WAL orders after it."""
+    root = str(tmp_path / "st")
+    store = NativeLogStore(root)
+    big = os.urandom(1 << 20) * 65  # 65 MiB
+    store.meta_put("qsnap/q@0", big)
+    store.meta_put("streams/after", b"still here")
+    store.close()
+
+    re = NativeLogStore(root)
+    assert re.meta_get("qsnap/q@0") == big
+    assert re.meta_get("streams/after") == b"still here"
+    re.close()
+
+
 def test_async_append_concurrent_first_use(tmp_path):
     """Many threads racing the FIRST append_async must share one
     appender (pre-fix: unlocked lazy init could build two appenders with
