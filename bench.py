@@ -1131,14 +1131,6 @@ def main() -> None:
     force(ex)
     pipe.reset_stats()  # stage occupancies cover the timed region only
 
-    import contextlib
-    import os
-
-    from hstream_tpu.common.tracing import jax_profiler
-
-    profile_dir = os.environ.get("HSTREAM_PROFILE_DIR")
-    prof = (jax_profiler(profile_dir) if profile_dir
-            else contextlib.nullcontext())
     # 3 sustained runs; the timed region includes the host->device
     # uploads. The headline is EXPLICITLY the best run ("methodology"
     # field); every run and the median are reported so cross-round
@@ -1150,26 +1142,25 @@ def main() -> None:
     emitted_rows = 0
     events = MEASURE_BATCHES * BATCH
     budget_t0 = time.perf_counter()
-    with prof:  # HSTREAM_PROFILE_DIR=... captures a TensorBoard trace
-        for _run in range(3):
-            if runs and time.perf_counter() - budget_t0 > 240:
-                # stop re-running so the whole bench stays inside the
-                # driver's time budget
-                print(f"# headline budget hit after {len(runs)} run(s)",
-                      flush=True)
-                break
-            guard = RetraceGuard()
-            t_start = time.perf_counter()
-            with guard:
-                for _ in range(MEASURE_BATCHES):
-                    kids, ts, cols = src.next()
-                    pipe.submit(kids, ts, cols)
-                pipe.flush()
-                emitted_rows += len(ex.drain_closed())
-                force(ex)  # all dispatched work in timed region
-            dt = time.perf_counter() - t_start
-            runs.append((events / dt, dt))
-            run_recompiles.append(guard.count)
+    for _run in range(3):
+        if runs and time.perf_counter() - budget_t0 > 240:
+            # stop re-running so the whole bench stays inside the
+            # driver's time budget
+            print(f"# headline budget hit after {len(runs)} run(s)",
+                  flush=True)
+            break
+        guard = RetraceGuard()
+        t_start = time.perf_counter()
+        with guard:
+            for _ in range(MEASURE_BATCHES):
+                kids, ts, cols = src.next()
+                pipe.submit(kids, ts, cols)
+            pipe.flush()
+            emitted_rows += len(ex.drain_closed())
+            force(ex)  # all dispatched work in timed region
+        dt = time.perf_counter() - t_start
+        runs.append((events / dt, dt))
+        run_recompiles.append(guard.count)
     eps, elapsed = max(runs)  # best run, with ITS measured wall time
     # per-stage pipeline occupancy over the timed region: encode (host
     # wire pack, summed over workers), upload wait (H2D double-buffer

@@ -506,7 +506,14 @@ def main(argv=None) -> int:
                    help="per-peer fan-out timeout (seconds)")
     p.add_argument("--json", action="store_true",
                    help="raw per-node reports instead of the table")
-    p = sub.add_parser("trace")
+    p = sub.add_parser(
+        "trace",
+        help="per-stage timings of a running query: count, total, "
+             "mean, p50, p95 and lifetime max in ms. Task thread: "
+             "read_wait decode state_wait key_encode step (ring_wait "
+             "stage_wait close (close_fetch close_decode)) emit "
+             "snapshot; workers: encode store_read; pulls: "
+             "pull_state_wait pull_hold pull_serve")
     p.add_argument("id", help="running query id (e.g. view-<name>)")
     p.add_argument("--spans", action="store_true",
                    help="print the query's span ring as Chrome "

@@ -8,10 +8,17 @@ always-on: one perf_counter pair per stage, no allocation beyond the
 ring slot.
 
 `trace_span(tracer, stage)` is the instrumentation point;
-`QueryTracer.summary()` aggregates count/total/mean/p50/p95 per stage
-for the admin surface (admin CLI `trace` command, HTTP /queries/<id>).
-`jax_profiler(path)` wraps jax.profiler.trace for deep device profiles
-(TensorBoard format) when an operator asks for one.
+`QueryTracer.summary()` aggregates count/total/mean/p50/p95/max per
+stage for the admin surface (admin CLI `trace` command, HTTP
+/queries/<id>).
+
+One host timeline (ISSUE 25): every span also holds a
+`jax.profiler.TraceAnnotation` (a TraceMe) open for its life, named by
+the stage (`dispatch:<family>` for a kernel family). It is inert unless
+a profiler session is live; when one is, the span lands on its own
+thread's line of the trace's host plane, on the profiler's clock — the
+device planes' clock. The program keeps no clock and no ring of its
+own for this. `name_os_thread()` gives that line its thread's name.
 
 ISSUE 13 grows the request-id correlation into cross-component trace
 spans: `SpanCollector` keeps bounded per-scope rings of completed spans
@@ -54,6 +61,7 @@ class QueryTracer:
             lambda: deque(maxlen=capacity))
         self._counts: dict[str, int] = defaultdict(int)
         self._totals: dict[str, float] = defaultdict(float)
+        self._maxes: dict[str, float] = defaultdict(float)
         self._lock = threading.Lock()
         self._observer = observer
         self.request_id: str | None = None
@@ -82,6 +90,8 @@ class QueryTracer:
             self._rings[stage].append(seconds)
             self._counts[stage] += 1
             self._totals[stage] += seconds
+            if seconds > self._maxes[stage]:
+                self._maxes[stage] = seconds
         if self._observer is not None:
             try:
                 self._observer(stage, seconds)
@@ -91,17 +101,20 @@ class QueryTracer:
         if spans is not None:
             try:
                 dur_ms = seconds * 1e3
+                parent = TRACE_PARENT.get(stage)
                 spans.record_span(
                     self._span_scope, stage,
                     trace_id=self._trace_id, span_id=new_span_id(),
                     parent_id=self._parent_span,
-                    t0_ms=time.time() * 1e3 - dur_ms, dur_ms=dur_ms)
+                    t0_ms=time.time() * 1e3 - dur_ms, dur_ms=dur_ms,
+                    **({"parent_stage": parent} if parent else {}))
             except Exception:  # noqa: BLE001 — span plumbing must
                 pass           # never fail the traced stage
 
     def summary(self) -> dict[str, dict[str, float]]:
-        """stage -> {count, total_ms, mean_ms, p50_ms, p95_ms} over the
-        ring (percentiles) and lifetime (count/total)."""
+        """stage -> {count, total_ms, mean_ms, p50_ms, p95_ms, max_ms}
+        over the ring (percentiles) and lifetime (count/total/max: one
+        4.5 s stall among a million 40 ms batches still shows)."""
         out: dict[str, dict[str, float]] = {}
         with self._lock:
             for stage, ring in self._rings.items():
@@ -118,23 +131,89 @@ class QueryTracer:
                     "p50_ms": round(xs[n // 2] * 1e3, 3),
                     "p95_ms": round(xs[min(n - 1, (n * 95) // 100)] * 1e3,
                                     3),
+                    "max_ms": round(self._maxes[stage] * 1e3, 3),
                 }
         if self.request_id:
             out["request"] = {"id": self.request_id}
         return out
 
 
+_TraceMe = None  # jax.profiler.TraceAnnotation, imported at first use
+
+
+def _annotation(name: str):
+    """A profiler annotation named `name`: a TraceMe, inert (under half
+    a microsecond) unless a profiler session is live. Imported lazily
+    so the SQL client, which shares this module's header keys, does
+    not pay JAX's import."""
+    global _TraceMe
+    if _TraceMe is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceMe = TraceAnnotation
+    return _TraceMe(name)
+
+
+def name_os_thread() -> None:
+    """Give the calling thread's OS thread its Python name (cut to the
+    kernel's 15 bytes). The profiler's host plane, `top -H` and perf
+    name a thread's line by it; CPython sets it only from 3.14 on, so
+    every thread that records spans calls this first. Linux only; a
+    no-op where `/proc/thread-self/comm` is not there."""
+    try:
+        with open("/proc/thread-self/comm", "w") as f:
+            f.write(threading.current_thread().name[:15])
+    except OSError:
+        pass
+
+
 @contextlib.contextmanager
 def trace_span(tracer: QueryTracer | None, stage: str):
-    """Time a stage into the tracer; no-op when tracer is None."""
+    """Time a stage into the tracer, under a profiler annotation of the
+    same name; no-op when tracer is None."""
     if tracer is None:
         yield
         return
     t0 = time.perf_counter()
     try:
-        yield
+        with _annotation(stage):
+            yield
     finally:
         tracer.record(stage, time.perf_counter() - t0)
+
+
+class begin_span:  # noqa: N801 — reads as the verb it is, like trace_span
+    """A span ended by hand: for a wait that ends INSIDE the `with` of
+    the lock it waited for, where no block can hold it —
+
+        wait = begin_span(self.tracer, "state_wait")
+        with self.state_lock:
+            wait.end()
+
+    so the wait is named at the call site by who waited (the lock's own
+    `lock_wait_ms` cannot tell the task from a pull) and the analyzer
+    still sees a plain `with self.<lock>`. `end()` runs on the thread
+    that began the span; a second call is a no-op."""
+
+    __slots__ = ("_tracer", "_stage", "_t0", "_ann")
+
+    def __init__(self, tracer: QueryTracer | None, stage: str):
+        self._tracer = tracer
+        if tracer is None:
+            return
+        self._stage = stage
+        self._ann = _annotation(stage)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+
+    def end(self) -> None:
+        tracer = self._tracer
+        if tracer is None:
+            return
+        self._tracer = None
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(None, None, None)
+        tracer.record(self._stage, dt)
 
 
 # ---- cross-component trace spans (ISSUE 13) --------------------------------
@@ -152,6 +231,16 @@ PARENT_SPAN_KEY = "x-parent-span"
 TRACE_STAGES = frozenset({
     # query-task pipeline stages (QueryTracer rings + stage_latency_ms)
     "decode", "key_encode", "step", "emit", "snapshot", "close",
+    # the waits of the task thread and the halves of a close cycle
+    # (ISSUE 25): observed once per batch whether or not anything
+    # waited, so a label that is absent means a renamed stage
+    "read_wait", "state_wait", "ring_wait", "stage_wait",
+    "close_fetch", "close_decode",
+    # the task's helper threads: encode workers, store prefetch
+    "encode", "store_read",
+    # a pull, on its gRPC thread: asking for tasks.state -> holding it
+    # -> released, then filter/project/sort outside the lock
+    "pull_state_wait", "pull_hold", "pull_serve",
     # framed-append stages (handlers.APPEND_STAGES)
     "append_decode", "append_admit", "append_handoff", "append_store",
     # RPC entry span + the freshness lag taxonomy (freshness_lag_ms
@@ -159,9 +248,21 @@ TRACE_STAGES = frozenset({
     "rpc", "ingest", "engine", "delivery",
 })
 
+# The stage a nested stage runs inside, on the same thread. A stage
+# without an entry is top-level: the top-level stages of one thread do
+# not overlap, so they sum to (at most) its wall, and a stage's self
+# time is its duration minus its children's. `close` is top-level at
+# ONE site, the deferred-changes flush of an EMIT CHANGES query, whose
+# steps run no close cycle of their own.
+TRACE_PARENT = {
+    "ring_wait": "step", "stage_wait": "step", "close": "step",
+    "close_fetch": "close", "close_decode": "close",
+}
+
 # kernel dispatch families (per-family dispatch histograms + recompile
-# attribution) — also cross-checked by the analyzer registry pass
-KERNEL_FAMILIES = frozenset({"step", "close", "probe", "session"})
+# attribution) — also cross-checked by the analyzer registry pass.
+# `peek` is the read plane's batched extract, on a pull's thread.
+KERNEL_FAMILIES = frozenset({"step", "close", "probe", "session", "peek"})
 
 
 def new_span_id() -> str:
@@ -297,7 +398,8 @@ def kernel_family(family: str, observer=None, *, ready=None):
     """Scope a kernel dispatch under a family name. When `observer`
     (a callable (family, seconds)) is set, the dispatch's host time
     lands there — the per-family dispatch-time histograms ride this.
-    Cost with no observer: two thread-local attribute writes.
+    Cost with no observer: two thread-local attribute writes and an
+    inert profiler annotation `dispatch:<family>`.
 
     `ready` (ISSUE 18) — a zero-arg callable returning the dispatch's
     live device values — opts the site into the device-time sampler:
@@ -319,7 +421,8 @@ def kernel_family(family: str, observer=None, *, ready=None):
     t0 = time.perf_counter() \
         if (observer is not None or sampled) else 0.0
     try:
-        yield
+        with _annotation("dispatch:" + family):
+            yield
     finally:
         _family_tls.name = prev
         if sampled:
@@ -332,19 +435,6 @@ def kernel_family(family: str, observer=None, *, ready=None):
                 observer(family, time.perf_counter() - t0)
             except Exception:  # noqa: BLE001 — observers are metrics
                 pass           # plumbing; never fail a dispatch
-
-
-@contextlib.contextmanager
-def jax_profiler(log_dir: str):
-    """Deep device profile (TensorBoard trace format) around a block —
-    the jax.profiler hook SURVEY §5.1 prescribes."""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 # ---- recompile guard (ISSUE 7) ----------------------------------------------

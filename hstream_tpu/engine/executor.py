@@ -41,7 +41,7 @@ from hstream_tpu.common.columnar import ColumnarEmit, extend_rows
 from hstream_tpu.common.errors import SQLCodegenError
 from hstream_tpu.common.faultinject import FAULTS
 from hstream_tpu.common.logger import get_logger
-from hstream_tpu.common.tracing import kernel_family
+from hstream_tpu.common.tracing import kernel_family, trace_span
 from hstream_tpu.engine import lattice, transport
 from hstream_tpu.engine.expr import (
     BinOp,
@@ -272,6 +272,10 @@ class QueryExecutor:
         # (the host twin of the device's watermark mask), and H2D/D2H
         # byte totals on the staging and stacked-drain paths
         self.dispatch_observer = None   # callable (family, seconds)
+        # the owning task's QueryTracer, set where dispatch_observer
+        # is: the close cycle's spans (close / close_fetch /
+        # close_decode) land in the query's rings; None = no-op
+        self.tracer = None
         self.late_drops = 0
         self.transfer_stats = {"h2d_bytes": 0, "d2h_bytes": 0}
         # read-plane versioning (ISSUE 20): read_epoch bumps at every
@@ -1154,6 +1158,15 @@ class QueryExecutor:
         instead of killing the query (ISSUE 8)."""
         if not starts:
             return []
+        with trace_span(self.tracer, "close"):
+            return self._close_cycle(starts)
+
+    # contract: dispatches<=1 fetches<=1
+    def _close_cycle(self, starts: list[int]) -> list[dict[str, Any]]:
+        """The cycle itself, inside the `close` span: dispatch, then
+        the D2H sync (`close_fetch`: the device's extract time shows
+        here, the dispatch being async) and the host decode of the
+        fetched rows (`close_decode`)."""
         ows = [(s, self._open.pop(s).slot) for s in starts]
         self.read_epoch += 1
         self.close_stats["close_cycles"] += 1
@@ -1190,7 +1203,8 @@ class QueryExecutor:
         else:
             self.close_stats["close_fetches"] += 1
             try:
-                packed_host = np.asarray(packed)
+                with trace_span(self.tracer, "close_fetch"):
+                    packed_host = np.asarray(packed)
                 self.transfer_stats["d2h_bytes"] += packed_host.nbytes
             except Exception as e:  # noqa: BLE001 — the dispatch is
                 # async: a device-side execution failure surfaces at
@@ -1205,7 +1219,8 @@ class QueryExecutor:
                 self.device_fallbacks += 1
                 self.state = prev_state
                 return self._close_windows_ref(ows)
-            rows = self._decode_extract_batch(packed_host, starts)
+            with trace_span(self.tracer, "close_decode"):
+                rows = self._decode_extract_batch(packed_host, starts)
         for s in starts:
             self._no_close.discard(s)
         return rows
@@ -1407,17 +1422,20 @@ class QueryExecutor:
         """Current (open-window) aggregate rows without resetting state —
         the live half of a materialized view; closed windows are kept by
         the view store that owns this executor. ONE batched extract
-        dispatch + ONE fetch covers every open window."""
+        dispatch + ONE fetch covers every open window. The whole of it
+        is the `peek` family's span on the caller's (a pull's) thread:
+        `kernel_dispatch_ms{peek}` is dispatch + D2H sync + decode."""
         if self.window is None:
+            starts, slots = [None], [0]
+        else:
+            starts = sorted(self._open)
+            if not starts:
+                return []
+            slots = [self._open[s].slot for s in starts]
+        with kernel_family("peek", self.dispatch_observer):
             packed = np.asarray(self._extract_slots(
-                self.state, self._pad_slots([0])))
-            return self._decode_extract_batch(packed, [None])
-        starts = sorted(self._open)
-        if not starts:
-            return []
-        slots = self._pad_slots([self._open[s].slot for s in starts])
-        packed = np.asarray(self._extract_slots(self.state, slots))
-        return self._decode_extract_batch(packed, starts)
+                self.state, self._pad_slots(slots)))
+            return self._decode_extract_batch(packed, starts)
 
     # contract: dispatches<=0 fetches<=1
     def block_until_ready(self) -> None:

@@ -54,6 +54,25 @@ NEG_INF = jnp.float32(-jnp.inf)
 POS_INF = jnp.float32(jnp.inf)
 EMPTY_START = -(1 << 31)  # slot_start sentinel for "slot unoccupied"
 
+# The served path's programs, as a profiler trace names them (`jit_` +
+# the jitted function's name). A trace reduction finds a program by
+# this name, so each is pinned here and held to its function by a test
+# (tests/test_host_timeline.py): rename the function and the constant
+# together, and say so to whoever reads traces.
+STEP_PROGRAM = "jit_step"                    # build_step_encoded / _packed
+CLOSE_PROGRAM = "jit_extract_and_reset"      # build_extract_reset_slots
+PEEK_PROGRAM = "jit_peek_slots"              # build_extract_slots: it alone
+
+# `jax.named_scope` of each aggregate's scatter inside the step (op
+# metadata only: the computation and its cache key do not change)
+_AGG_SCOPE = {
+    AggKind.COUNT: "count", AggKind.SUM: "sum", AggKind.AVG: "avg",
+    AggKind.MIN: "minmax", AggKind.MAX: "minmax",
+    AggKind.APPROX_COUNT_DISTINCT: "hll",
+    AggKind.APPROX_QUANTILE: "quantile",
+    AggKind.TOPK: "topk", AggKind.TOPK_DISTINCT: "topk",
+}
+
 
 @dataclass(frozen=True)
 class LatticeSpec:
@@ -193,8 +212,9 @@ def build_step_fn(spec: LatticeSpec,
         flat_starts = starts.reshape(-1)
 
         out = dict(state)
-        out["count"] = state["count"].at[flat_k, flat_s].add(
-            flat_ok.astype(jnp.int32), mode="drop")
+        with jax.named_scope("count"):
+            out["count"] = state["count"].at[flat_k, flat_s].add(
+                flat_ok.astype(jnp.int32), mode="drop")
         out["slot_start"] = state["slot_start"].at[
             jnp.where(ok_slot.reshape(-1), slots.reshape(-1), W)].max(
             flat_starts, mode="drop")
@@ -207,49 +227,52 @@ def build_step_fn(spec: LatticeSpec,
             vfn, null_key = agg_inputs[i]
             if agg.kind == AggKind.COUNT_ALL:
                 continue  # reads the built-in `count` plane at finalize
-            v = vfn(cols)                                        # [B]
-            # input validity: not SQL NULL, and finite for float inputs
-            input_ok = jnp.ones(v.shape, jnp.bool_)
-            if null_key is not None:
-                input_ok = input_ok & ~cols[null_key]
-            if jnp.issubdtype(v.dtype, jnp.floating):
-                input_ok = input_ok & jnp.isfinite(v)
-            iok = flat_ok & jnp.repeat(input_ok, n_per)
-            v_rep = jnp.repeat(v, n_per)
-            if agg.kind == AggKind.COUNT:
-                out[name] = state[name].at[flat_k, flat_s].add(
-                    iok.astype(jnp.int32), mode="drop")
-            elif agg.kind == AggKind.SUM:
-                vals = jnp.where(iok, v_rep.astype(jnp.float32), 0.0)
-                out[name] = state[name].at[flat_k, flat_s].add(vals, mode="drop")
-            elif agg.kind == AggKind.AVG:
-                vals = jnp.where(iok, v_rep.astype(jnp.float32), 0.0)
-                out[name] = state[name].at[flat_k, flat_s].add(vals, mode="drop")
-                out[name + "_n"] = state[name + "_n"].at[flat_k, flat_s].add(
-                    iok.astype(jnp.int32), mode="drop")
-            elif agg.kind == AggKind.MIN:
-                vals = jnp.where(iok, v_rep.astype(jnp.float32), POS_INF)
-                out[name] = state[name].at[flat_k, flat_s].min(vals, mode="drop")
-            elif agg.kind == AggKind.MAX:
-                vals = jnp.where(iok, v_rep.astype(jnp.float32), NEG_INF)
-                out[name] = state[name].at[flat_k, flat_s].max(vals, mode="drop")
-            elif agg.kind == AggKind.APPROX_COUNT_DISTINCT:
-                reg, rank = hll_update_indices(v, spec.hll)
-                reg_rep = jnp.repeat(reg, n_per)
-                rank_rep = jnp.where(iok, jnp.repeat(rank, n_per), 0)
-                out[name] = state[name].at[flat_k, flat_s, reg_rep].max(
-                    rank_rep, mode="drop")
-            elif agg.kind == AggKind.APPROX_QUANTILE:
-                b_rep = jnp.repeat(quantile_bin(v, spec.qcfg), n_per)
-                out[name] = state[name].at[flat_k, flat_s, b_rep].add(
-                    iok.astype(jnp.int32), mode="drop")
-            elif agg.kind in _TOPK_KINDS:
-                out[name] = _topk_step(
-                    state[name], agg, spec,
-                    jnp.where(iok, v_rep.astype(jnp.float32), NEG_INF),
-                    flat_k, flat_s, iok)
-            else:
-                raise NotImplementedError(agg.kind)
+            # one scope per aggregate: an xprof reader sees which
+            # aggregate a fusion of the step belongs to
+            with jax.named_scope(_AGG_SCOPE[agg.kind]):
+                v = vfn(cols)                                        # [B]
+                # input validity: not SQL NULL, and finite for float inputs
+                input_ok = jnp.ones(v.shape, jnp.bool_)
+                if null_key is not None:
+                    input_ok = input_ok & ~cols[null_key]
+                if jnp.issubdtype(v.dtype, jnp.floating):
+                    input_ok = input_ok & jnp.isfinite(v)
+                iok = flat_ok & jnp.repeat(input_ok, n_per)
+                v_rep = jnp.repeat(v, n_per)
+                if agg.kind == AggKind.COUNT:
+                    out[name] = state[name].at[flat_k, flat_s].add(
+                        iok.astype(jnp.int32), mode="drop")
+                elif agg.kind == AggKind.SUM:
+                    vals = jnp.where(iok, v_rep.astype(jnp.float32), 0.0)
+                    out[name] = state[name].at[flat_k, flat_s].add(vals, mode="drop")
+                elif agg.kind == AggKind.AVG:
+                    vals = jnp.where(iok, v_rep.astype(jnp.float32), 0.0)
+                    out[name] = state[name].at[flat_k, flat_s].add(vals, mode="drop")
+                    out[name + "_n"] = state[name + "_n"].at[flat_k, flat_s].add(
+                        iok.astype(jnp.int32), mode="drop")
+                elif agg.kind == AggKind.MIN:
+                    vals = jnp.where(iok, v_rep.astype(jnp.float32), POS_INF)
+                    out[name] = state[name].at[flat_k, flat_s].min(vals, mode="drop")
+                elif agg.kind == AggKind.MAX:
+                    vals = jnp.where(iok, v_rep.astype(jnp.float32), NEG_INF)
+                    out[name] = state[name].at[flat_k, flat_s].max(vals, mode="drop")
+                elif agg.kind == AggKind.APPROX_COUNT_DISTINCT:
+                    reg, rank = hll_update_indices(v, spec.hll)
+                    reg_rep = jnp.repeat(reg, n_per)
+                    rank_rep = jnp.where(iok, jnp.repeat(rank, n_per), 0)
+                    out[name] = state[name].at[flat_k, flat_s, reg_rep].max(
+                        rank_rep, mode="drop")
+                elif agg.kind == AggKind.APPROX_QUANTILE:
+                    b_rep = jnp.repeat(quantile_bin(v, spec.qcfg), n_per)
+                    out[name] = state[name].at[flat_k, flat_s, b_rep].add(
+                        iok.astype(jnp.int32), mode="drop")
+                elif agg.kind in _TOPK_KINDS:
+                    out[name] = _topk_step(
+                        state[name], agg, spec,
+                        jnp.where(iok, v_rep.astype(jnp.float32), NEG_INF),
+                        flat_k, flat_s, iok)
+                else:
+                    raise NotImplementedError(agg.kind)
         return out
 
     return step
@@ -396,12 +419,13 @@ def build_step_encoded(spec: LatticeSpec, agg_inputs: list[AggInput],
 
     base = build_step_fn(spec, agg_inputs, filter_fn)
 
-    def step(state, watermark, n, bases, words):
-        key_ids, ts, valid, cols = tp.decode_batch(words, combo, cap, n,
-                                                   bases)
-        for nk in null_keys:
-            if nk is not None and nk not in cols:
-                cols[nk] = jnp.zeros((cap,), jnp.bool_)
+    def step(state, watermark, n, bases, words):  # STEP_PROGRAM
+        with jax.named_scope("wire_decode"):
+            key_ids, ts, valid, cols = tp.decode_batch(words, combo, cap,
+                                                       n, bases)
+            for nk in null_keys:
+                if nk is not None and nk not in cols:
+                    cols[nk] = jnp.zeros((cap,), jnp.bool_)
         return base(state, watermark, key_ids, ts, valid, cols)
 
     return step
@@ -611,7 +635,8 @@ def _extract_slots_packed(spec: LatticeSpec, state, slots):
     def one(slot):
         col = {k: v[:, slot] for k, v in state.items()
                if k not in ("slot_start", "touched")}
-        outs = finalize_column(spec, col)
+        with jax.named_scope("finalize"):
+            outs = finalize_column(spec, col)
         return pack_extract_rows(spec, col["count"],
                                  state["slot_start"][slot], outs)
 
@@ -629,7 +654,7 @@ def build_extract_reset_slots(spec: LatticeSpec):
     state). Padding entries (slot < 0) extract zeros and reset nothing."""
 
     @jax.jit
-    def extract_and_reset(state, slots):
+    def extract_and_reset(state, slots):  # CLOSE_PROGRAM
         packed = _extract_slots_packed(spec, state, slots)
         rs = jnp.where(slots >= 0, slots, spec.n_slots)  # OOB -> drop
         return _reset_slots_tree(spec, state, rs), packed
@@ -638,15 +663,15 @@ def build_extract_reset_slots(spec: LatticeSpec):
 
 
 def build_extract_slots(spec: LatticeSpec):
-    """extract(state, slots i32[P]) -> packed i32[P, 2+rows, K]: the
+    """peek_slots(state, slots i32[P]) -> packed i32[P, 2+rows, K]: the
     read-only half of the fused close — one dispatch serves a pull
     query / view peek over every open window."""
 
     @jax.jit
-    def extract(state, slots):
+    def peek_slots(state, slots):  # PEEK_PROGRAM
         return _extract_slots_packed(spec, state, slots)
 
-    return extract
+    return peek_slots
 
 
 def build_reset_slots(spec: LatticeSpec):

@@ -41,15 +41,26 @@ from typing import Any, Mapping
 import numpy as np
 
 from hstream_tpu.common.columnar import extend_rows
+from hstream_tpu.common.tracing import name_os_thread, trace_span
 
 
 class IngestPipeline:
     """Pipelines stage_columnar (worker pool) with process_staged
     (caller thread) for one QueryExecutor. Not thread-safe itself: one
-    producer calls submit()/flush()."""
+    producer calls submit()/flush().
 
-    def __init__(self, executor, depth: int = 4, workers: int = 1):
+    `tracer` (the owning task's QueryTracer) names the waits nothing
+    else times: `ring_wait` (the caller's put into a full staging
+    ring), `stage_wait` (the caller waiting for its next batch to be
+    staged) and, on each worker's own thread, `encode`. `on_stepped`
+    (a callable (events)) is told of every batch the caller takes to
+    step: the owner's public count of what the query has consumed."""
+
+    def __init__(self, executor, depth: int = 4, workers: int = 1, *,
+                 tracer=None, on_stepped=None):
         self._ex = executor
+        self._tracer = tracer
+        self._on_stepped = on_stepped
         self.depth = max(int(depth), 1)
         self.workers = max(int(workers), 1)
         # bounded staging ring: (seq, batch) items; blocking put() is the
@@ -80,6 +91,7 @@ class IngestPipeline:
     # ---- encode workers ----------------------------------------------------
 
     def _encode_loop(self) -> None:
+        name_os_thread()
         while True:
             try:
                 item = self._in.get(timeout=0.5)
@@ -92,7 +104,9 @@ class IngestPipeline:
             seq, (kids, ts, cols, nulls) = item
             try:
                 t0 = time.perf_counter()
-                staged = self._ex.stage_columnar(kids, ts, cols, nulls)
+                with trace_span(self._tracer, "encode"):
+                    staged = self._ex.stage_columnar(kids, ts, cols,
+                                                     nulls)
                 with self._stat_lock:
                     self._busy["encode_s"] += time.perf_counter() - t0
             except BaseException as e:  # surfaced in order on the caller
@@ -117,20 +131,26 @@ class IngestPipeline:
         if self._take_seq >= self._next_seq:
             return None
         seq = self._take_seq
-        with self._cond:
-            while seq not in self._ready:
-                if not block:
+        if not block:
+            with self._cond:
+                if seq not in self._ready:
                     return None
-                if self._live_workers <= 0:
-                    raise RuntimeError(
-                        "ingest pipeline workers died with batches "
-                        "pending")
-                self._cond.wait(0.5)
-            staged = self._ready.pop(seq)
+        # one observation per batch taken, however short the wait
+        with trace_span(self._tracer, "stage_wait"):
+            with self._cond:
+                while seq not in self._ready:
+                    if self._live_workers <= 0:
+                        raise RuntimeError(
+                            "ingest pipeline workers died with batches "
+                            "pending")
+                    self._cond.wait(0.5)
+                staged = self._ready.pop(seq)
         self._take_seq = seq + 1
         if isinstance(staged, _WorkerError):
             self._dead = True
             raise staged.err
+        if self._on_stepped is not None and staged is not None:
+            self._on_stepped(staged.n)
         t0 = time.perf_counter()
         rows = self._ex.process_staged(staged)
         with self._stat_lock:
@@ -176,7 +196,10 @@ class IngestPipeline:
             self._next_seq = seq + 1
             while True:
                 try:
-                    self._in.put((seq, item), timeout=0.5)
+                    # one observation per put tried (at least one per
+                    # batch); a full ring shows as 0.5 s spans
+                    with trace_span(self._tracer, "ring_wait"):
+                        self._in.put((seq, item), timeout=0.5)
                     break
                 except queue.Full:
                     # ring full AND nothing staged yet: keep draining so
@@ -204,13 +227,16 @@ class IngestPipeline:
         dispatch, summed over workers); step: caller time in
         process_staged (step dispatch + window bookkeeping + inline
         drains). The executor contributes upload-wait and change-drain
-        counters when it tracks them (executor.stage_stats)."""
+        counters when it tracks them (executor.stage_stats).
+        `batches_stepped` is the public count of batches the caller
+        has taken to step, in submission order."""
         wall = max(time.perf_counter() - self._t0, 1e-9)
         with self._stat_lock:
             out = dict(self._busy)
         for k, v in getattr(self._ex, "stage_stats", {}).items():
             out[k] = out.get(k, 0.0) + v
         out["wall_s"] = wall
+        out["batches_stepped"] = self._take_seq
         out["encode_occupancy"] = min(
             out.get("encode_s", 0.0) / (wall * self.workers), 1.0)
         out["step_occupancy"] = min(out.get("step_s", 0.0) / wall, 1.0)

@@ -16,6 +16,7 @@ from concurrent import futures
 import grpc
 
 from hstream_tpu.common.logger import get_logger
+from hstream_tpu.common.tracing import name_os_thread
 from hstream_tpu.proto.rpc import add_hstream_api_to_server
 from hstream_tpu.server.context import (
     DEFAULT_APPEND_LANES,
@@ -126,7 +127,11 @@ def serve(host: str = "127.0.0.1", port: int = 6570,
         # one process must not leak cadence into each other's tasks
         ctx.snapshot_interval_ms = snapshot_interval_ms
     server = grpc.server(
-        futures.ThreadPoolExecutor(max_workers=max_workers),
+        # named, on the OS too: a pull's spans lie on its handler
+        # thread's line of a profiler trace (`rpc_<n>`)
+        futures.ThreadPoolExecutor(max_workers=max_workers,
+                                   thread_name_prefix="rpc",
+                                   initializer=name_os_thread),
         options=[("grpc.max_receive_message_length", 64 * 1024 * 1024),
                  ("grpc.max_send_message_length", 64 * 1024 * 1024)])
     from hstream_tpu.server.handlers import HStreamApiServicer

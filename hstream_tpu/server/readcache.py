@@ -183,7 +183,7 @@ class ReadCache:
             now = self._clock()
         try:
             closed, live, got_version, peeked = mat.snapshot_parts(select)
-            rows = serve_parts(closed, live, select)
+            rows = serve_parts(closed, live, select, mat.tracer)
             flight.rows = rows
             flight.ok = True
             with self._lock:

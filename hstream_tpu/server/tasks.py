@@ -34,7 +34,12 @@ from hstream_tpu.common import columnar, jsondec, locktrace
 from hstream_tpu.common import records as rec
 from hstream_tpu.common.faultinject import FAULTS
 from hstream_tpu.common.logger import get_logger
-from hstream_tpu.common.tracing import QueryTracer, trace_span
+from hstream_tpu.common.tracing import (
+    QueryTracer,
+    begin_span,
+    name_os_thread,
+    trace_span,
+)
 from hstream_tpu.engine.pipeline import IngestPipeline
 from hstream_tpu.engine.snapshot import (
     capture_executor,
@@ -216,6 +221,19 @@ class QueryTask(threading.Thread):
             try:
                 stats.observe("kernel_dispatch_ms", family,
                               seconds * 1e3)
+            except Exception:  # noqa: BLE001 — metrics must not kill
+                pass           # the ingest loop
+
+    def _note_consumed(self, events: int) -> None:
+        """The ingest pipeline's `on_stepped`: one batch of `events`
+        taken to step. `consumed_events` is the public count of what
+        the query has consumed (`admin stats queries`, /metrics): a
+        replay client bounds its own lead by it."""
+        stats = getattr(self.ctx, "stats", None)
+        if stats is not None:
+            try:
+                stats.stat_add("consumed_events", self.info.query_id,
+                               float(events))
             except Exception:  # noqa: BLE001 — metrics must not kill
                 pass           # the ingest loop
 
@@ -455,6 +473,7 @@ class QueryTask(threading.Thread):
 
     def run(self) -> None:
         ctx = self.ctx
+        name_os_thread()
         try:
             reader = CheckpointedReader(
                 f"query-{self.info.query_id}",
@@ -480,8 +499,9 @@ class QueryTask(threading.Thread):
             self._read_thread.start()
             while not self._stop_ev.is_set():
                 try:
-                    results = self._read_q.get(
-                        timeout=POLL_TIMEOUT_MS / 1000)
+                    with trace_span(self.tracer, "read_wait"):
+                        results = self._read_q.get(
+                            timeout=POLL_TIMEOUT_MS / 1000)
                 except queue.Empty:
                     results = None
                 if isinstance(results, BaseException):
@@ -569,9 +589,11 @@ class QueryTask(threading.Thread):
         decodes/encodes/computes. Read errors travel to the task thread
         as a sentinel (raised at its next get). Only reader.read runs
         here — checkpoint writes stay on the task/persist threads."""
+        name_os_thread()
         while not self._stop_ev.is_set():
             try:
-                results = reader.read(READ_CHUNK)
+                with trace_span(self.tracer, "store_read"):
+                    results = reader.read(READ_CHUNK)
             except BaseException as e:  # noqa: BLE001 — surfaced on
                 # the task thread; this thread must not die silently
                 results = e
@@ -589,7 +611,15 @@ class QueryTask(threading.Thread):
         per-chunk step latency every chunk (an EWMA update, cheap), and
         pipeline occupancy + reorder-ring depth at ~1 Hz (stats() walks
         the stage rings)."""
-        self._note_device_fallbacks()
+        # ONE timed acquisition for the chunk's bookkeeping (the mirror
+        # below re-enters it): a pull that holds tasks.state stops the
+        # task here as surely as at the ingest path's own acquisition,
+        # and the wait must not go unnamed
+        wait = begin_span(self.tracer, "state_wait")
+        with self.state_lock:
+            wait.end()
+            self._note_device_fallbacks()
+            pipe = self._pipe
         flow = getattr(self.ctx, "flow", None)
         if flow is None:
             return
@@ -602,8 +632,6 @@ class QueryTask(threading.Thread):
         det = flow.overload
         qid = self.info.query_id  # per-source EWMA: tasks don't blend
         det.note("step_latency_ms", step_s * 1000.0, source=qid)
-        with self.state_lock:  # _pipe is guarded (hstream-analyze)
-            pipe = self._pipe
         if pipe is None:
             return
         now = time.monotonic()
@@ -634,6 +662,7 @@ class QueryTask(threading.Thread):
             # a join's downstream aggregate is created lazily — wire
             # its dispatch observer the first time it appears
             inner.dispatch_observer = self._observe_kernel
+            inner.tracer = self.tracer
 
         def transfer(key: str) -> int:
             cur = int(getattr(ex, "transfer_stats", {}).get(key, 0))
@@ -778,7 +807,9 @@ class QueryTask(threading.Thread):
         join-coalesced) AND deferred session closes to the sink — idle
         ticks and pre-snapshot; the snapshot guards require an empty
         queue on both surfaces."""
+        wait = begin_span(self.tracer, "state_wait")
         with self.state_lock:  # executor is guarded (hstream-analyze)
+            wait.end()
             ex = self.executor
         if ex is None:
             return
@@ -789,7 +820,9 @@ class QueryTask(threading.Thread):
         pending = pending or (hc is not None and hc())
         if not pending:
             return
+        wait = begin_span(self.tracer, "state_wait")
         with self.state_lock:
+            wait.end()
             with trace_span(self.tracer, "close"):
                 rows = ex.flush_changes()
             if rows:
@@ -896,6 +929,7 @@ class QueryTask(threading.Thread):
             self._persist_cv.notify_all()
 
     def _persist_loop(self) -> None:
+        name_os_thread()
         while True:
             with self._persist_cv:
                 while (self._persist_pending is None
@@ -1091,7 +1125,9 @@ class QueryTask(threading.Thread):
         stateless materialize rows."""
         if len(ts) == 0:
             return
+        wait = begin_span(self.tracer, "state_wait")
         with self.state_lock:
+            wait.end()
             if self.executor is None:
                 self.executor = self._make_executor(
                     _sample_rows(ts, cols, nulls), len(ts))
@@ -1181,6 +1217,7 @@ class QueryTask(threading.Thread):
             if target is not None and hasattr(target,
                                               "dispatch_observer"):
                 target.dispatch_observer = self._observe_kernel
+                target.tracer = self.tracer
         if getattr(ex, "emit_changes", False) and \
                 getattr(ex, "supports_deferred_changes", False):
             # pipeline changelog fetches behind later batches' work and
@@ -1200,7 +1237,9 @@ class QueryTask(threading.Thread):
         return ex
 
     def _run_rows(self, rows: list, ts: list, logid: int | None) -> None:
+        wait = begin_span(self.tracer, "state_wait")
         with self.state_lock:
+            wait.end()
             if self.executor is None:
                 self.executor = self._make_executor(rows, len(rows))
             ex = self.executor
@@ -1248,7 +1287,9 @@ class QueryTask(threading.Thread):
             log.warning("skipping malformed columnar record on logid %d",
                         logid)
             return
+        wait = begin_span(self.tracer, "state_wait")
         with self.state_lock:
+            wait.end()
             if self.executor is None:
                 self.executor = self._make_executor(
                     _sample_rows(ts, cols, nulls), len(ts))
@@ -1296,7 +1337,9 @@ class QueryTask(threading.Thread):
         tick / snapshot barrier) flushes the tail."""
         if self._pipe is None:
             self._pipe = IngestPipeline(ex, depth=self.pipeline_depth,
-                                        workers=self.encode_workers)
+                                        workers=self.encode_workers,
+                                        tracer=self.tracer,
+                                        on_stepped=self._note_consumed)
         with trace_span(self.tracer, "step"):
             out = self._pipe.submit(key_ids, ts, cols, nulls)
         if out:
@@ -1333,12 +1376,19 @@ class QueryTask(threading.Thread):
 
     def _drain_pipe(self) -> None:
         """Pipeline barrier: every submitted batch processed, rows sunk."""
+        wait = begin_span(self.tracer, "state_wait")
         with self.state_lock:  # _pipe is guarded (hstream-analyze)
+            wait.end()
             pipe = self._pipe
         if pipe is None or pipe.pending == 0:
             return
+        wait = begin_span(self.tracer, "state_wait")
         with self.state_lock:
-            rows = pipe.flush()
+            wait.end()
+            # the barrier's steps are steps: stage_wait, the close
+            # cycle and the dispatch nest in `step` here as in _submit
+            with trace_span(self.tracer, "step"):
+                rows = pipe.flush()
             if rows:
                 with trace_span(self.tracer, "emit"):
                     self.sink(rows)

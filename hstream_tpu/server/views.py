@@ -25,6 +25,7 @@ import numpy as np
 from hstream_tpu.common import locktrace
 from hstream_tpu.common.columnar import ColumnarEmit
 from hstream_tpu.common.errors import ViewNotFound
+from hstream_tpu.common.tracing import begin_span, trace_span
 from hstream_tpu.engine.expr import (
     BinOp,
     Col,
@@ -61,6 +62,13 @@ class Materialization:
         # the read cache. Bumped under self._lock; probed lock-free (a
         # torn probe can only cause a spurious cache miss).
         self._version = 0
+
+    @property
+    def tracer(self):
+        """The owning task's tracer (None without a task): a pull's
+        spans land in the query's rings, beside the ingest stages they
+        contend with."""
+        return getattr(self.task, "tracer", None)
 
     def _row_key(self, row: dict[str, Any]) -> tuple:
         # (window, group identity): last write per (winStart, key cols)
@@ -106,12 +114,16 @@ class Materialization:
         # same order the task thread takes them (state_lock -> mat._lock
         # via sink): a window closing between the two reads would
         # otherwise appear in neither half
+        tracer = self.tracer
+        wait = begin_span(tracer, "pull_state_wait")
         with task.state_lock:
-            with self._lock:
-                rows = list(self._closed.values())
-            ex = task.executor
-            if ex is not None and hasattr(ex, "peek"):
-                rows.extend(ex.peek())
+            wait.end()
+            with trace_span(tracer, "pull_hold"):
+                with self._lock:
+                    rows = list(self._closed.values())
+                ex = task.executor
+                if ex is not None and hasattr(ex, "peek"):
+                    rows.extend(ex.peek())
         return rows
 
     def version(self) -> tuple | None:
@@ -151,22 +163,29 @@ class Materialization:
         if task is None:
             with self._lock:
                 return list(self._closed.values()), [], None, False
+        # a pull's two costs to ingest, named by who pays: the wait
+        # for the task's lock, and the hold (closed-row copy + peek)
+        # during which the task's `state_wait` runs
+        tracer = self.tracer
+        wait = begin_span(tracer, "pull_state_wait")
         with task.state_lock:
-            with self._lock:
-                closed = list(self._closed.values())
-                mver = self._version
-            ex = task.executor
-            live: Any = []
-            peeked = False
-            if ex is not None and hasattr(ex, "peek"):
-                if not _skip_live(ex, select):
-                    live = ex.peek()
-                    peeked = True
-                rv = getattr(ex, "read_version", None)
-                exv = rv() if rv is not None else None
-                version = None if exv is None else (mver, exv)
-            else:
-                version = (mver, None)
+            wait.end()
+            with trace_span(tracer, "pull_hold"):
+                with self._lock:
+                    closed = list(self._closed.values())
+                    mver = self._version
+                ex = task.executor
+                live: Any = []
+                peeked = False
+                if ex is not None and hasattr(ex, "peek"):
+                    if not _skip_live(ex, select):
+                        live = ex.peek()
+                        peeked = True
+                    rv = getattr(ex, "read_version", None)
+                    exv = rv() if rv is not None else None
+                    version = None if exv is None else (mver, exv)
+                else:
+                    version = (mver, None)
         return closed, live, version, peeked
 
 
@@ -340,14 +359,16 @@ def _select_emit(emit, select: ast.Select) -> list[dict[str, Any]]:
 
 
 def serve_parts(closed: list[dict[str, Any]], live,
-                select: ast.Select) -> list[dict[str, Any]]:
+                select: ast.Select, tracer=None) -> list[dict[str, Any]]:
     """Filter + project both halves, then the fixed-window slicing sort
     (stable, so closed-before-live order at equal winStart matches the
-    legacy concat pipeline exactly)."""
-    out = project_rows(filter_rows(closed, select), select,
-                       keep_meta=("winStart", "winEnd"))
-    out.extend(_select_emit(live, select))
-    out.sort(key=lambda r: (r.get("winStart") or 0))
+    legacy concat pipeline exactly). Runs after the task's lock is
+    released: `pull_serve` in `tracer` (the view's, `mat.tracer`)."""
+    with trace_span(tracer, "pull_serve"):
+        out = project_rows(filter_rows(closed, select), select,
+                           keep_meta=("winStart", "winEnd"))
+        out.extend(_select_emit(live, select))
+        out.sort(key=lambda r: (r.get("winStart") or 0))
     return out
 
 
@@ -356,4 +377,4 @@ def serve_select_view(mat: Materialization,
     """Execute a pull query against a materialization
     (reference Handler.hs:277-325: key filter + fixed-window slicing)."""
     closed, live, _version, _peeked = mat.snapshot_parts(select)
-    return serve_parts(closed, live, select)
+    return serve_parts(closed, live, select, mat.tracer)

@@ -63,6 +63,11 @@ STAT_FAMILIES = [
                "aggregate rows emitted over the trailing window"),
     StatFamily("close_cycles", "query", "cycles",
                "window close cycles emitted over the trailing window"),
+    # what the query has taken in, counted where a batch reaches its
+    # step: a replay client holds its lead against the total
+    StatFamily("consumed_events", "query", "events",
+               "events the query's ingest pipeline has taken to step "
+               "over the trailing window"),
     # multi-chip execution (ISSUE 16): device dispatches that ran
     # under shard_map — the rate a sharded query's fused kernels hit
     # the mesh (zero for single-chip queries)

@@ -78,17 +78,3 @@ def test_query_trace_rpc_and_admin():
         ctx.shutdown()
 
 
-def test_jax_profiler_writes_trace(tmp_path):
-    """The deep-profile hook (HSTREAM_PROFILE_DIR in bench.py) captures
-    a TensorBoard trace directory."""
-    import jax.numpy as jnp
-
-    from hstream_tpu.common.tracing import jax_profiler
-
-    out = str(tmp_path / "prof")
-    with jax_profiler(out):
-        jnp.sum(jnp.arange(128)).block_until_ready()
-    import os
-
-    files = [os.path.join(dp, f) for dp, _, fs in os.walk(out) for f in fs]
-    assert files, "profiler produced no trace files"

@@ -71,6 +71,7 @@ HIST_CALLS = {"observe", "histogram_percentile", "_hist"}
 # at one call site silently forks the series.
 STAGE_ARG_CALLS = {
     "trace_span": (1, "stage"),
+    "begin_span": (1, "stage"),
     "record_span": (1, "stage"),
     "_observe_append_stage": (0, "stage"),
     "_trace_stage_span": (1, "stage"),
