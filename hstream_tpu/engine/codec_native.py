@@ -1,8 +1,9 @@
-"""ctypes binding for the native wire-encode kernels (cpp/encode.cpp).
+"""ctypes binding for the native wire-encode kernels and the string
+key table (cpp/encode.cpp).
 
 Build-on-demand like the native store (store/build.py); `load()` returns
-None when no toolchain is available and the transport falls back to its
-pure-numpy packer.
+None when no toolchain is available: the transport falls back to its
+pure-numpy packer, the key table (engine/keytable.py) to a dict.
 """
 
 from __future__ import annotations
@@ -61,5 +62,18 @@ def load() -> C.CDLL | None:
                                          C.c_float, _i64, _p_i32,
                                          _p_i64, _p_i64]
         lib.enc_quantize_f32.restype = C.c_int32
+        # string -> key id table (engine/keytable.py)
+        lib.kt_new.argtypes = []
+        lib.kt_new.restype = C.c_void_p
+        lib.kt_free.argtypes = [C.c_void_p]
+        lib.kt_free.restype = None
+        lib.kt_size.argtypes = [C.c_void_p]
+        lib.kt_size.restype = _i64
+        lib.kt_resolve.argtypes = [C.c_void_p, C.c_char_p, _i64, _i64,
+                                   _p_i32]
+        lib.kt_resolve.restype = _i64
+        lib.kt_insert.argtypes = [C.c_void_p, C.c_char_p, _i64, _i64,
+                                  _p_i32]
+        lib.kt_insert.restype = _i64
         _lib = lib
         return _lib

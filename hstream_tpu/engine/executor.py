@@ -52,6 +52,7 @@ from hstream_tpu.engine.expr import (
     eval_host,
     eval_host_vec,
 )
+from hstream_tpu.engine.keytable import KeyTable
 from hstream_tpu.engine.plan import AggKind, AggregateNode, AggSpec
 from hstream_tpu.engine.types import (
     ColumnType,
@@ -162,6 +163,10 @@ class QueryExecutor:
 
         self._key_ids: dict[tuple, int] = {}
         self._key_rev: list[tuple] = []
+        # what the two above said, kept for the task's key_encode stage
+        # to resolve a batch's dictionary in one call; derived, rebuilt
+        # from _key_rev, never persisted
+        self._key_table = KeyTable()
 
         # Pre-encode string literals (fills the column dictionaries) so the
         # expressions are hashable and compiled functions can be shared.

@@ -237,6 +237,29 @@ class QueryTask(threading.Thread):
             except Exception:  # noqa: BLE001 — metrics must not kill
                 pass           # the ingest loop
 
+    def _key_encode(self, ex, ts, cols: dict, nulls: dict | None):
+        """The `key_encode` stage of one columnar batch (caller holds
+        state_lock): group values -> key ids, batch columns -> device
+        columns. `key_lookups` / `key_misses` count the dictionary
+        entries resolved against the executor's key table and those
+        that had to go through `key_id_for`: after warm-up the second
+        stands still unless new keys arrive."""
+        with trace_span(self.tracer, "key_encode"):
+            key_ids = _columnar_key_ids(ex, cols, len(ts), nulls=nulls)
+            dev_cols, dnulls = _device_columns(ex, cols, len(ts),
+                                               nulls=nulls)
+        stats = getattr(self.ctx, "stats", None)
+        if stats is not None:
+            lookups, misses = ex._key_table.take_counts()
+            try:
+                stats.stat_add("key_lookups", self.info.query_id,
+                               float(lookups))
+                stats.stat_add("key_misses", self.info.query_id,
+                               float(misses))
+            except Exception:  # noqa: BLE001 — metrics must not kill
+                pass           # the ingest loop
+        return key_ids, dev_cols, dnulls
+
     # ---- event-time freshness plane (ISSUE 13) -----------------------------
 
     def _wrap_sink(self, sink: SinkFn) -> SinkFn:
@@ -1169,11 +1192,8 @@ class QueryTask(threading.Thread):
                     with trace_span(self.tracer, "emit"):
                         self.sink(out)
                 return
-            with trace_span(self.tracer, "key_encode"):
-                key_ids = _columnar_key_ids(ex, cols, len(ts),
-                                            nulls=nulls)
-                dev_cols, dnulls = _device_columns(ex, cols, len(ts),
-                                                   nulls=nulls)
+            key_ids, dev_cols, dnulls = self._key_encode(ex, ts, cols,
+                                                         nulls)
             self._submit(ex, key_ids, ts, dev_cols, dnulls)
 
     def _query_mesh(self):
@@ -1322,11 +1342,8 @@ class QueryTask(threading.Thread):
                     with trace_span(self.tracer, "emit"):
                         self.sink(out)
                 return
-            with trace_span(self.tracer, "key_encode"):
-                key_ids = _columnar_key_ids(ex, cols, len(ts),
-                                            nulls=nulls)
-                dev_cols, dnulls = _device_columns(ex, cols, len(ts),
-                                                   nulls=nulls)
+            key_ids, dev_cols, dnulls = self._key_encode(ex, ts, cols,
+                                                         nulls)
             self._submit(ex, key_ids, ts, dev_cols, dnulls)
 
     def _submit(self, ex, key_ids, ts, cols, nulls) -> None:
@@ -1509,6 +1526,51 @@ def _columnarize_rows(ex, rows: list) -> tuple:
     return key_ids, cols, (nulls or None)
 
 
+def _dictionary_key_ids(ex, cols: dict, n: int,
+                        nulls: dict | None) -> "np.ndarray | None":
+    """Key ids for ONE string group column whose dictionary is no larger
+    than the batch: the whole dictionary resolves against the executor's
+    key table in one call (engine/keytable.py: native, GIL released),
+    and only its misses go through `key_id_for`. None when the input is
+    not of that shape (`_columnar_key_ids` then takes the general path).
+
+    Misses keep the general path's rules, so every key gets the id it
+    always got and snapshots stay byte-compatible: only entries whose
+    code OCCURS in an unmasked row are registered, None (a null-masked
+    cell) first, then ascending code order. An unknown entry absent
+    from the rows stays -1 in the LUT and is never gathered."""
+    c = ex.group_cols[0]
+    ent = cols.get(c)
+    if ent is None or ent[0] != "str" or len(ent[2]) > n:
+        return None
+    _kind, codes, d = ent
+    table = ex._key_table
+    table.sync(ex._key_rev)
+    lut = table.resolve(d)
+    if lut is None:
+        return None
+    nm = nulls.get(c) if nulls else None
+    live = None
+    if nm is not None and nm.any():
+        live = ~nm
+        codes = codes[live]
+        null_kid = table.get(None)
+        table.lookups += 1
+        if null_kid is None:
+            null_kid = table.register(ex, None)
+    missed = np.flatnonzero(lut < 0)
+    if len(missed):
+        occurs = np.bincount(codes, minlength=len(d))[missed] > 0
+        missed = missed[occurs]
+        lut[missed] = table.register_strings(
+            ex, [d[p] for p in missed.tolist()])
+    if live is None:
+        return lut[codes]
+    key_ids = np.full(n, null_kid, np.int32)
+    key_ids[live] = lut[codes]
+    return key_ids
+
+
 def _columnar_key_ids(ex, cols: dict, n: int,
                       nulls: dict | None = None) -> "np.ndarray":
     """Vectorized group-key encoding: per-column unique+inverse, then
@@ -1516,6 +1578,10 @@ def _columnar_key_ids(ex, cols: dict, n: int,
     marks cells whose group value is None (native JSON decode)."""
     if not ex.group_cols:
         return np.zeros(n, np.int32)
+    if len(ex.group_cols) == 1:
+        key_ids = _dictionary_key_ids(ex, cols, n, nulls)
+        if key_ids is not None:
+            return key_ids
     col_vals: list[list] = []
     col_codes: list[np.ndarray] = []
     for c in ex.group_cols:
@@ -1569,23 +1635,23 @@ def _columnar_key_ids(ex, cols: dict, n: int,
         # key-capacity grow.
         vals = col_vals[0]
         codes = col_codes[0]
-        # raw-value -> key id memo: at SURVEY-scale cardinality (100K+
-        # live keys) the per-distinct key_id_for canon+tuple work is
-        # ~100ms per batch; a dict hit is ~10x cheaper. kids never
-        # change once assigned, so the memo cannot go stale; it is
-        # bounded like the session key caches.
-        memo = getattr(ex, "_kid_vmemo", None)
-        if memo is None:
-            memo = ex._kid_vmemo = {}
-        elif len(memo) > (1 << 20):
-            memo.clear()
+        # value -> key id through the executor's key table: at
+        # SURVEY-scale cardinality (100K+ live keys) the per-distinct
+        # key_id_for canon+tuple work is ~100ms per batch; a dict hit
+        # is ~10x cheaper. kids never change once assigned, so the
+        # table cannot go stale, and it holds canonical values only,
+        # so the key space bounds it.
+        table = ex._key_table
+        table.sync(ex._key_rev)
+        get = table.get
+        present = np.unique(codes).tolist()
+        table.lookups += len(present)
         kid_lut = np.zeros(len(vals), np.int32)
-        for p in np.unique(codes).tolist():
+        for p in present:
             v = vals[p]
-            kid = memo.get(v)
+            kid = get(v)
             if kid is None:
-                kid = ex.key_id_for((v,))
-                memo[v] = kid
+                kid = table.register(ex, v)
             kid_lut[p] = kid
         return kid_lut[codes]
     radix = 1

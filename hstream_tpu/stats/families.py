@@ -68,6 +68,16 @@ STAT_FAMILIES = [
     StatFamily("consumed_events", "query", "events",
                "events the query's ingest pipeline has taken to step "
                "over the trailing window"),
+    # the key_encode stage's key table (engine/keytable.py): group
+    # values looked up, and those the table did not know and the
+    # executor's key_id_for had to answer (new keys, or a table just
+    # rebuilt): the second stands still once the key space is seen
+    StatFamily("key_lookups", "query", "keys",
+               "distinct group values resolved to key ids over the "
+               "trailing window"),
+    StatFamily("key_misses", "query", "keys",
+               "group values the key table did not know over the "
+               "trailing window"),
     # multi-chip execution (ISSUE 16): device dispatches that ran
     # under shard_map — the rate a sharded query's fused kernels hit
     # the mesh (zero for single-chip queries)

@@ -93,6 +93,14 @@ def test_snapshots_and_replicas_and_assignments(server_stub):
         query_text="SELECT k, COUNT(*) AS c FROM snapsrc GROUP BY k, "
                    "TUMBLING (INTERVAL 10 SECOND) EMIT CHANGES;"))
     append_rows(stub, "snapsrc", [{"k": "a"}], [BASE])
+    # the row has to reach the task first: a query stopped before its
+    # first batch has no executor and nothing to persist
+    deadline = time.time() + 10
+    while time.time() < deadline:
+        task = ctx.running_queries.get(q.id)
+        if task is not None and task.executor is not None:
+            break
+        time.sleep(0.02)
     # force a snapshot via terminate (graceful stop persists state)
     stub.TerminateQueries(pb.TerminateQueriesRequest(query_ids=[q.id]))
     snaps = admin(stub, "snapshots")
