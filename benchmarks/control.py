@@ -26,10 +26,10 @@ if ROOT not in sys.path:
 def control_numbers(config: dict, size: dict, seed: int, n_frames: int,
                     precision: str = "bf16") -> dict:
     """The comparison's numbers for answers computed at `precision`."""
-    from benchmarks.harness import generator as gen
     from benchmarks.harness import manifest
 
     ref = manifest.reference_of(config)
+    gen = manifest.generator_of(config)
     names = gen.key_names(size)
     last_pane = gen.pane_of(size, n_frames - 1)
     width = size["size_ms"] // size["advance_ms"]
@@ -41,12 +41,11 @@ def control_numbers(config: dict, size: dict, seed: int, n_frames: int,
         panes = set(answers)
     served = {"final": ref.rows_from(size, names, answers),
               "complete": sorted(panes), "pulls": [],
-              "horizon": int(gen.closer(size, names, last_pane)[0][0])}
+              "horizon": gen.pulls(size, n_frames)["horizon"]}
     return ref.compare(size, seed, n_frames, served)
 
 
 def main(argv=None) -> int:
-    from benchmarks.harness import generator as gen
     from benchmarks.harness import manifest
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
@@ -59,6 +58,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     cell = manifest.cell(args.workload)
     size = manifest.size_of(cell["config"], bool(args.dry))
+    gen = manifest.generator_of(cell["config"])
     measured = args.frames or 4 * gen.frames_per_pane(size)
     n_frames = gen.warm_frames(size) + measured
     failed_all = True

@@ -1,27 +1,74 @@
 """BENCHMARK.json and the files it names. A cell `<config>.<traffic>`
-resolves to `benchmarks/configs/<config>.json` and
+resolves to the configuration's `file` and
 `benchmarks/traffic/<traffic>.json`; a per-layer metric to
 `benchmarks/metrics/<name>.json`, whose `reader` names a module under
-`benchmarks/readers/`; a configuration's `reference` names a module
-under `benchmarks/references/`. Nothing here knows a cell by name."""
+`benchmarks/readers/`; a configuration's `generator` names a module
+under `benchmarks/generators/` and its `reference` one under
+`benchmarks/references/`. Nothing here knows a cell by name.
+
+`use(path)` puts another manifest in BENCHMARK.json's place (the tests'
+fixture manifest): files and modules are then looked for beside that
+manifest first (`<its directory>/<kind>/<name>`) and under
+`benchmarks/` after."""
 
 from __future__ import annotations
 
 import importlib
+import importlib.util
 import json
 import os
+import sys
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
+_home: dict = {}
 
 
-def load_json(*parts: str) -> dict:
-    with open(os.path.join(BENCH_DIR, *parts)) as f:
+def use(path: str | None) -> None:
+    """Read `path` (relative to the checkout's root) as the manifest;
+    None: BENCHMARK.json itself."""
+    if not path:
+        _home.update(manifest=os.path.join(ROOT, "BENCHMARK.json"),
+                     dirs=[BENCH_DIR])
+        return
+    path = os.path.join(ROOT, path)
+    _home.update(manifest=path, dirs=[os.path.dirname(path), BENCH_DIR])
+
+
+use(None)
+
+
+def _find(kind: str, name: str) -> tuple[str, str]:
+    for d in _home["dirs"]:
+        path = os.path.join(d, kind, name)
+        if os.path.exists(path):
+            return d, path
+    raise FileNotFoundError(
+        f"no {kind}/{name} under {' or '.join(_home['dirs'])}")
+
+
+def load_json(kind: str, name: str) -> dict:
+    with open(_find(kind, name)[1]) as f:
         return json.load(f)
 
 
+def module_of(kind: str, name: str):
+    """`<kind>/<name>.py`: of `benchmarks/` by import, of a fixture
+    manifest's directory by its path."""
+    d, path = _find(kind, name + ".py")
+    if d == BENCH_DIR:
+        return importlib.import_module(f"benchmarks.{kind}.{name}")
+    key = f"bench_fixture_{kind}_{name}"
+    if key not in sys.modules:
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[key] = mod
+        spec.loader.exec_module(mod)
+    return sys.modules[key]
+
+
 def manifest() -> dict:
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+    with open(_home["manifest"]) as f:
         return json.load(f)
 
 
@@ -73,10 +120,46 @@ def reader_of(metric_name: str):
     """A metric's file and its reader, `read(run, spec) -> number or
     None` in `benchmarks/readers/<reader>.py`."""
     spec = load_json("metrics", metric_name + ".json")
-    mod = importlib.import_module(f"benchmarks.readers.{spec['reader']}")
+    mod = module_of("readers", spec["reader"])
     return spec, getattr(mod, spec.get("function", "read"))
 
 
 def reference_of(config: dict):
-    return importlib.import_module(
-        f"benchmarks.references.{config['reference']}")
+    return module_of("references", config["reference"])
+
+
+def generator_of(config: dict):
+    """The module that makes the configuration's streams, frames and
+    pulls. A configuration that names none is an error: there is no
+    default generator."""
+    if not config.get("generator"):
+        raise SystemExit(f"configuration {config.get('name')!r} names no "
+                         "generator")
+    return module_of("generators", config["generator"])
+
+
+def server_options(config: dict, serve) -> dict:
+    """The configuration's `server` block: keyword arguments of
+    `serve()` that the deployment itself states. A key `serve()` does
+    not take as a keyword-only option is refused."""
+    import inspect
+
+    block = dict(config.get("server") or {})
+    takes = {n for n, p in inspect.signature(serve).parameters.items()
+             if p.kind is p.KEYWORD_ONLY}
+    unknown = sorted(set(block) - takes)
+    if unknown:
+        raise SystemExit(f"configuration {config.get('name')!r}: server "
+                         f"block has {unknown}, which serve() does not "
+                         "take")
+    return block
+
+
+def mesh_devices(config: dict) -> int | None:
+    """Devices the configuration's `mesh_shape` asks for ("DxK"), or
+    None where it states none."""
+    shape = (config.get("server") or {}).get("mesh_shape")
+    if not shape:
+        return None
+    n_data, _, n_key = str(shape).lower().partition("x")
+    return int(n_data) * int(n_key or 1)

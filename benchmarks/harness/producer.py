@@ -4,13 +4,17 @@ it JAX; nothing here touches the chip).
 
 ONE ordered producer, closed loop: one `AppendColumnarStream` call
 outstanding at a time, and (where the traffic sets `max_lead_events`) no
-frame sent further than that ahead of what the query has consumed, which
-`run.py` relays as `consumed <frames>` lines; each call is one request
-message of the configuration's `frames_per_call` frames, sent in frame
-order (with GRACE 0 a second, unordered sender would turn good frames
-into late ones). Frames are generated from the seed and encoded as the client
-library's `encode_batch` encodes them by `encoders` helper processes, each
-making every n-th frame into a queue of two, so the next calls' frames are
+event sent further than that ahead of what the query has consumed, which
+`run.py` relays as `consumed <events>` lines (the program's public
+`consumed_events` count); each call is one request message of the
+configuration's `frames_per_call` frames, all of one stream, sent in
+frame order (with GRACE 0 a second, unordered sender would turn good
+frames into late ones). Which stream a frame goes to, and so how several
+streams interleave, is the generator's: the module the configuration
+names, the only thing here that knows a stream or a column. Frames are
+generated from the seed and encoded as the client library's
+`encode_batch` encodes them by `encoders` helper processes, each making
+every n-th frame into a queue of two, so the next calls' frames are
 ready while the last is in flight; the sender takes them in order.
 `RESOURCE_EXHAUSTED` is honoured with the client library's
 `RetryPolicy`; a refused call that is retried is one sample, timed from
@@ -18,16 +22,17 @@ its first send.
 
 Protocol: started before the server is up, it reads `port <n>` on stdin,
 prints `ready` once every encoder has a frame waiting and it is
-connected, then reads `go`, `consumed <frames>` and `stop` lines; after
+connected, then reads `go`, `consumed <events>` and `stop` lines; after
 `stop` (or end of input) it finishes the call in flight, writes its log
 to `--log` and exits. The log is one JSON object:
-  calls        [first frame, frames, t_send, t_ack, retries, lsn, ok]
-               per call, times on CLOCK_MONOTONIC (shared with run.py)
+  calls        [first frame, frames, t_send, t_ack, retries, lsn, ok,
+               stream, events] per call, times on CLOCK_MONOTONIC
+               (shared with run.py)
   t_go, t_stop when the sender started and saw the stop
   encode_wait_s  time the sender sat waiting for an encoder (generator
                too slow), apart from time waiting for acks
   lead_wait_s  time it sat with frames in hand, waiting for the query to
-               come within `--max-lead-frames` of what was sent
+               come within `--max-lead-events` of what was sent
   error        the first failure, or null
 """
 
@@ -56,11 +61,12 @@ def encode_frame(ts, cols) -> bytes:
         columnar.encode_columnar(ts, cols, float_kind="f32"))
 
 
-def encoder_main(size: dict, seed: int, worker: int, n_workers: int,
-                 first: int, out_q, stop) -> None:
-    from benchmarks.harness import generator as gen
+def encoder_main(size: dict, home: str | None, seed: int, worker: int,
+                 n_workers: int, first: int, out_q, stop) -> None:
+    from benchmarks.harness import manifest
 
-    names = gen.key_names(size)
+    manifest.use(home)
+    gen = manifest.generator_of(size)
     i = first + worker
     parent = os.getppid()
 
@@ -68,11 +74,11 @@ def encoder_main(size: dict, seed: int, worker: int, n_workers: int,
         return stop.is_set() or os.getppid() != parent
 
     while not stopped():
-        ts, cols = gen.columns(size, names, gen.frame(size, seed, i))
+        stream, ts, cols, events = gen.frame(size, seed, i)
         data = encode_frame(ts, cols)
         while not stopped():
             try:
-                out_q.put((i, data), timeout=0.1)
+                out_q.put((i, stream, events, data), timeout=0.1)
                 break
             except queue.Full:
                 continue
@@ -80,7 +86,7 @@ def encoder_main(size: dict, seed: int, worker: int, n_workers: int,
 
 
 class Credit:
-    """What `run.py` relays: the server's port, and the measured frames
+    """What `run.py` relays: the server's port, and the measured events
     the query has consumed."""
 
     def __init__(self):
@@ -124,9 +130,12 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--first-frame", type=int, required=True)
     ap.add_argument("--encoders", type=int, required=True)
-    ap.add_argument("--max-lead-frames", type=int, default=0,
-                    help="send no frame further than this ahead of the "
-                         "query's consumption (0: as fast as acks allow)")
+    ap.add_argument("--max-lead-events", type=int, default=0,
+                    help="send no event further than this ahead of the "
+                         "query's consumption (0: as fast as acks allow; "
+                         "never under two calls)")
+    ap.add_argument("--manifest", default=None,
+                    help="a manifest in BENCHMARK.json's place (tests)")
     ap.add_argument("--log", required=True)
     args = ap.parse_args(argv)
     with open(args.size) as f:
@@ -138,8 +147,8 @@ def main(argv=None) -> int:
     queues = [mp.Queue(maxsize=QUEUE_DEPTH) for _ in range(n)]
     workers = [mp.Process(
         target=encoder_main, name=f"bench-encoder-{w}", daemon=True,
-        args=(size, args.seed, w, n, args.first_frame, queues[w],
-              stop_workers)) for w in range(n)]
+        args=(size, args.manifest, args.seed, w, n, args.first_frame,
+              queues[w], stop_workers)) for w in range(n)]
     for p in workers:
         p.start()
 
@@ -147,9 +156,12 @@ def main(argv=None) -> int:
     # takes longer than an encoder needs to make its first frame
     import grpc
 
+    from benchmarks.harness import manifest
     from hstream_tpu.client.producer import ColumnarProducer
     from hstream_tpu.client.retry import RetryPolicy
 
+    manifest.use(args.manifest)
+    gen = manifest.generator_of(size)
     retry = RetryPolicy(attempts=120)
     go, stop = threading.Event(), threading.Event()
     credit = Credit()
@@ -159,8 +171,7 @@ def main(argv=None) -> int:
 
     log = {"calls": [], "t_go": None, "t_stop": None,
            "encode_wait_s": 0.0, "lead_wait_s": 0.0, "error": None,
-           "frames_per_call": size["frames_per_call"],
-           "frame_rows": size["frame_rows"]}
+           "frames_per_call": size["frames_per_call"]}
     try:
         # ready: every encoder has made its first frame
         deadline = time.monotonic() + 120
@@ -175,33 +186,43 @@ def main(argv=None) -> int:
         channel = grpc.insecure_channel(
             f"127.0.0.1:{credit.port}",
             options=[("grpc.max_send_message_length", 64 << 20)])
-        producer = ColumnarProducer(channel, size["stream"])
+        producers = {st["name"]: ColumnarProducer(channel, st["name"])
+                     for st in gen.streams(size)}
         print("ready", flush=True)
         go.wait()
         log["t_go"] = time.monotonic()
         nxt = args.first_frame
         per_call = size["frames_per_call"]
+        sent = 0  # measured events sent, this call's included
         while not stop.is_set():
             t_w = time.monotonic()
-            frames = []
+            frames, streams, events = [], set(), 0
             for _ in range(per_call):
                 got = _take(queues[(nxt - args.first_frame) % n], stop)
                 if got is None:
                     break
-                i, data = got
+                i, stream, n_events, data = got
                 if i != nxt:
                     raise RuntimeError(f"frame {i} arrived, {nxt} due")
                 frames.append(data)
+                streams.add(stream)
+                events += n_events
                 nxt += 1
             if len(frames) < per_call:
                 break
+            if len(streams) != 1:
+                raise RuntimeError(
+                    f"the call of frames {nxt - per_call}..{nxt - 1} holds "
+                    f"frames of {sorted(streams)}: a call is of one stream")
+            stream = streams.pop()
+            sent += events
             t_e = time.monotonic()
             log["encode_wait_s"] += t_e - t_w
-            if args.max_lead_frames:
+            if args.max_lead_events:
                 # closed loop on the query: wait for it to come within
                 # the lead before sending further
-                while (nxt - args.first_frame
-                       > credit.consumed + args.max_lead_frames
+                lead = max(args.max_lead_events, 2 * events)
+                while (sent > credit.consumed + lead
                        and not stop.is_set()):
                     time.sleep(0.002)
             t_send = time.monotonic()
@@ -209,8 +230,9 @@ def main(argv=None) -> int:
             before = retry.retries
             ok, lsn = True, None
             try:
-                resp = retry.call(producer.append_stream_frames, frames)
-                ok = (resp.rows == per_call * size["frame_rows"]
+                resp = retry.call(producers[stream].append_stream_frames,
+                                  frames)
+                ok = (resp.rows == events
                       and len(resp.record_ids) == per_call)
                 lsn = int(resp.record_ids[-1].batch_id)
             except grpc.RpcError as e:
@@ -218,7 +240,8 @@ def main(argv=None) -> int:
                 log["error"] = f"{e.code()}: {e.details()}"
             t_ack = time.monotonic()
             log["calls"].append([nxt - per_call, per_call, t_send, t_ack,
-                                 retry.retries - before, lsn, ok])
+                                 retry.retries - before, lsn, ok, stream,
+                                 events])
             if not ok:
                 log["error"] = log["error"] or "short ack"
                 break

@@ -6,7 +6,10 @@ on the chip); nothing is imported from it, so a later PR may change it.
 
 From the program this takes only the system and its spans and counters:
 `stage_latency_ms` and `kernel_dispatch_ms` histograms, `pipe.stats()`,
-`close_stats`, `read_extracts`, the pipeline's count of batches stepped.
+`close_stats`, `read_extracts`, and the query's public count of what it
+has consumed, the `consumed_events` stat. A task whose executor has no
+ingest pipeline reads `pipe` as `{}`; one that never counts
+`consumed_events` reads 0.
 """
 
 from __future__ import annotations
@@ -34,7 +37,7 @@ class Client:
                      ("grpc.max_send_message_length", 64 << 20)])
         self.stub = HStreamApiStub(self.channel)
         self.retry = RetryPolicy(attempts=120)
-        self.frames_acked = 0
+        self.frames_acked: dict[str, int] = {}  # by stream
 
     def close(self) -> None:
         self.channel.close()
@@ -46,20 +49,27 @@ class Client:
         resp = self.stub.ExecuteQuery(pb.CommandQuery(stmt_text=text))
         return [rec.struct_to_dict(s) for s in resp.result_set]
 
-    def append_call(self, stream: str, batches: list[tuple]) -> None:
-        """One AppendColumnarStream call of one request message."""
+    def append_call(self, frames: list[tuple]) -> None:
+        """One AppendColumnarStream call of one request message: frames
+        of one stream, each (stream, ts, cols, events) as the
+        generator makes them."""
         from hstream_tpu.client.producer import ColumnarProducer, encode_batch
 
+        stream = frames[0][0]
+        if any(f[0] != stream for f in frames):
+            raise RuntimeError("a call holds frames of one stream, not of "
+                               f"{sorted({f[0] for f in frames})}")
         producer = ColumnarProducer(self.channel, stream)
-        frames = [encode_batch(ts, cols) for ts, cols in batches]
-        resp = self.retry.call(producer.append_stream_frames, frames)
-        rows = sum(len(ts) for ts, _cols in batches)
-        if resp.rows != rows or len(resp.record_ids) != len(batches):
+        data = [encode_batch(ts, cols) for _s, ts, cols, _n in frames]
+        resp = self.retry.call(producer.append_stream_frames, data)
+        rows = sum(n for _s, _ts, _cols, n in frames)
+        if resp.rows != rows or len(resp.record_ids) != len(frames):
             raise RuntimeError(
                 f"append to {stream}: acked {resp.rows} rows / "
                 f"{len(resp.record_ids)} frames, sent {rows} / "
-                f"{len(batches)}")
-        self.frames_acked += len(batches)
+                f"{len(frames)}")
+        self.frames_acked[stream] = (self.frames_acked.get(stream, 0)
+                                     + len(frames))
 
 
 def wait_for(pred, what: str, timeout: float, poll: float = 0.02):
@@ -86,13 +96,13 @@ def pipe_of(task):
         return task._pipe
 
 
-def consumed_frames(task) -> int:
-    """Frames through the task AND its ingest pipeline's step (what
-    `chip_smoke.wait_consumed` waits for, as a count). One frame is one
-    pipeline batch because the executor's batch capacity is sized to the
-    first frame it sees (`QueryTask._make_executor`)."""
-    pipe = pipe_of(task)
-    return 0 if pipe is None else int(pipe._take_seq)
+def consumed_events(ctx, task) -> int:
+    """Events the query has consumed, by the program's public count: the
+    `consumed_events` stat (`admin stats queries`), which a batch joins
+    when the task's ingest pipeline takes it to its step. 0 for a query
+    that never counted."""
+    return int(ctx.stats.stat_ladder("consumed_events",
+                                     task.info.query_id)["total"])
 
 
 def wait_consumed(ctx, task, timeout: float) -> None:
@@ -123,6 +133,19 @@ def log_payloads(ctx, stream: str) -> int:
         for b in got:
             if hasattr(b, "payloads"):
                 payloads += len(b.payloads)
+
+
+def acked_not_stored(ctx, streams: list[str], client_acked: dict,
+                     calls: list) -> int:
+    """Over every stream: the distance between the frames acknowledged
+    (to this process's client, and to the producer in its log's `calls`)
+    and the payloads on the stream's log."""
+    acked = dict(client_acked)
+    for c in calls:
+        if c[6]:
+            acked[c[7]] = acked.get(c[7], 0) + c[1]
+    return sum(abs(log_payloads(ctx, stream) - acked.get(stream, 0))
+               for stream in streams)
 
 
 def on_device(ctx, task, kind: str) -> dict:
@@ -163,7 +186,7 @@ def counters(ctx, task, view: str) -> dict:
         ex = task.executor
     return {
         "t": time.monotonic(),
-        "consumed_frames": consumed_frames(task),
+        "consumed_events": consumed_events(ctx, task),
         "histograms": hists,
         "pipe": dict(pipe.stats()) if pipe is not None else {},
         "close_stats": dict(getattr(ex, "close_stats", {})),
@@ -173,27 +196,27 @@ def counters(ctx, task, view: str) -> dict:
 
 
 class Reader(threading.Thread):
-    """One closed-loop reader: pull one seeded-random key, think, pull
-    again. Every pull is kept, with its times, for the comparison after
-    the window."""
+    """One closed-loop reader: pull the statement of one seeded draw of
+    the generator's (`reader_pull`), think, pull again. Every pull is
+    kept, with its times and what the draw says of it, for the
+    comparison after the window."""
 
-    def __init__(self, client: Client, view: str, key_col: str,
-                 names: np.ndarray, seed: int, think_s: float):
+    def __init__(self, client: Client, gen, size: dict, seed: int,
+                 think_s: float):
         super().__init__(name="bench-reader", daemon=True)
-        self.client, self.view, self.key_col = client, view, key_col
-        self.names, self.think_s = names, think_s
+        self.client, self.gen, self.size = client, gen, size
+        self.think_s = think_s
         self.rng = np.random.default_rng([int(seed), 2])
         self.stop_ev = threading.Event()
         self.pulls: list[dict] = []
         self.error: str | None = None
 
     def pull(self) -> dict:
-        k = int(self.rng.integers(0, len(self.names)))
+        draw = dict(self.gen.reader_pull(self.size, self.rng))
+        text = draw.pop("sql")
         t0 = time.monotonic()
-        rows = self.client.sql(
-            f"SELECT * FROM {self.view} WHERE {self.key_col} = "
-            f"'{self.names[k]}';")
-        return {"key": k, "t0": t0, "t1": time.monotonic(), "rows": rows}
+        rows = self.client.sql(text)
+        return {**draw, "t0": t0, "t1": time.monotonic(), "rows": rows}
 
     def run(self) -> None:
         while not self.stop_ev.is_set():
@@ -201,7 +224,7 @@ class Reader(threading.Thread):
                 self.pulls.append(self.pull())
             except Exception as e:  # noqa: BLE001 — reported, fatal
                 self.error = f"{type(e).__name__}: {e}"
-                self.pulls.append({"key": -1, "t0": time.monotonic(),
+                self.pulls.append({"t0": time.monotonic(),
                                    "t1": time.monotonic(), "rows": None})
                 return
             self.stop_ev.wait(self.think_s)

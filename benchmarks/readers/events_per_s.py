@@ -1,7 +1,8 @@
-"""Events consumed by the query over the window, per second of it."""
+"""Events consumed by the query over the window, per second of it: the
+difference of the program's public `consumed_events` count."""
 
 
 def read(run: dict, spec: dict):
-    frames = (run["end"]["consumed_frames"]
-              - run["start"]["consumed_frames"])
-    return frames * run["size"]["frame_rows"] / run["window_s"]
+    events = (run["end"]["consumed_events"]
+              - run["start"]["consumed_events"])
+    return events / run["window_s"]

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.harness import generator as gen
+from benchmarks.generators import sensor as gen
 from benchmarks.references._rows import lower, match
 
 
@@ -33,7 +33,7 @@ def pane_histograms(size: dict, seed: int, n_frames: int) -> dict:
     lo0, span = value_range(size)
     hists: dict[int, np.ndarray] = {}
     for i in range(n_frames):
-        kids, tenths, _temps, _ts = gen.frame(size, seed, i)
+        kids, tenths, _temps, _ts = gen.draw(size, seed, i)
         if tenths.min() < lo0 or tenths.max() >= lo0 + span:
             raise ValueError("value outside the reference's histogram")
         flat = kids.astype(np.int64) * span + (tenths - lo0)

@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.harness import generator as gen
+from benchmarks.generators import sensor as gen
 from benchmarks.references._rows import lower, match
 
 P = 10
@@ -64,7 +64,7 @@ def hll_estimate(regs: np.ndarray, n_keys: int) -> np.ndarray:
 
 def _frames(size: dict, seed: int, n_frames: int):
     for i in range(n_frames):
-        yield i, gen.pane_of(size, i), gen.frame(size, seed, i)
+        yield i, gen.pane_of(size, i), gen.draw(size, seed, i)
 
 
 def answers(size: dict, seed: int, n_frames: int, panes: set[int],

@@ -9,14 +9,22 @@ producer process of its own (`harness/producer.py`) and, where the
 traffic has a reader, `ExecuteQuery` pulls of the view. Phases:
 
   set-up   (`setup_s`, process start -> window start) device check,
-           compile cache placed, natives built if missing, stream and
-           view created, warm phase: every key named, one close cycle,
-           one snapshot landed, one pull
+           compile cache placed, natives built if missing, streams and
+           view created, the generator's warm frames consumed (for the
+           sensor generator: every key named, one close cycle), one
+           snapshot landed, one pull
   window   `--seconds` of load from an event-time window boundary; no
            program may compile inside it
   after    producer stopped, closer sent, task drained, answers pulled,
            guarantees read, server shut down, THEN the plain reference
            runs and the comparison decides `correct`
+
+Nothing here names a stream, a column or a window: the configuration
+names its generator (`benchmarks/generators/<name>.py`), which gives the
+streams to create, every frame, the closers, the statements to pull and
+the windows that must be complete; its `server` block gives the options
+`serve()` is started with; and what the query has consumed is the
+program's public `consumed_events` count.
 
 The last stdout line is the result (`harness/result.py` holds it to the
 driver's contract before printing it). `--dry 1` runs the
@@ -95,7 +103,7 @@ def build_natives() -> None:
 
 
 def start_producer(size: dict, traffic: dict, seed: int, tmp: str,
-                   first_frame: int) -> subprocess.Popen:
+                   first_frame: int, home: str | None) -> subprocess.Popen:
     """Started once this process holds the chip (beside JAX's own start
     its five processes' imports cost more than they save), so that its
     encoders spawn and fill their queues while the server boots and
@@ -110,8 +118,9 @@ def start_producer(size: dict, traffic: dict, seed: int, tmp: str,
          "--size", size_path, "--seed", str(seed),
          "--first-frame", str(first_frame),
          "--encoders", str(traffic["encoders"]),
-         "--max-lead-frames", str(lead_frames(size, traffic)),
-         "--log", os.path.join(tmp, "producer.json")],
+         "--max-lead-events", str(traffic.get("max_lead_events") or 0),
+         "--log", os.path.join(tmp, "producer.json"),
+         *(["--manifest", home] if home else [])],
         cwd=ROOT, env=env, stdin=subprocess.PIPE, stdout=subprocess.PIPE,
         text=True)
 
@@ -132,24 +141,14 @@ def stop_producer(proc: subprocess.Popen) -> None:
         proc.wait(30)
 
 
-def lead_frames(size: dict, traffic: dict) -> int:
-    """The traffic's `max_lead_events` in whole calls of this
-    configuration's frames (0: no bound)."""
-    events = traffic.get("max_lead_events") or 0
-    if not events:
-        return 0
-    per_call = size["frames_per_call"]
-    frames = events // size["frame_rows"]
-    return max(2 * per_call, frames // per_call * per_call)
-
-
-def relay_consumed(proc: subprocess.Popen, pipe, base: int,
+def relay_consumed(proc: subprocess.Popen, consumed, base: int,
                    stop: threading.Event) -> None:
-    """Tell the producer how many measured frames the query has
-    consumed, some fifty times a second."""
+    """Tell the producer how many measured events the query has
+    consumed (`consumed()`, the program's public count), some fifty
+    times a second."""
     last = -1
     while not stop.is_set():
-        n = int(pipe._take_seq) - base
+        n = consumed() - base
         if n != last:
             try:
                 proc.stdin.write(f"consumed {n}\n")
@@ -185,6 +184,7 @@ def memory_peak_bytes() -> int:
 def run(args) -> int:
     from benchmarks.harness import manifest
 
+    manifest.use(args.manifest)
     cell = manifest.cell(args.workload)
     size = manifest.size_of(cell["config"], bool(args.dry))
     tmp = tempfile.mkdtemp(prefix="bench_run_")
@@ -199,11 +199,11 @@ def run(args) -> int:
 
 def measure(args, cell: dict, size: dict, tmp: str,
             started: list) -> int:
-    from benchmarks.harness import generator as gen
     from benchmarks.harness import manifest, result, served
     from benchmarks.harness import trace as tr
 
     config, traffic, man = cell["config"], cell["traffic"], cell["manifest"]
+    gen = manifest.generator_of(config)
     dry = bool(args.dry)
     traced = bool(args.trace)
     seconds = float(args.seconds)
@@ -221,8 +221,12 @@ def measure(args, cell: dict, size: dict, tmp: str,
         raise SystemExit(f"the cell asks for {cell['chips']} chips, JAX "
                          f"reports {device['count']}")
     chips = device["count"] if dry else cell["chips"]
+    mesh = manifest.mesh_devices(config)
+    if not dry and mesh is not None and mesh != cell["chips"]:
+        raise SystemExit(f"the cell asks for {cell['chips']} chips, its "
+                         f"configuration's mesh for {mesh}")
     producer = start_producer(size, traffic, args.seed, tmp,
-                              gen.warm_frames(size))
+                              gen.warm_frames(size), args.manifest)
     started.append(producer)
     cache_dir = place_compile_cache()
     # keep every program: a warm run must load all of them (JAX's
@@ -237,9 +241,9 @@ def measure(args, cell: dict, size: dict, tmp: str,
     from hstream_tpu.common.tracing import RetraceGuard
     from hstream_tpu.server.main import serve
 
-    names = gen.key_names(size)
-    key_col = size["columns"][0]
-    view, stream = size["view"], size["stream"]
+    options = manifest.server_options(config, serve)
+    streams = [st["name"] for st in gen.streams(size)]
+    view = size["view"]
     n_warm = gen.warm_frames(size)
     per_call = size["frames_per_call"]
     info: dict = {"workload": args.workload, "seed": args.seed,
@@ -248,37 +252,41 @@ def measure(args, cell: dict, size: dict, tmp: str,
     info["setup_marks_s"] = marks  # seconds since process start
     server = ctx = client = reader = None
     try:
-        server, ctx = serve("127.0.0.1", 0, os.path.join(tmp, "store"))
+        server, ctx = serve("127.0.0.1", 0, os.path.join(tmp, "store"),
+                            **options)
         producer.stdin.write(f"port {ctx.port}\n")
         producer.stdin.flush()
         client = served.Client(ctx.port)
-        client.sql(f"CREATE STREAM {stream};")
+        for stream in streams:
+            client.sql(f"CREATE STREAM {stream};")
         client.sql(size["sql"])
         task = served.wait_task(ctx, f"view-{view}")
         marks["served_and_view"] = time.monotonic() - T_PROC
 
         # ---- warm phase: the cell's own shapes and no others ----------
+        biggest = 0  # events of the largest warm frame
         for lo in range(0, n_warm, per_call):
-            client.append_call(stream, [
-                gen.columns(size, names, gen.frame(size, args.seed, i))
-                for i in range(lo, lo + per_call)])
+            call = [gen.frame(size, args.seed, i)
+                    for i in range(lo, lo + per_call)]
+            biggest = max(biggest, *(f[3] for f in call))
+            client.append_call(call)
         marks["warm_sent"] = time.monotonic() - T_PROC
         served.wait_consumed(ctx, task, 900)
         marks["warm_consumed"] = time.monotonic() - T_PROC
         with task.state_lock:
             ex = task.executor
-        if ex.batch_capacity < size["frame_rows"]:
+        capacity = getattr(ex, "batch_capacity", None)
+        if capacity is not None and capacity < biggest:
             raise RuntimeError(
-                f"executor batch capacity {ex.batch_capacity} is under "
-                f"the frame's {size['frame_rows']} rows: a frame is not "
-                "one step")
+                f"executor batch capacity {capacity} is under the frame's "
+                f"{biggest} rows: a frame is not one step")
         served.wait_for(
             lambda: served.stage_count(ctx, "snapshot") >= 1
             and not getattr(task, "_persist_busy", False),
             "the first snapshot to land", 300)
         marks["snapshot_landed"] = time.monotonic() - T_PROC
         if traffic.get("readers"):
-            reader = served.Reader(client, view, key_col, names, args.seed,
+            reader = served.Reader(client, gen, size, args.seed,
                                    traffic["reader_think_ms"] / 1e3)
             reader.pull()
         wait_ready(producer, 180)
@@ -298,8 +306,8 @@ def measure(args, cell: dict, size: dict, tmp: str,
         relay_stop = threading.Event()
         relay = threading.Thread(
             target=relay_consumed, name="bench-relay", daemon=True,
-            args=(producer, served.pipe_of(task),
-                  start["consumed_frames"], relay_stop))
+            args=(producer, lambda: served.consumed_events(ctx, task),
+                  start["consumed_events"], relay_stop))
         producer.stdin.write("go\n")
         producer.stdin.flush()
         relay.start()
@@ -345,34 +353,37 @@ def measure(args, cell: dict, size: dict, tmp: str,
 
         # ---- after the window: drain, pull the answers ----------------
         n_frames = n_warm + sum(c[1] for c in plog["calls"] if c[6])
-        frames_acked = client.frames_acked + n_frames - n_warm
         served.wait_consumed(ctx, task, 900)
         after["drained"] = time.monotonic() - end["t"]
-        width = size["size_ms"] // size["advance_ms"]
-        last_pane = gen.pane_of(size, n_frames - 1)
-        newest_closed = last_pane - width
-        final = client.sql(
-            f"SELECT * FROM {view} WHERE winStart = "
-            f"{gen.BASE + newest_closed * size['advance_ms']};")
-        complete = [newest_closed] if final else []
-        first = {(r["winStart"], r[key_col]): r for r in final}
-        closer = gen.closer(size, names, last_pane)
-        client.append_call(stream, [closer])
-        frames_acked += 1
+        plan = gen.pulls(size, n_frames)
+        final: list[dict] = []
+        complete: list = []
+        seen: set = set()  # rows an earlier statement gave
+
+        def pull_answers(statements: list) -> None:
+            # a later statement may repeat an earlier one's window: a row
+            # both give alike counts once, one they give differently
+            # twice (and so does a row one statement gives twice)
+            for st in statements:
+                rows = client.sql(st["sql"])
+                whole = [tuple(sorted(r.items())) for r in rows]
+                final.extend(r for r, w in zip(rows, whole)
+                             if w not in seen)
+                seen.update(whole)
+                if rows or not st.get("if_rows"):
+                    complete.extend(m for m in st["complete"]
+                                    if m not in complete)
+
+        pull_answers(plan["before"])
+        for closer in gen.closers(size, n_frames):
+            client.append_call([closer])
         served.wait_consumed(ctx, task, 900)
         after["closer_consumed"] = time.monotonic() - end["t"]
-        # the second pull repeats the first one's window: a row both
-        # pulls give alike counts once, one they give differently twice
-        final += [r for r in client.sql(f"SELECT * FROM {view};")
-                  if first.get((r.get("winStart"), r.get(key_col))) != r]
+        pull_answers(plan["after"])
         after["answers_pulled"] = time.monotonic() - end["t"]
-        n_windows = last_pane + width
-        fit = max(1, size["view_rows_kept"] // size["keys"] - 1)
-        complete += [m for m in range(last_pane - min(n_windows, fit) + 1,
-                                      last_pane + 1) if m not in complete]
         guarantees = served.on_device(ctx, task, size["executor"])
-        guarantees["acked_not_stored"] = abs(
-            served.log_payloads(ctx, stream) - frames_acked)
+        guarantees["acked_not_stored"] = served.acked_not_stored(
+            ctx, streams, client.frames_acked, plog["calls"])
         guarantees["compiles_in_window"] = int(guard.count)
         after["guarantees_read"] = time.monotonic() - end["t"]
         info["compile"] = ledger.snapshot()
@@ -419,7 +430,7 @@ def measure(args, cell: dict, size: dict, tmp: str,
         size, args.seed, n_frames,
         {"final": final, "complete": complete,
          "pulls": [p for p in pulls if p["rows"] is not None],
-         "horizon": int(closer[0][0])})
+         "horizon": plan["horizon"]})
     numbers.update(guarantees)
     info["reference_s"] = time.monotonic() - t_ref
     limits = size["limits"]
@@ -492,6 +503,10 @@ def main(argv=None) -> int:
     ap.add_argument("--dry", type=int, choices=(0, 1), default=0,
                     help="the configuration's toy sizes on any backend "
                          "(tests only)")
+    ap.add_argument("--manifest", default=None,
+                    help="a manifest in BENCHMARK.json's place, relative "
+                         "to the checkout's root: files are looked for "
+                         "beside it first (tests only)")
     ap.add_argument("--out", default=None,
                     help="directory to keep the producer's log, run "
                          "info and the trace's description in")

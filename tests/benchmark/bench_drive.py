@@ -64,12 +64,20 @@ ref.answers = answers
 
 
 def drive(workload: str, seed: int, *, fault: str | None = None,
-          reference: str = "", seconds: float = 1.5, trace: int = 0):
-    """(exit code, the last stdout line parsed or None, stderr)."""
+          reference: str = "", seconds: float = 1.5, trace: int = 0,
+          manifest: str | None = None, devices: int = 1):
+    """(exit code, the last stdout line parsed or None, stderr).
+    `manifest`: a fixture manifest in BENCHMARK.json's place; `devices`:
+    virtual CPU devices for the run."""
     argv = ["--workload", workload, "--seed", str(seed), "--seconds",
             str(seconds), "--trace", str(trace), "--dry", "1"]
+    if manifest:
+        argv += ["--manifest", manifest]
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env["JAX_PLATFORMS"] = "cpu"
+    if devices > 1:
+        env["XLA_FLAGS"] = \
+            f"--xla_force_host_platform_device_count={devices}"
     if fault is None:
         cmd = [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),
                *argv]

@@ -4,7 +4,7 @@ import numpy as np
 import pytest
 
 from benchmarks import control
-from benchmarks.harness import generator as gen
+from benchmarks.generators import sensor as gen
 from benchmarks.harness import manifest
 from benchmarks.readers import _stages
 
@@ -23,9 +23,9 @@ def test_same_seed_same_frames_other_seed_other_frames(cell):
     _config, size = dry_size(cell)
     n_warm = gen.warm_frames(size)
     for i in (0, n_warm - 1, n_warm, n_warm + 17):
-        a = gen.frame(size, BIG_SEED, i)
-        b = gen.frame(size, BIG_SEED, i)
-        c = gen.frame(size, BIG_SEED + 1, i)
+        a = gen.draw(size, BIG_SEED, i)
+        b = gen.draw(size, BIG_SEED, i)
+        c = gen.draw(size, BIG_SEED + 1, i)
         assert all(np.array_equal(x, y) for x, y in zip(a, b))
         assert not all(np.array_equal(x, y) for x, y in zip(a, c))
         # every seed: the same sizes, in the same pane
@@ -43,7 +43,7 @@ def test_warm_frames_name_every_key_and_the_window_starts_on_a_pane(cell):
     assert n_warm % size["frames_per_call"] == 0
     seen = set()
     for i in range(n_warm):
-        kids, tenths, temps, _ts = gen.frame(size, 5, i)
+        kids, tenths, temps, _ts = gen.draw(size, 5, i)
         if gen.pane_of(size, i) == 0:
             seen |= set(kids.tolist())
         assert temps.dtype == np.float32 and len(kids) == size["frame_rows"]
@@ -65,9 +65,13 @@ def test_full_sizes_fill_whole_panes(cell):
         == size["events_per_advance"]
     names = gen.key_names(size)
     assert len(names) == size["keys"] and names[0] == "dev000000"
-    ts, cols = gen.closer(size, names, 7)
+    n_frames = gen.warm_frames(size) + 5 * gen.frames_per_pane(size) + 1
+    assert gen.pane_of(size, n_frames - 1) == 7
+    [(stream, ts, cols, events)] = gen.closers(size, n_frames)
+    assert stream == size["stream"] and events == 1 == len(ts)
     assert ts[0] == gen.BASE + 8 * size["advance_ms"] + size["size_ms"]
     assert list(cols) == size["columns"]
+    assert gen.pulls(size, n_frames)["horizon"] == ts[0]
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -102,7 +106,7 @@ def test_hop_reference_against_a_loop():
     width = size["size_ms"] // size["advance_ms"]
     by_pane: dict[int, list] = {}
     for i in range(n_frames):
-        kids, _tenths, temps, _ts = gen.frame(size, 4, i)
+        kids, _tenths, temps, _ts = gen.draw(size, 4, i)
         by_pane.setdefault(gen.pane_of(size, i), []).append((kids, temps))
     m = max(by_pane) - 1  # a window over several panes
     vals = [[] for _ in range(size["keys"])]
@@ -142,6 +146,5 @@ def test_encoders_make_the_client_librarys_bytes():
     from hstream_tpu.client.producer import encode_batch
 
     _config, size = dry_size(CELLS[0])
-    names = gen.key_names(size)
-    ts, cols = gen.columns(size, names, gen.frame(size, 3, 5))
+    _stream, ts, cols, _events = gen.frame(size, 3, 5)
     assert producer.encode_frame(ts, cols) == encode_batch(ts, cols)

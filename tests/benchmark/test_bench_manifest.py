@@ -51,12 +51,21 @@ def test_config_resolves_to_its_file(entry):
     for text in (entry["why"], entry["source"]):
         assert 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
     for key in ("sql", "stream", "view", "keys", "frame_rows",
-                "frames_per_call", "events_per_advance", "reference",
-                "limits", "guarantees", "assumed", "dry", "aggregates"):
+                "frames_per_call", "events_per_advance", "generator",
+                "reference", "limits", "guarantees", "assumed", "dry",
+                "aggregates"):
         assert key in config, key
     assert any(w["config"] == entry["name"] for w in MAN["workloads"])
     ref = manifest.reference_of(config)
     assert callable(ref.compare) and callable(ref.answers)
+    gen = manifest.generator_of(config)
+    for duty in ("streams", "warm_frames", "frame", "closers", "pulls",
+                 "reader_pull"):
+        assert callable(getattr(gen, duty)), duty
+    # the server block, where there is one, holds options serve() takes
+    from hstream_tpu.server.main import serve
+
+    manifest.server_options(config, serve)
     # a dry block never changes a shape, only the scale
     assert set(config["dry"]) <= {"keys", "events_per_advance",
                                   "frame_rows", "frames_per_call"}

@@ -20,10 +20,9 @@ from benchmarks.readers import program_ms_per_run, stage_share_pct
 MAN = manifest.manifest()
 BOUNDS = [0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0]
 
-# metric file -> the cells its label is observed in. Two of the seven
-# are entries of BENCHMARK.json; the other five wait for a harness
-# that prints a line without a metric the parent program cannot give
-# (PERF.md section 7).
+# metric file -> the cells its label is observed in. All are entries
+# of BENCHMARK.json since PR 28 (every parent since PR 25 has the
+# spans), each with the cells listed where it reads a number.
 STAGE_METRICS = {
     "task_key_encode_pct": "both",
     "task_state_wait_pct": "both",
@@ -33,7 +32,8 @@ STAGE_METRICS = {
     "pull_state_wait_p50_ms": "pull",
 }
 CELL_OF = {"pull": "sensor_hll_100k.replay_pull",
-           "hop": "sensor_hop_1k.replay"}
+           "hop": "sensor_hop_1k.replay",
+           "replay": "sensor_hll_100k.replay"}
 FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures",
                        "trace_slice_hll_100k.json")
 
@@ -172,6 +172,14 @@ def test_metric_file_names_what_the_program_declares(name):
     if entry is not None:
         for key in ("unit", "better", "source", "layer", "moves"):
             assert entry[key] == spec[key], key
+
+
+@pytest.mark.parametrize("name", sorted(STAGE_METRICS))
+def test_manifest_lists_a_stage_metric_where_its_label_is_observed(name):
+    entry = next(m for m in MAN["per_layer"] if m["name"] == name)
+    want = (set(CELL_OF.values()) if STAGE_METRICS[name] == "both"
+            else {CELL_OF[STAGE_METRICS[name]]})
+    assert set(entry["workloads"]) == want
 
 
 @pytest.fixture(scope="module", params=sorted(CELL_OF))
