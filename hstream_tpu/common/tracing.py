@@ -238,6 +238,13 @@ TRACE_STAGES = frozenset({
     "close_fetch", "close_decode",
     # the task's helper threads: encode workers, store prefetch
     "encode", "store_read",
+    # the device session path inside `step` (engine/session.py): the
+    # key-code dictionary, the host interval mirror (late walk,
+    # segmentation, chain merge), packing the batch, one close cycle
+    # and its halves, a code-space compaction
+    "session_key_codes", "session_mirror", "session_pack",
+    "session_close", "session_close_fetch", "session_close_decode",
+    "session_remap",
     # a pull, on its gRPC thread: asking for tasks.state -> holding it
     # -> released, then filter/project/sort outside the lock
     "pull_state_wait", "pull_hold", "pull_serve",
@@ -257,6 +264,11 @@ TRACE_STAGES = frozenset({
 TRACE_PARENT = {
     "ring_wait": "step", "stage_wait": "step", "close": "step",
     "close_fetch": "close", "close_decode": "close",
+    "session_key_codes": "step", "session_mirror": "step",
+    "session_pack": "step", "session_close": "step",
+    "session_close_fetch": "session_close",
+    "session_close_decode": "session_close",
+    "session_remap": "session_key_codes",
 }
 
 # kernel dispatch families (per-family dispatch histograms + recompile

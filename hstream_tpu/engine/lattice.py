@@ -62,6 +62,10 @@ EMPTY_START = -(1 << 31)  # slot_start sentinel for "slot unoccupied"
 STEP_PROGRAM = "jit_step"                    # build_step_encoded / _packed
 CLOSE_PROGRAM = "jit_extract_and_reset"      # build_extract_reset_slots
 PEEK_PROGRAM = "jit_peek_slots"              # build_extract_slots: it alone
+# the device session lattice's three (record mode)
+SESSION_STEP_PROGRAM = "jit_session_step"        # session_step_kernel
+SESSION_EXTRACT_PROGRAM = "jit_session_extract"  # session_extract_kernel
+SESSION_REMAP_PROGRAM = "jit_session_remap"      # session_remap_kernel
 
 # `jax.named_scope` of each aggregate's scatter inside the step (op
 # metadata only: the computation and its cache key do not change)
@@ -1359,7 +1363,8 @@ def session_step_kernel(spec, schema, layout: ColLayout, cap: int,
     agg_inputs, null_keys = compile_agg_inputs(spec, schema)
 
     @jax.jit
-    def step(arena, packed, gap, close_cut, delta):
+    def session_step(arena, packed, gap, close_cut, delta):
+        # SESSION_STEP_PROGRAM
         codes_b, ts_b, valid, cols = unpack_batch_device(
             packed, layout, null_keys)
         acode = arena["code"]
@@ -1449,7 +1454,7 @@ def session_step_kernel(spec, schema, layout: ColLayout, cap: int,
                 raise NotImplementedError(f"session agg {agg.kind}")
         return out
 
-    return step
+    return session_step
 
 
 @functools.lru_cache(maxsize=256)
@@ -1534,7 +1539,7 @@ def session_extract_kernel(spec, cap: int, pcap: int):
     close cycle or peek, exactly like the fused window close."""
 
     @jax.jit
-    def extract(arena, slots):
+    def session_extract(arena, slots):  # SESSION_EXTRACT_PROGRAM
         ok = slots >= 0
         at = jnp.where(ok, slots, 0)
         rows = [jnp.where(ok, arena["code"][at], SESSION_SENT_CODE)]
@@ -1570,7 +1575,7 @@ def session_extract_kernel(spec, cap: int, pcap: int):
                 jnp.where(ok, v, 0.0), jnp.int32))
         return jnp.stack(rows)
 
-    return extract
+    return session_extract
 
 
 @functools.lru_cache(maxsize=64)
@@ -1581,14 +1586,14 @@ def session_remap_kernel(cap: int, lcap: int):
     t0)-sorted across the remap. One dispatch, no fetch."""
 
     @jax.jit
-    def remap(arena, lut):
+    def session_remap(arena, lut):  # SESSION_REMAP_PROGRAM
         code = arena["code"]
         out = dict(arena)
         out["code"] = jnp.where(code < lcap,
                                 lut[jnp.clip(code, 0, lcap - 1)], code)
         return out
 
-    return remap
+    return session_remap
 
 
 @jax.jit

@@ -44,7 +44,11 @@ from hstream_tpu.common.columnar import ColumnarEmit, extend_rows
 from hstream_tpu.common.errors import SQLCodegenError
 from hstream_tpu.common.faultinject import FAULTS
 from hstream_tpu.common.logger import get_logger
-from hstream_tpu.common.tracing import kernel_family
+from hstream_tpu.common.tracing import (
+    begin_span,
+    kernel_family,
+    trace_span,
+)
 from hstream_tpu.engine.executor import _READ_NONCE, QueryExecutor
 from hstream_tpu.engine.expr import (
     columns_of,
@@ -344,6 +348,11 @@ class SessionExecutor:
         # host mirror), and H2D/D2H byte totals — all host values the
         # owning task mirrors into /metrics
         self.dispatch_observer = None   # callable (family, seconds)
+        # the owning task's QueryTracer: the device path's stages
+        # (session_key_codes, session_mirror, session_pack,
+        # session_close {session_close_fetch, session_close_decode},
+        # session_remap) land in stage_latency_ms beside the task's own
+        self.tracer = None
         self.late_drops = 0
         self.transfer_stats = {"h2d_bytes": 0, "d2h_bytes": 0}
         self.dicts: dict[str, StringDictionary] = {
@@ -359,6 +368,17 @@ class SessionExecutor:
         self._read_nonce = next(_READ_NONCE)
 
     # QueryExecutor._extract_filter reads self.node only.
+
+    def input_columns(self) -> frozenset:
+        """The record columns this plan reads: group keys, WHERE
+        operands, aggregate inputs. The task feeds these alone."""
+        cols = set(self.group_cols)
+        if self._filter is not None:
+            cols |= columns_of(self._filter)
+        for a in self.aggs:
+            if a.input is not None:
+                cols |= columns_of(a.input)
+        return frozenset(cols)
 
     def _agg_input(self, agg: AggSpec, row: Mapping[str, Any]):
         if agg.input is None:
@@ -504,26 +524,50 @@ class SessionExecutor:
     # growing without limit after its sessions closed
     _KEY_CACHE_MAX = 1 << 18
 
-    def _bound_key_cache(self) -> None:
-        """Cache-bound enforcement: host mode drops the caches wholesale
-        (codes only matter within one batch there); device mode must
-        keep codes of keys with LIVE arena sessions stable, so it
-        compacts through the order-preserving remap kernel instead."""
-        if len(self._code_of) <= self._KEY_CACHE_MAX:
+    def _bound_key_cache(self, n: int) -> None:
+        """Cache-bound enforcement before `n` rows mint codes: host mode
+        drops the caches wholesale (codes only matter within one batch
+        there); device mode must keep codes of keys with LIVE arena
+        sessions stable, so it compacts through the order-preserving
+        remap kernel instead. A compaction frees only the codes of
+        closed sessions, so on the device the bound is never under twice
+        the open sessions: at least half of what a compaction walks is
+        then dead, where a live set of the bound's own size would
+        compact with every batch and free nothing. Whatever the live
+        set, no code reaches `lattice.SESSION_SENT_CODE`, which the
+        kernels read as an empty slot: the dictionary is compacted
+        before this batch could mint one, and where the open sessions'
+        own codes leave no room the executor degrades to the host."""
+        size = len(self._code_of)
+        if self._dev is None:
+            if size > self._KEY_CACHE_MAX:
+                self._code_of = {}
+                self._code_rev = []
+                self._raw_memo = {}
+                self._code_cols_cache = (-1, [])
             return
-        if self._dev is not None:
+        from hstream_tpu.engine import lattice
+
+        # the next code minted is len(_code_rev): a sharded compaction
+        # leaves holes, so it can lie above the count of codes
+        sent = lattice.SESSION_SENT_CODE
+        if len(self._code_rev) + n < sent and (
+                size <= self._KEY_CACHE_MAX
+                or size <= 2 * int(self._dev["mir_live"].sum())):
+            return
+        with trace_span(self.tracer, "session_remap"):
             self._compact_codes_device()
-        else:
-            self._code_of = {}
-            self._code_rev = []
-            self._raw_memo = {}
-            self._code_cols_cache = (-1, [])
+        if self._dev is not None and len(self._code_rev) + n >= sent:
+            self._degrade_to_host(
+                f"the codes of {len(self._code_of)} open keys and a "
+                f"batch of {n} rows leave none under the arena's "
+                f"sentinel {sent}")
 
     def _key_codes(self, rows) -> tuple[np.ndarray, list]:
         """Dense int codes per row's group key. Codes persist across
         batches (encoding cache only — not part of snapshot state);
         raw-value memoization keeps the per-row cost to one dict hit."""
-        self._bound_key_cache()
+        self._bound_key_cache(len(rows))
         out = np.empty(len(rows), np.int64)
         rev = self._code_rev
         if len(self.group_cols) == 1:
@@ -1157,10 +1201,12 @@ class SessionExecutor:
             "mir_live": np.ones(n, np.bool_),
             "bcaps": set(),
             "scaps": set(),
+            "pcaps": set(),
         }
         self.epoch = epoch
         self.sessions = {}
         self.read_epoch += 1
+        self._warm_remap()
 
     def _degrade_to_host(self, reason: str) -> None:
         """Pull the device state back into the host session dict and pin
@@ -1186,10 +1232,12 @@ class SessionExecutor:
 
     # contract: dispatches<=0 fetches<=1
     def _host_sessions_view(self) -> dict[tuple, list[_Session]]:
-        """Host-format view of the device arena (snapshot serialization
-        and the degrade path): ONE pytree fetch, then per-live-slot acc
-        decode into the reference accumulator formats."""
+        """Host-format view of the device arena (the degrade path): ONE
+        pytree fetch, then per-live-slot acc decode into the reference
+        accumulator formats."""
         import jax
+
+        from hstream_tpu.engine import lattice
 
         dev = self._dev
         host = jax.device_get(dev["arena"])
@@ -1199,33 +1247,74 @@ class SessionExecutor:
             # single-chip arena below
             cls, sl = self._shard_slots()
             host = {k: v[cls, sl] for k, v in host.items()}
-        spec = dev["spec"]
-        sessions: dict[tuple, list[_Session]] = {}
-        from hstream_tpu.engine import lattice
+        rows = np.nonzero(dev["mir_live"])[0]
+        return self.sessions_from_rows(
+            self.aggs, lattice.session_plane_names(dev["spec"]),
+            [self._code_rev[c] for c in dev["mir_code"][rows].tolist()],
+            dev["mir_t0"][rows], dev["mir_t1"][rows],
+            {k: v[rows] for k, v in host.items()})
 
-        for slot in np.nonzero(dev["mir_live"])[0].tolist():
-            key = self._code_rev[int(dev["mir_code"][slot])]
+    @staticmethod
+    def sessions_from_rows(aggs, plane_names, keys, t0, t1, planes
+                           ) -> dict[tuple, list[_Session]]:
+        """The host engine's session dict from arena rows: row i is the
+        session [t0[i], t1[i]] of key `keys[i]`, its accumulators at
+        `planes[name][i]` (`plane_names[j]`: the plane of aggregate j).
+        Shared by the degrade path and the restore of a device capture."""
+        sessions: dict[tuple, list[_Session]] = {}
+        for i, key in enumerate(keys):
             accs: dict[str, Any] = {}
-            for name, a in zip(lattice.session_plane_names(spec),
-                               spec.aggs):
-                v = host[name][slot]
+            for name, a in zip(plane_names, aggs):
+                v = planes[name][i]
                 if a.kind in (AggKind.COUNT_ALL, AggKind.COUNT):
                     accs[a.out_name] = int(v)
-                elif a.kind == AggKind.SUM:
-                    accs[a.out_name] = float(v)
                 elif a.kind == AggKind.AVG:
                     accs[a.out_name] = (float(v),
-                                        int(host[name + "_n"][slot]))
-                elif a.kind in (AggKind.MIN, AggKind.MAX):
+                                        int(planes[name + "_n"][i]))
+                elif a.kind in (AggKind.SUM, AggKind.MIN, AggKind.MAX):
                     accs[a.out_name] = float(v)
                 elif a.kind == AggKind.APPROX_COUNT_DISTINCT:
                     accs[a.out_name] = np.asarray(v, np.int8).copy()
                 elif a.kind == AggKind.APPROX_QUANTILE:
                     accs[a.out_name] = np.asarray(v, np.int64).copy()
-            sessions.setdefault(key, []).append(_Session(
-                start=int(dev["mir_t0"][slot]),
-                end=int(dev["mir_t1"][slot]), accs=accs))
+            sessions.setdefault(tuple(key), []).append(_Session(
+                start=int(t0[i]), end=int(t1[i]), accs=accs))
         return sessions
+
+    # contract: dispatches<=0 fetches<=0
+    def capture_device(self) -> tuple[dict, dict[str, Any]]:
+        """A consistent capture of the device-resident sessions for a
+        snapshot, cheap enough for the owner's state lock (phase 1 of
+        engine/snapshot.py): the arena's accumulator planes BY
+        REFERENCE (jax arrays are immutable; a step replaces the dict),
+        the open mirror rows copied, and one key a distinct code. No
+        fetch and no per-session Python object: at 336 000 open sessions
+        the host-format view took 4.6 s under the lock and its blob
+        seconds more to persist. `sessions_from_rows` turns the capture
+        back into the host engine's sessions at restore, so the blob is
+        as mesh-portable as the host format it replaces."""
+        from hstream_tpu.engine import lattice
+
+        dev = self._dev
+        rows = np.nonzero(dev["mir_live"])[0]
+        codes, key_of_row = np.unique(dev["mir_code"][rows],
+                                      return_inverse=True)
+        arrays: dict[str, Any] = {
+            "sess.key": key_of_row.astype(np.int64),
+            "sess.t0": dev["mir_t0"][rows].copy(),
+            "sess.t1": dev["mir_t1"][rows].copy(),
+        }
+        if dev.get("ssl") is not None:
+            cls, sl = self._shard_slots()
+            arrays["sess.shard"] = cls[rows]
+            arrays["sess.slot"] = sl[rows]
+        else:
+            arrays["sess.slot"] = rows
+        for name, plane in dev["arena"].items():
+            if name not in ("code", "t0", "t1"):
+                arrays["sess.plane." + name] = plane
+        return {"keys": [self._code_rev[c] for c in codes.tolist()],
+                "planes": lattice.session_plane_names(dev["spec"])}, arrays
 
     def _shard_slots(self) -> tuple[np.ndarray, np.ndarray]:
         """(key shard, per-shard arena slot) of every mirror row: each
@@ -1258,7 +1347,8 @@ class SessionExecutor:
                 ts = ts[idx]
         if not rows:
             return self._advance_and_close_device(pre_max)
-        codes, _rev = self._key_codes(rows)
+        with trace_span(self.tracer, "session_key_codes"):
+            codes, _rev = self._key_codes(rows)
         if self._dev is None:  # the key-cache bound degraded mid-encode
             return _DEGRADED
         if self._dev["mode"] == "record":
@@ -1342,7 +1432,8 @@ class SessionExecutor:
                 if len(ts) == 0:
                     return self._advance_and_close_device(pre_max)
         nk = n if kept is None else len(kept)
-        codes = self._key_codes_cols(cols, nulls, kept, nk)
+        with trace_span(self.tracer, "session_key_codes"):
+            codes = self._key_codes_cols(cols, nulls, kept, nk)
         if self._dev is None:  # the key-cache bound degraded mid-encode
             return _DEGRADED
         if self._dev["mode"] == "record":
@@ -1429,7 +1520,7 @@ class SessionExecutor:
         DISTINCT value/combination — the _columnar_key_ids discipline);
         object columns fall back to the memoized per-row loop.
         Null-masked group cells decode as None."""
-        self._bound_key_cache()
+        self._bound_key_cache(n)
         if not self.group_cols:  # global session: one key ()
             k = canon_key(())
             code = self._code_of.get(k)
@@ -1610,6 +1701,7 @@ class SessionExecutor:
         grace = self.window.grace_ms
         n = len(codes)
         self.session_stats["batches"] += 1
+        mirror = begin_span(self.tracer, "session_mirror")
         if n and self.watermark >= 0 \
                 and int(ts.min()) + gap + grace <= self.watermark:
             keep = self._late_keep_mask(codes, ts)
@@ -1644,6 +1736,7 @@ class SessionExecutor:
                 np.concatenate([dev["mir_t0"][live], seg_t0]),
                 np.concatenate([dev["mir_t1"][live], seg_t1]),
                 gap, n_first=int(live.sum()))
+            mirror.end()
             if fanin > self.chain_merge_limit:
                 self._degrade_to_host(
                     f"one session chain merged {fanin} open sessions "
@@ -1701,6 +1794,7 @@ class SessionExecutor:
             dev["mir_t0"] = mt0
             dev["mir_t1"] = mt1
             dev["mir_live"] = np.ones(len(mcode), np.bool_)
+        mirror.end()  # a batch the late walk emptied
         return self._advance_and_close_device(pre_max)
 
     @staticmethod
@@ -1725,20 +1819,21 @@ class SessionExecutor:
         dev = self._dev
         _tag, cols, nulls = feed
         n = len(codes)
-        ts_rel = (ts - self.epoch).astype(np.int64)
-        bcap = self._dev_bcap(n)
-        null_masks = []
-        for refs in dev["null_refs"]:
-            m = np.zeros(n, np.bool_)
-            if nulls:
-                for c in refs:
-                    nm = nulls.get(c)
-                    if nm is not None:
-                        m |= np.asarray(nm, np.bool_)[:n]
-            null_masks.append(m if m.any() else None)
-        packed = lattice.pack_batch_host(
-            bcap, n, codes.astype(np.int32), ts_rel, None, cols,
-            null_masks, dev["layout"])
+        with trace_span(self.tracer, "session_pack"):
+            ts_rel = (ts - self.epoch).astype(np.int64)
+            bcap = self._dev_bcap(n)
+            null_masks = []
+            for refs in dev["null_refs"]:
+                m = np.zeros(n, np.bool_)
+                if nulls:
+                    for c in refs:
+                        nm = nulls.get(c)
+                        if nm is not None:
+                            m |= np.asarray(nm, np.bool_)[:n]
+                null_masks.append(m if m.any() else None)
+            packed = lattice.pack_batch_host(
+                bcap, n, codes.astype(np.int32), ts_rel, None, cols,
+                null_masks, dev["layout"])
         self.transfer_stats["h2d_bytes"] += int(
             getattr(packed, "nbytes", 0))
         ssl = dev.get("ssl")
@@ -1971,6 +2066,46 @@ class SessionExecutor:
                 dev["spec"], dev["arena"], new_cap)
         dev["cap"] = new_cap
         self.session_stats["grows"] += 1
+        self._warm_remap()
+
+    def _remap_lcap(self, codes: int) -> int:
+        """Width of the remap LUT for a dictionary of `codes` codes:
+        never under four times the arena's capacity. A dictionary is
+        compacted at twice the open sessions (`_bound_key_cache`), so
+        where the live set sets the bound the LUT has this ONE width
+        for as long as the arena keeps its capacity, and the program
+        `_warm_remap` compiled then is the one a compaction runs."""
+        dev = self._dev
+        ssl = dev.get("ssl")
+        total = dev["cap"] * (ssl.n_shards if ssl is not None else 1)
+        return round_up_pow2(max(codes, 1), lo=max(4 * total, 256))
+
+    def _remap_arena(self, lut: np.ndarray):
+        """The arena with its codes sent through `lut`: one dispatch."""
+        import jax
+
+        from hstream_tpu.engine import lattice
+
+        dev = self._dev
+        ssl = dev.get("ssl")
+        if ssl is not None:
+            self.sharded_dispatches += 1
+            return ssl.remap(dev["arena"], jax.device_put(lut))
+        kern = lattice.session_remap_kernel(dev["cap"], len(lut))
+        return kern(dev["arena"], jax.device_put(lut))
+
+    def _warm_remap(self) -> None:
+        """Compile the remap program an arena of this capacity will run,
+        with an identity LUT, as the arena takes the capacity (activation
+        or growth: shapes compile there anyway). Only where the live set
+        can set the bound (this width above `_KEY_CACHE_MAX`): a small
+        arena under a large dictionary compacts at the dictionary's own
+        width, once, as before."""
+        lcap = self._remap_lcap(0)
+        if lcap <= self._KEY_CACHE_MAX:
+            return
+        self._dev["arena"] = self._remap_arena(
+            np.arange(lcap, dtype=np.int32))
 
     # contract: dispatches<=1 fetches<=0
     def _compact_codes_device(self) -> None:
@@ -1979,8 +2114,6 @@ class SessionExecutor:
         arena stays (code, t0)-sorted), remap the arena through the
         pow2-padded LUT kernel — one dispatch, no fetch. Dead codes map
         to the sentinel, so the remap doubles as eviction."""
-        import jax
-
         from hstream_tpu.engine import lattice
 
         dev = self._dev
@@ -1995,8 +2128,8 @@ class SessionExecutor:
               for arr in self._code_rev_columns()])
             for codes, t0, t1, packed, keys in self._pending_closes]
         live_codes = np.unique(dev["mir_code"][live]).astype(np.int64)
-        lcap = round_up_pow2(max(len(self._code_rev), 1), lo=256)
-        lut = np.full(lcap, lattice.SESSION_SENT_CODE, np.int32)
+        lut = np.full(self._remap_lcap(len(self._code_rev)),
+                      lattice.SESSION_SENT_CODE, np.int32)
         ssl = dev.get("ssl")
         if ssl is not None:
             # residue-class-preserving compaction (new % n_shards ==
@@ -2013,13 +2146,7 @@ class SessionExecutor:
             new_of = np.arange(len(live_codes), dtype=np.int64)
         lut[live_codes] = new_of.astype(np.int32)
         try:
-            if ssl is not None:
-                dev["arena"] = ssl.remap(dev["arena"],
-                                         jax.device_put(lut))
-                self.sharded_dispatches += 1
-            else:
-                kern = lattice.session_remap_kernel(dev["cap"], lcap)
-                dev["arena"] = kern(dev["arena"], jax.device_put(lut))
+            dev["arena"] = self._remap_arena(lut)
         except Exception as e:  # noqa: BLE001 — arena unchanged
             # (functional update): the host engine continues with the
             # un-remapped caches; the device caller re-checks _dev
@@ -2068,6 +2195,13 @@ class SessionExecutor:
         idx = np.nonzero(due)[0]
         if len(idx) == 0:
             return []
+        with trace_span(self.tracer, "session_close"):
+            return self._close_cycle_device(idx)
+
+    # contract: dispatches<=1 fetches<=1
+    def _close_cycle_device(self, idx: np.ndarray):
+        """One close cycle over the due mirror rows `idx`."""
+        dev = self._dev
         self.session_stats["close_cycles"] += 1
         # the mirror rows are snapshotted NOW: the mirror mutates on the
         # next step, the deferred decode must not see that
@@ -2076,7 +2210,7 @@ class SessionExecutor:
         t1 = dev["mir_t1"][idx].copy()
         self.session_stats["close_dispatches"] += 1
         try:
-            packed_dev = self._dispatch_extract(idx)
+            packed_dev = self._dispatch_extract(idx, sticky=True)
         except Exception as e:  # noqa: BLE001 — nothing retired yet:
             # the host engine closes the same due set from the pulled-
             # back state (a FETCH failure later still propagates — by
@@ -2093,13 +2227,20 @@ class SessionExecutor:
                                          None))
             return []
         self.session_stats["close_fetches"] += 1
-        packed_host = np.asarray(packed_dev)
+        with trace_span(self.tracer, "session_close_fetch"):
+            packed_host = np.asarray(packed_dev)
         self.transfer_stats["d2h_bytes"] += packed_host.nbytes
-        return self._decode_close(packed_host, codes, t0, t1)
+        with trace_span(self.tracer, "session_close_decode"):
+            return self._decode_close(packed_host, codes, t0, t1)
 
-    def _dispatch_extract(self, idx: np.ndarray):
+    def _dispatch_extract(self, idx: np.ndarray, *, sticky: bool = False):
         """One pow2-padded extract dispatch over the named arena slots;
-        returns the packed device value (the caller fetches or defers)."""
+        returns the packed device value (the caller fetches or defers).
+        `sticky` (close cycles): the padded width is one this arena has
+        used before where that wastes under 8x, so a stream that closes
+        a few sessions with every batch keeps ONE compiled shape instead
+        of crossing a power of two now and then; a peek, which names
+        every open session, pads to its own size."""
         from hstream_tpu.engine import lattice
 
         dev = self._dev
@@ -2128,7 +2269,12 @@ class SessionExecutor:
                                ready=_ready):
                 res = ssl.extract(dev["arena"], slots)
             return res
-        slots = lattice.pad_slots(idx)
+        if sticky:
+            slots = np.full(self._sticky_cap(dev["pcaps"], len(idx), 256),
+                            -1, np.int32)
+            slots[:len(idx)] = idx
+        else:
+            slots = lattice.pad_slots(idx)
         kern = lattice.session_extract_kernel(dev["spec"], dev["cap"],
                                               len(slots))
         res = None
@@ -2199,6 +2345,18 @@ class SessionExecutor:
         after the dispatch under measurement replaced it."""
         dev = self._dev
         return dev["arena"] if dev is not None else ()
+
+    # contract: dispatches<=0 fetches<=0
+    def session_gauges(self) -> dict[str, int]:
+        """`session_stats`, and while the arena is on the device the
+        open sessions (`live`) and its capacity in slots (`arena_cap`):
+        what `admin stats queries` shows of a session query."""
+        out = dict(self.session_stats)
+        dev = self._dev
+        if dev is not None:
+            out["live"] = int(dev["mir_live"].sum())
+            out["arena_cap"] = int(dev["cap"])
+        return out
 
     # contract: dispatches<=0 fetches<=0
     def device_plane_bytes(self) -> dict[str, int]:

@@ -151,7 +151,7 @@ def capture_executor(ex, extra: dict | None = None
     if isinstance(ex, QueryExecutor):
         meta, arrays = _lattice_state(ex)
     elif isinstance(ex, SessionExecutor):
-        meta, arrays = _session_state(ex), {}
+        meta, arrays = _session_state(ex)
     elif isinstance(ex, TableJoinExecutor):
         meta, arrays = _table_join_state(ex)
     elif isinstance(ex, JoinExecutor):
@@ -205,7 +205,7 @@ def restore_executor(plan, blob: bytes, *, initial_keys: int = 1024,
         ex = _restore_lattice(plan.node, meta, arrays,
                               batch_capacity=batch_capacity, mesh=mesh)
     elif kind == "session":
-        ex = _restore_session(plan.node, meta, mesh=mesh)
+        ex = _restore_session(plan.node, meta, arrays, mesh=mesh)
     elif kind == "stateless":
         from hstream_tpu.engine.stateless import StatelessExecutor
 
@@ -360,38 +360,41 @@ def _scatter_state(ex, canonical: dict[str, np.ndarray]):
 
 # ---- session ----------------------------------------------------------------
 
-def _session_state(ex) -> dict:
+def _session_state(ex) -> tuple[dict, dict[str, Any]]:
     if getattr(ex, "_pending_closes", None):
         # the deferred extract buffers are the ONLY copy of those
         # closed-session rows (mirror entries already retired)
         raise SQLCodegenError(
             "snapshot with deferred session closes pending; "
             "drain_closed() first")
-    # device-resident sessions serialize through the host-format view
-    # (one pytree fetch + acc decode); restore rebuilds the host engine
-    # and the device path re-activates and re-migrates lazily on the
-    # next batch, like the join store
-    src = (ex._host_sessions_view()
-           if getattr(ex, "_dev", None) is not None else ex.sessions)
-    sessions = [
-        {"k": _enc(key),
-         "s": [{"a": s.start, "b": s.end, "acc": _enc(s.accs)}
-               for s in sess_list]}
-        for key, sess_list in src.items()
-    ]
-    return {
+    meta = {
         "kind": "session",
         "watermark": ex.watermark,
         "emit_changes": ex.emit_changes,
         "schema": [[n, t.value] for n, t in ex.schema.fields],
-        "sessions": sessions,
     }
+    if getattr(ex, "_dev", None) is not None:
+        # device-resident sessions are captured as columns (planes by
+        # reference, mirror rows, one key a code): the fetch and the
+        # packing are phase 2's, off the lock; restore rebuilds the host
+        # engine and the device path re-activates and re-migrates lazily
+        # on the next batch, like the join store
+        meta["device"], arrays = ex.capture_device()
+        return meta, arrays
+    meta["sessions"] = [
+        {"k": _enc(key),
+         "s": [{"a": s.start, "b": s.end, "acc": _enc(s.accs)}
+               for s in sess_list]}
+        for key, sess_list in ex.sessions.items()
+    ]
+    return meta, {}
 
 
-def _restore_session(node, meta, mesh=None):
-    """Session snapshots are mesh-portable: the blob holds the gathered
-    host view, so restoring with a different `mesh` (or none) just
-    re-shards when the device path re-activates on the next batch."""
+def _restore_session(node, meta, arrays, mesh=None):
+    """Session snapshots are mesh-portable: the blob holds the host
+    view, or the arena's rows with each row's shard and slot, so
+    restoring with a different `mesh` (or none) just re-shards when the
+    device path re-activates on the next batch."""
     from hstream_tpu.engine.session import SessionExecutor, _Session
 
     schema = Schema(tuple((n, ColumnType(t)) for n, t in meta["schema"]))
@@ -399,6 +402,18 @@ def _restore_session(node, meta, mesh=None):
     ex = SessionExecutor(node, schema, emit_changes=meta["emit_changes"],
                          **kw)
     ex.watermark = meta["watermark"]
+    cap = meta.get("device")
+    if cap is not None:
+        at = (arrays["sess.slot"],) if "sess.shard" not in arrays \
+            else (arrays["sess.shard"], arrays["sess.slot"])
+        head = "sess.plane."
+        ex.sessions = SessionExecutor.sessions_from_rows(
+            ex.aggs, cap["planes"],
+            [cap["keys"][k] for k in arrays["sess.key"].tolist()],
+            arrays["sess.t0"], arrays["sess.t1"],
+            {name[len(head):]: plane[at]
+             for name, plane in arrays.items() if name.startswith(head)})
+        return ex
     for ent in meta["sessions"]:
         key = tuple(_dec(ent["k"]))
         ex.sessions[key] = [

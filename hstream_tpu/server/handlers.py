@@ -1326,6 +1326,10 @@ class HStreamApiServicer:
                     lad = ctx.stats.stat_ladder(f.name, key)
                     row[f"{f.name}_per_s"] = round(lad[interval], 3)
                     row[f"{f.name}_total"] = lad["total"]
+                if scope == "query":
+                    task = ctx.running_queries.get(key)
+                    if task is not None:
+                        row.update(task.engine_gauges())
                 out[key] = row
         elif cmd == "cluster-stats":
             # federation (ISSUE 15): fan the ClusterStats RPC out to

@@ -225,10 +225,12 @@ class QueryTask(threading.Thread):
                 pass           # the ingest loop
 
     def _note_consumed(self, events: int) -> None:
-        """The ingest pipeline's `on_stepped`: one batch of `events`
-        taken to step. `consumed_events` is the public count of what
-        the query has consumed (`admin stats queries`, /metrics): a
-        replay client bounds its own lead by it."""
+        """One batch of `events` taken to step: the ingest pipeline's
+        `on_stepped`, and the session and join paths, which have no
+        pipeline, as they hand a batch to the executor.
+        `consumed_events` is the public count of what the query has
+        consumed (`admin stats queries`, /metrics): a replay client
+        bounds its own lead by it."""
         stats = getattr(self.ctx, "stats", None)
         if stats is not None:
             try:
@@ -373,6 +375,22 @@ class QueryTask(threading.Thread):
             return {}
         try:
             return fn()
+        except Exception:  # noqa: BLE001 — a half-built executor must
+            return {}      # not kill the stats sweep
+
+    def engine_gauges(self) -> dict[str, int]:
+        """The executor's own counts for `admin stats queries`, beside
+        `consumed_events`: for a session executor its `session_stats`,
+        the open sessions and the arena's capacity, each named
+        `session_<what>`. Host ints, no dispatch; {} for an executor
+        that states none."""
+        with self.state_lock:  # executor is guarded (hstream-analyze)
+            ex = self.executor
+        fn = getattr(ex, "session_gauges", None)
+        if fn is None:
+            return {}
+        try:
+            return {f"session_{k}": int(v) for k, v in fn().items()}
         except Exception:  # noqa: BLE001 — a half-built executor must
             return {}      # not kill the stats sweep
 
@@ -1368,11 +1386,14 @@ class QueryTask(threading.Thread):
         lattice, engine.session): string columns pre-gathered through
         their payload dictionaries into fixed-width unicode arrays, so
         the session key encoder factorizes them at C speed."""
+        self._note_consumed(len(ts))
         with trace_span(self.tracer, "step"):
-            return ex.process_columnar(ts, _session_columns(cols), nulls)
+            return ex.process_columnar(
+                ts, _session_columns(cols, ex.input_columns()), nulls)
 
     def _run_join_cols(self, ex, ts, plain, nulls, logid):
         """Columnar dispatch into a stream-stream join executor."""
+        self._note_consumed(len(ts))
         with trace_span(self.tracer, "step"):
             out = ex.process_columnar(
                 ts, plain, nulls, stream=self._sources[logid])
@@ -1433,15 +1454,19 @@ def _max_win_end(rows) -> float | None:
     return None if best is None else float(best)
 
 
-def _session_columns(cols: dict) -> dict:
+def _session_columns(cols: dict, wanted: frozenset) -> dict:
     """Decoded payload columns -> the session executor's columnar feed:
     like _plain_columns, but string columns gather into fixed-width
     unicode arrays (one vectorized fancy-index) instead of object
     arrays — the session key encoder's np.unique factorization runs at
     C speed on those and would fall back to a per-row memo loop on
-    object dtype."""
+    object dtype. Only the columns the plan reads (`wanted`) are
+    gathered: a wide record's other strings (one dictionary entry a row)
+    cost their gather and are never looked at."""
     out = {}
     for name, (kind, arr, d) in cols.items():
+        if name not in wanted:
+            continue
         if kind == "str":
             out[name] = np.asarray(d)[arr] if d else \
                 np.zeros(len(arr), "U1")

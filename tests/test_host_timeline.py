@@ -322,7 +322,9 @@ def test_each_child_lies_inside_its_parent(run):
                            for pa, pb in by_name.get(parent, ())), \
                     (child, parent)
                 checked.add(child)
-    assert checked == set(parents)
+    # a session query alone shows the session path's stages:
+    # tests/test_session_served.py holds their nesting
+    assert checked == {c for c in parents if not c.startswith("session_")}
 
 
 def test_top_level_stages_cover_the_task_threads_wall(run):

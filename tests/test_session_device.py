@@ -243,6 +243,70 @@ def test_key_growth_and_code_compaction(mode):
 
 
 @pytest.mark.parametrize("mode", MODES)
+def test_no_key_code_reaches_the_arenas_sentinel(mode):
+    """The kernels read a code at or above `SESSION_SENT_CODE` as an
+    empty slot. A dictionary the live-set rule would let grow (under
+    twice the open sessions, or under the cache bound) is compacted all
+    the same before a batch could mint such a code."""
+    from hstream_tpu.engine import lattice
+
+    aggs = [AggSpec(AggKind.COUNT_ALL, "c")]
+    exd = make_ex(aggs, device=True, mode=mode, gap=500, grace=0)
+    exh = make_ex(aggs, device=False, gap=500, grace=0)
+    od, oh = [], []
+
+    def feed(b):
+        ks = [f"k{b}_{i % 30}" for i in range(90)]
+        ts = [BASE + b * 200 + i for i in range(90)]
+        rows = [{"k": k, "v": 1.0} for k in ks]
+        od.extend(exd.process(rows, ts))
+        oh.extend(exh.process(rows, ts))
+
+    feed(0)
+    feed(1)
+    assert exd._dev is not None
+    assert exd.session_stats["remap_dispatches"] == 0
+    # codes of long-closed keys, up to 50 short of the sentinel: under
+    # the cache bound by count (`_code_of` is as it was), so only the
+    # sentinel rule can see them
+    exd._code_rev.extend([("gone",)] * (
+        lattice.SESSION_SENT_CODE - 50 - len(exd._code_rev)))
+    feed(2)  # 90 rows could mint codes past the sentinel
+    assert exd._dev is not None and exd.device_fallbacks == 0
+    assert exd.session_stats["remap_dispatches"] == 1
+    assert len(exd._code_rev) < 200
+    feed(3)
+    feed(40)  # closes everything before it
+    assert_rows_close(od, oh)
+    assert_rows_close(list(exd.peek()), list(exh.peek()))
+
+
+def test_open_keys_that_fill_the_code_space_degrade_to_the_host():
+    """Where a compaction cannot get under the sentinel (the open
+    sessions' own codes and the batch leave no room), the executor
+    degrades to the host engine with the reason, and counts it."""
+    from hstream_tpu.engine import lattice
+
+    aggs = [AggSpec(AggKind.COUNT_ALL, "c")]
+    exd = make_ex(aggs, device=True, mode="record", gap=500, grace=0)
+    exh = make_ex(aggs, device=False, gap=500, grace=0)
+    rows = [{"k": f"k{i % 7}", "v": 1.0} for i in range(40)]
+    ts = [BASE + i for i in range(40)]
+    exd.process(rows, ts)
+    exh.process(rows, ts)
+    assert exd._dev is not None
+    # a batch as large as the code space itself: no compaction helps
+    reasons, degrade = [], exd._degrade_to_host
+    exd._degrade_to_host = lambda why: (reasons.append(why),
+                                        degrade(why))[1]
+    exd._bound_key_cache(lattice.SESSION_SENT_CODE)
+    assert exd._dev is None and exd.device_fallbacks == 1
+    assert "under the arena's sentinel" in reasons[0]
+    later = [BASE + 5000 + i for i in range(40)]
+    assert_rows_close(exd.process(rows, later), exh.process(rows, later))
+
+
+@pytest.mark.parametrize("mode", MODES)
 def test_snapshot_roundtrip_in_device_mode(mode):
     """Snapshot taken while sessions are device-resident restores into
     the host engine, re-activates lazily, and continues identically."""
