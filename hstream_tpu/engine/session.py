@@ -342,6 +342,10 @@ class SessionExecutor:
             "batches": 0, "step_dispatches": 0, "close_cycles": 0,
             "close_dispatches": 0, "close_fetches": 0,
             "peek_dispatches": 0, "remap_dispatches": 0, "grows": 0,
+            # the decode columns of the code dictionary: times they were
+            # made over the whole dictionary (activation, compaction,
+            # host reset), and entries appended to them since
+            "code_cols_builds": 0, "code_cols_appended": 0,
         }
         # observability plane (ISSUE 13): per-family dispatch observer,
         # late-record drop count (both engines decide lateness on the
@@ -359,7 +363,12 @@ class SessionExecutor:
             name: StringDictionary() for name, t in schema.fields
             if t == ColumnType.STRING
         }
-        self._code_cols_cache: tuple[int, list[np.ndarray]] = (-1, [])
+        # code -> key, one object array a group column, for vectorized
+        # key decode (_code_rev_columns): the first _code_cols_filled
+        # entries of _code_rev, in arrays with room to spare
+        self._code_cols: list[np.ndarray] = [
+            np.empty(0, object) for _ in self.group_cols]
+        self._code_cols_filled = 0
         # read-plane versioning (ISSUE 20): bumped at every mutation
         # entry point (ingest, close, engine migration) so equal
         # read_version() tuples guarantee identical peek() results.
@@ -518,10 +527,12 @@ class SessionExecutor:
         except (TypeError, KeyError):
             return False
 
-    # key-encoding cache bound: codes only matter WITHIN one batch, so
-    # the caches are safe to drop wholesale; bounding them keeps a
-    # months-long high-cardinality query (session per request_id) from
-    # growing without limit after its sessions closed
+    # key-encoding cache bound: it keeps a months-long high-cardinality
+    # query (session per request_id) from growing without limit after
+    # its sessions closed. In host mode codes only matter WITHIN one
+    # batch, so the caches are dropped wholesale; on the device a code
+    # is its open sessions' sort key in the arena, so the dictionary is
+    # compacted to the live codes instead (_bound_key_cache)
     _KEY_CACHE_MAX = 1 << 18
 
     def _bound_key_cache(self, n: int) -> None:
@@ -544,7 +555,7 @@ class SessionExecutor:
                 self._code_of = {}
                 self._code_rev = []
                 self._raw_memo = {}
-                self._code_cols_cache = (-1, [])
+                self._reset_code_cols()
             return
         from hstream_tpu.engine import lattice
 
@@ -1117,6 +1128,8 @@ class SessionExecutor:
                 self._code_rev.append(key)
             for s in sess_list:
                 entries.append((code, s))
+        self._reset_code_cols()
+        self._extend_code_cols()
         n = len(entries)
         cap = round_up_pow2(2 * max(n, 1), lo=256)
         mir_code = np.empty(n, np.int64)
@@ -1218,12 +1231,7 @@ class SessionExecutor:
         # cache bound may rebuild that dictionary, so resolve their key
         # columns against the CURRENT one now (same rule as the
         # code-space compaction)
-        self._pending_closes = [
-            (codes, t0, t1, packed,
-             keys if keys is not None else
-             [arr[codes.astype(np.int64)]
-              for arr in self._code_rev_columns()])
-            for codes, t0, t1, packed, keys in self._pending_closes]
+        self._resolve_pending_close_keys()
         self.sessions = self._host_sessions_view()
         self._dev = None
         self.use_device_sessions = False
@@ -2121,12 +2129,7 @@ class SessionExecutor:
         # pending deferred closes still decode by their PRE-remap codes
         # (the extracted device buffers are immutable): resolve their
         # key columns against the old dictionary now
-        self._pending_closes = [
-            (codes, t0, t1, packed,
-             keys if keys is not None else
-             [arr[codes.astype(np.int64)]
-              for arr in self._code_rev_columns()])
-            for codes, t0, t1, packed, keys in self._pending_closes]
+        self._resolve_pending_close_keys()
         live_codes = np.unique(dev["mir_code"][live]).astype(np.int64)
         lut = np.full(self._remap_lcap(len(self._code_rev)),
                       lattice.SESSION_SENT_CODE, np.int32)
@@ -2167,6 +2170,7 @@ class SessionExecutor:
         # sharded new codes are class-strided, so the reverse index may
         # carry holes (None); only live codes ever decode through it
         top = int(new_of.max()) + 1 if len(live_codes) else 0
+        old_cols = self._code_rev_columns()
         new_rev: list = [None] * top
         for c, nc in zip(live_codes.tolist(), new_of.tolist()):
             new_rev[nc] = self._code_rev[c]
@@ -2174,7 +2178,14 @@ class SessionExecutor:
         self._code_of = {k: i for i, k in enumerate(new_rev)
                          if k is not None}
         self._raw_memo = {}
-        self._code_cols_cache = (-1, [])
+        # the decode columns move with it: a take of the old ones at the
+        # live codes, scattered to the new (holes stay None)
+        new_cols = []
+        for old in old_cols:
+            col = np.empty(top, object)
+            col[new_of] = old[live_codes]
+            new_cols.append(col)
+        self._reset_code_cols(new_cols, top)
 
     # contract: dispatches<=1 fetches<=1
     def _close_due_device(self):
@@ -2404,13 +2415,9 @@ class SessionExecutor:
         from the mirror snapshot taken at dispatch time."""
         n = len(codes)
         cols: dict[str, Any] = {}
-        if keys is not None:  # resolved before a code-space compaction
-            for name, arr in zip(self.group_cols, keys):
-                cols[name] = arr
-        else:
-            for name, arr in zip(self.group_cols,
-                                 self._code_rev_columns()):
-                cols[name] = arr[codes.astype(np.int64)]
+        if keys is None:  # else resolved before the dictionary moved
+            keys = self._decode_key_cols(codes)
+        cols.update(zip(self.group_cols, keys))
         row = 1
         for a in self.aggs:
             v = np.ascontiguousarray(packed[row, :n])
@@ -2426,18 +2433,59 @@ class SessionExecutor:
 
     def _code_rev_columns(self) -> list[np.ndarray]:
         """Per-group-column object arrays over the code dictionary for
-        vectorized key decode; rebuilt only when codes changed."""
-        version = len(self._code_rev)
-        if self._code_cols_cache[0] != version:
-            out = []
-            for g in range(len(self.group_cols)):
-                arr = np.empty(version, object)
-                for i, key in enumerate(self._code_rev):
-                    if key is not None:  # sharded-compaction hole
-                        arr[i] = key[g]
-                out.append(arr)
-            self._code_cols_cache = (version, out)
-        return self._code_cols_cache[1]
+        vectorized key decode (None in a sharded compaction's holes),
+        kept incrementally: a call appends the codes minted since the
+        last one and hands out views of the filled part, so a decode
+        costs what it decodes and what was minted, never the size of
+        the dictionary. Whoever replaces the dictionary sets the
+        columns with it (_reset_code_cols)."""
+        self.session_stats["code_cols_appended"] += \
+            self._extend_code_cols()
+        filled = self._code_cols_filled
+        return [arr[:filled] for arr in self._code_cols]
+
+    def _extend_code_cols(self) -> int:
+        """Append `_code_rev[filled:]` to the decode columns, doubling
+        their room when it runs out; returns the entries appended.
+        Minted codes are never holes: those lie under `filled`."""
+        rev = self._code_rev
+        filled, top = self._code_cols_filled, len(rev)
+        if top == filled:
+            return 0
+        new = rev[filled:]
+        for g, arr in enumerate(self._code_cols):
+            if top > len(arr):
+                grown = np.empty(max(top, 2 * len(arr)), object)
+                grown[:filled] = arr[:filled]
+                self._code_cols[g] = arr = grown
+            # fromiter stores each value as it is (no dtype inference)
+            arr[filled:top] = np.fromiter((key[g] for key in new),
+                                          object, top - filled)
+        self._code_cols_filled = top
+        return top - filled
+
+    def _reset_code_cols(self, cols: list[np.ndarray] | None = None,
+                         filled: int = 0) -> None:
+        """The decode columns of a dictionary made anew: `cols` over its
+        first `filled` codes (none: `_extend_code_cols` walks it)."""
+        self._code_cols = cols if cols is not None else [
+            np.empty(0, object) for _ in self.group_cols]
+        self._code_cols_filled = filled
+        self.session_stats["code_cols_builds"] += 1
+
+    def _decode_key_cols(self, codes) -> list[np.ndarray]:
+        """The key of each code, one object array a group column."""
+        at = codes.astype(np.int64)
+        return [arr[at] for arr in self._code_rev_columns()]
+
+    def _resolve_pending_close_keys(self) -> None:
+        """Deferred closes decode lazily, by the codes their extracted
+        buffers hold: resolve their key columns against the CURRENT
+        dictionary before it is replaced."""
+        self._pending_closes = [
+            (codes, t0, t1, packed,
+             keys if keys is not None else self._decode_key_cols(codes))
+            for codes, t0, t1, packed, keys in self._pending_closes]
 
     # contract: dispatches<=1 fetches<=1
     def _peek_device(self):

@@ -38,3 +38,34 @@ def wait_any_attached(ctx, timeout: float = 10.0, *, exclude=()):
                 return task
         time.sleep(0.01)
     raise TimeoutError("no (new) query task attached")
+
+
+def fresh_code_columns(ex) -> list:
+    """A session executor's decode columns built from scratch, the plain
+    way: one walk of the WHOLE code dictionary a group column, a
+    sharded compaction's holes left None."""
+    import numpy as np
+
+    out = []
+    for g in range(len(ex.group_cols)):
+        arr = np.empty(len(ex._code_rev), object)
+        for i, key in enumerate(ex._code_rev):
+            if key is not None:
+                arr[i] = key[g]
+        out.append(arr)
+    return out
+
+
+def typed(values) -> list:
+    """Values with their types: 1, 1.0 and True are three keys."""
+    return [(type(v).__name__, v) for v in values]
+
+
+def assert_code_columns_fresh(ex) -> None:
+    """The incrementally kept decode columns equal a from-scratch build
+    of `_code_rev`, value for value and type for type."""
+    got, want = ex._code_rev_columns(), fresh_code_columns(ex)
+    assert len(got) == len(want) == len(ex.group_cols)
+    for g, w in zip(got, want):
+        assert g.dtype == object and g.shape == w.shape
+        assert typed(g.tolist()) == typed(w.tolist())

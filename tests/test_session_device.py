@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import numpy as np
 import pytest
+from helpers import assert_code_columns_fresh, typed
 
 from hstream_tpu.engine import ColumnType, Schema
 from hstream_tpu.engine.expr import Col
@@ -681,3 +682,274 @@ def test_late_records_merge_into_open_sessions_on_device():
         pd, ph = list(exd.peek()), list(exh.peek())
         assert pd == ph
         assert pd[0]["c"] == 2  # merged one, dropped one
+
+
+# ---- the decode columns of the code dictionary (ISSUE 30) -------------------
+#
+# A close cycle names the keys of the rows it closed by a gather from
+# one object array a group column (`_code_rev_columns`). The arrays are
+# kept incrementally: the oracle everywhere below is a from-scratch
+# build of `_code_rev` (helpers.fresh_code_columns) and the host engine
+# on the same input.
+
+WIDE = Schema.of(k=ColumnType.STRING, u=ColumnType.INT, f=ColumnType.FLOAT,
+                 v=ColumnType.FLOAT)
+
+# group columns, and whether the first carries null cells
+KEY_SHAPES = {
+    "int": (("u",), False),
+    "string": (("k",), False),
+    "null": (("k",), True),
+    "two": (("k", "u"), False),
+    "three": (("k", "u", "f"), False),
+    "none": ((), False),
+}
+
+
+def make_keyed(groups, *, device, mode=None):
+    node = AggregateNode(
+        child=SourceNode("s", WIDE), group_keys=[Col(g) for g in groups],
+        window=SessionWindow(500, grace_ms=0),
+        aggs=[AggSpec(AggKind.COUNT_ALL, "c"),
+              AggSpec(AggKind.SUM, "s", input=Col("v"))])
+    ex = SessionExecutor(node, WIDE)
+    ex.use_device_sessions = device
+    ex.device_session_mode = mode
+    return ex
+
+
+def churn(shape, seed, n_batches=10):
+    """A stream whose keys churn: every batch names ids none before it
+    named and, two seconds of event time on, closes the sessions of the
+    batch before (gap 500 ms). Yields (ts, cols, nulls) as the server
+    feeds `process_columnar`."""
+    groups, with_nulls = KEY_SHAPES[shape]
+    rng = np.random.default_rng(seed)
+    batch = 120
+    for b in range(n_batches):
+        ids = rng.integers(b * 25, b * 25 + 60, batch)
+        ts = BASE + b * 2000 + rng.integers(0, 400, batch)
+        cols = {"k": np.array([f"k{int(i)}" for i in ids]),
+                "u": (ids if groups == ("u",) else ids % 3).astype(
+                    np.int64),
+                "f": ((ids % 5) * 0.5).astype(np.float32),
+                "v": rng.integers(0, 9, batch).astype(np.float32)}
+        nulls = {"k": rng.random(batch) < 0.1} if with_nulls else {}
+        yield ts, cols, nulls
+
+
+def keyed_rows(out, groups):
+    """Emitted rows in a canonical order, group cells with their
+    types."""
+    return sorted(
+        (typed(r[g] for g in groups),
+         tuple((k, v) for k, v in sorted(r.items()) if k not in groups))
+        for r in out)
+
+
+def feed(ex, how, ts, cols, nulls):
+    if how == "cols":
+        return list(ex.process_columnar(ts, cols, nulls))
+    rows = SessionExecutor._rows_from_cols(cols, nulls, len(ts))
+    return list(ex.process(rows, ts.tolist()))
+
+
+@pytest.mark.parametrize("shape,how,mode", [
+    ("int", "cols", None), ("string", "cols", None),
+    ("null", "cols", None), ("two", "cols", None),
+    ("three", "cols", None), ("none", "cols", None),
+    ("string", "rows", "record"), ("null", "rows", None),
+    ("two", "rows", "record"), ("three", "cols", "record")])
+def test_decode_columns_equal_a_fresh_build_after_every_batch(
+        shape, how, mode):
+    """Through a long churning stream the incremental columns equal a
+    from-scratch build of `_code_rev` after EVERY batch, and the closed
+    rows and the open ones are the host engine's, value for value and
+    (the key cells) type for type."""
+    groups = KEY_SHAPES[shape][0]
+    exd = make_keyed(groups, device=True, mode=mode)
+    exh = make_keyed(groups, device=False)
+    closed = 0
+    for ts, cols, nulls in churn(shape, 7):
+        od = feed(exd, how, ts, cols, nulls)
+        oh = feed(exh, how, ts, cols, nulls)
+        assert exd._dev is not None and exd.device_fallbacks == 0
+        assert_code_columns_fresh(exd)
+        assert keyed_rows(od, groups) == keyed_rows(oh, groups)
+        assert keyed_rows(exd.peek(), groups) \
+            == keyed_rows(exh.peek(), groups)
+        closed += len(od)
+    assert closed > 0
+    assert exd.session_stats["code_cols_builds"] == 1  # activation
+    assert exd.session_stats["code_cols_appended"] == len(exd._code_rev)
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_a_close_decodes_what_was_minted_not_the_dictionary(mode):
+    """Cost by counter, not by clock: over N batches the columns are
+    never made anew (`code_cols_builds` stands where activation left
+    it) and each batch's close appends exactly the codes the batch
+    minted, however large the dictionary has grown."""
+    exd = make_keyed(("k",), device=True, mode=mode)
+    walked, extend = [], exd._extend_code_cols
+
+    def extending():
+        walked.append(extend())
+        return walked[-1]
+
+    exd._extend_code_cols = extending
+    expect, waiting = [0], 0  # activation walks an empty dictionary
+    for ts, cols, nulls in churn("string", 21, n_batches=14):
+        before = len(exd._code_rev)
+        cycles = exd.session_stats["close_cycles"]
+        exd.process_columnar(ts, cols, nulls)
+        waiting += len(exd._code_rev) - before
+        if exd.session_stats["close_cycles"] > cycles:
+            expect.append(waiting)  # what this close had to append
+            waiting = 0
+    st = exd.session_stats
+    assert exd._dev is not None and st["remap_dispatches"] == 0
+    assert st["close_cycles"] >= 12
+    assert st["code_cols_builds"] == 1
+    assert st["code_cols_appended"] == len(exd._code_rev) == sum(walked)
+    # one walk a close cycle, of the codes minted since the last and
+    # no more, while the dictionary grows past any of them
+    assert walked == expect and len(walked) == 1 + st["close_cycles"]
+    assert max(walked) < len(exd._code_rev) // 3
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_decode_columns_across_a_compaction(mode):
+    """`_compact_codes_device` sets the columns with the dictionary it
+    makes (a take of the old ones): equal to a fresh build right after,
+    and after every later batch; rows stay the host engine's."""
+    exd = make_keyed(("k", "u"), device=True, mode=mode)
+    exh = make_keyed(("k", "u"), device=False)
+    exd._KEY_CACHE_MAX = 64  # compacts every few batches
+    builds = []
+    compact = exd._compact_codes_device
+
+    def compacting():
+        compact()
+        builds.append(exd.session_stats["code_cols_builds"])
+        assert exd._code_cols_filled == len(exd._code_rev)
+        assert_code_columns_fresh(exd)
+
+    exd._compact_codes_device = compacting
+    for ts, cols, nulls in churn("two", 5, n_batches=12):
+        od = feed(exd, "cols", ts, cols, nulls)
+        oh = feed(exh, "cols", ts, cols, nulls)
+        assert_code_columns_fresh(exd)
+        assert keyed_rows(od, ("k", "u")) == keyed_rows(oh, ("k", "u"))
+    assert exd._dev is not None and exd.device_fallbacks == 0
+    remaps = exd.session_stats["remap_dispatches"]
+    assert remaps >= 2
+    # one build at activation, one a compaction, none between
+    assert builds == list(range(2, 2 + remaps))
+    assert exd.session_stats["code_cols_builds"] == 1 + remaps
+    assert keyed_rows(exd.peek(), ("k", "u")) \
+        == keyed_rows(exh.peek(), ("k", "u"))
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_deferred_closes_keep_their_keys_across_a_compaction(mode):
+    """Deferred closes decode by the codes their buffers hold: they are
+    resolved against the OLD dictionary before a compaction replaces
+    it, so a drain after it names the keys a synchronous close
+    named."""
+    exd = make_keyed(("k",), device=True, mode=mode)
+    exs = make_keyed(("k",), device=True, mode=mode)
+    exd.defer_close_decode = True
+    exd._KEY_CACHE_MAX = exs._KEY_CACHE_MAX = 64
+    crossing, compact = [], exd._compact_codes_device
+
+    def compacting():
+        crossing.append(len(exd._pending_closes))
+        compact()
+
+    exd._compact_codes_device = compacting
+    drained, sync = [], []
+    for i, (ts, cols, nulls) in enumerate(churn("string", 9,
+                                                n_batches=12)):
+        assert feed(exd, "cols", ts, cols, nulls) == []
+        sync.extend(feed(exs, "cols", ts, cols, nulls))
+        if i % 4 == 3:  # several cycles, and a compaction, in between
+            drained.extend(exd.drain_closed())
+            assert_code_columns_fresh(exd)
+    drained.extend(exd.drain_closed())
+    assert exd.session_stats["remap_dispatches"] >= 2
+    assert max(crossing) > 0  # pending cycles crossed a compaction
+    assert keyed_rows(drained, ("k",)) == keyed_rows(sync, ("k",))
+    assert [r["k"] for r in drained] == [r["k"] for r in sync]
+
+
+def test_decode_columns_across_a_degrade_and_the_host_reset():
+    """`_degrade_to_host` leaves the columns equal to a fresh build (a
+    pending close is resolved through them first); the host engine's
+    wholesale reset of the dictionary resets them with it, counted."""
+    exd = make_keyed(("k", "u"), device=True)
+    exh = make_keyed(("k", "u"), device=False)
+    exd.defer_close_decode = True
+    batches = list(churn("two", 3, n_batches=9))
+    od, oh = [], []
+    for ts, cols, nulls in batches[:4]:
+        od.extend(feed(exd, "cols", ts, cols, nulls))
+        oh.extend(feed(exh, "cols", ts, cols, nulls))
+    assert exd.has_pending_closes()
+    exd._degrade_to_host("test: a device lost mid-stream")
+    assert_code_columns_fresh(exd)
+    builds = exd.session_stats["code_cols_builds"]
+    exd._KEY_CACHE_MAX = 32  # the host engine drops the dictionary
+    for ts, cols, nulls in batches[4:]:
+        size = len(exd._code_of)
+        od.extend(feed(exd, "cols", ts, cols, nulls))
+        oh.extend(feed(exh, "cols", ts, cols, nulls))
+        if size > 32:
+            builds += 1
+        assert exd.session_stats["code_cols_builds"] == builds
+        assert_code_columns_fresh(exd)
+    od.extend(exd.drain_closed())
+    assert builds > 2
+    assert keyed_rows(od, ("k", "u")) == keyed_rows(oh, ("k", "u"))
+    assert keyed_rows(exd.peek(), ("k", "u")) \
+        == keyed_rows(exh.peek(), ("k", "u"))
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_decode_columns_across_capture_and_restore(mode):
+    """A snapshot of device-resident sessions reads `_code_rev` itself;
+    the restored executor mints its codes at activation and builds the
+    columns there, once, then appends."""
+    from types import SimpleNamespace
+
+    from hstream_tpu.engine import snapshot as snap
+
+    exd = make_keyed(("k", "u"), device=True, mode=mode)
+    exh = make_keyed(("k", "u"), device=False)
+    batches = list(churn("two", 13, n_batches=8))
+    for ts, cols, nulls in batches[:4]:
+        feed(exd, "cols", ts, cols, nulls)
+        feed(exh, "cols", ts, cols, nulls)
+    meta, _arrays = exd.capture_device()
+    live = exd._dev["mir_live"]
+    assert typed(meta["keys"]) == typed(
+        exd._code_rev[c] for c in np.unique(exd._dev["mir_code"][live]))
+    restored, _extra = snap.restore_executor(
+        SimpleNamespace(node=exd.node), snap.snapshot_executor(exd))
+    restored.device_session_mode = mode
+    assert restored._dev is None and restored._code_rev == []
+    od, oh = [], []
+    for ts, cols, nulls in batches[4:]:
+        od.extend(feed(restored, "cols", ts, cols, nulls))
+        oh.extend(feed(exh, "cols", ts, cols, nulls))
+        assert_code_columns_fresh(restored)
+    assert restored._dev is not None
+    st = restored.session_stats
+    # activation walked the open keys the snapshot held, counted as the
+    # one build; every code since was appended
+    assert st["code_cols_builds"] == 1
+    assert 0 < len(restored._code_rev) - st["code_cols_appended"] \
+        == len(meta["keys"])
+    assert keyed_rows(od, ("k", "u")) == keyed_rows(oh, ("k", "u"))
+    assert keyed_rows(restored.peek(), ("k", "u")) \
+        == keyed_rows(exh.peek(), ("k", "u"))

@@ -85,6 +85,7 @@ def run(request):
         with task.state_lock:
             ex = task.executor
         out["gauges_before_closer"] = ex.session_gauges()
+        out["codes_minted"] = len(ex._code_rev)
         for closer in gen.closers(DRY, N_FRAMES):
             client.append_call([closer])
         served.wait_consumed(ctx, task, 300)
@@ -156,6 +157,20 @@ def test_session_stats_and_the_arena_show_in_admin_stats_queries(run):
     assert stats["session_arena_cap"] == g["arena_cap"]
     ses = ref.sessions(DRY, run["seed"], N_FRAMES)
     assert g["live"] == int((ses["cycle"] == N_FRAMES).sum())
+
+
+def test_the_decode_columns_counters_show_in_admin_stats_queries(run):
+    """`code_cols_builds` / `code_cols_appended` (ISSUE 30) reach `admin
+    stats queries` as `session_<stat>`: the columns were made once, at
+    activation, and every code a close had to name was appended."""
+    stats, g = run["stats"], run["gauges_before_closer"]
+    assert stats["session_code_cols_builds"] == g["code_cols_builds"] == 1
+    assert stats["session_code_cols_appended"] == g["code_cols_appended"]
+    ses = ref.sessions(DRY, run["seed"], N_FRAMES)
+    bidders = set(ses["bidder"].tolist())
+    closed = set(ses["bidder"][ses["cycle"] < N_FRAMES].tolist())
+    assert run["codes_minted"] == len(bidders)
+    assert len(closed) <= g["code_cols_appended"] <= len(bidders)
 
 
 def test_the_session_paths_stages_are_spans_inside_step(run):

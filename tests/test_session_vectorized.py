@@ -5,6 +5,7 @@ from __future__ import annotations
 
 import numpy as np
 import pytest
+from helpers import assert_code_columns_fresh
 
 from hstream_tpu.engine import ColumnType, Schema
 from hstream_tpu.engine.expr import Col
@@ -205,3 +206,44 @@ def test_ragged_sequence_values_nulled_not_crash():
     rows = ex.process([{"k": "z", "v": 0.0}], [BASE + 200_000])
     got = {r["k"]: (r["c"], r["s"]) for r in rows if r["k"] == "a"}
     assert got["a"] == (3, 5.0), got
+
+
+@pytest.mark.parametrize("groups", [("k",), ("k", "r"), ("k", "r", "f"), ()],
+                         ids=["one", "two", "three", "none"])
+def test_host_reset_sets_the_decode_columns_with_the_dictionary(groups):
+    """The host engine drops its code dictionary wholesale above the
+    cache bound; the decode columns (ISSUE 30) are set with it, counted
+    as a build, and what is appended after equals a from-scratch build
+    of `_code_rev`: int, string, float and absent (None) key cells."""
+    schema = Schema.of(k=ColumnType.STRING, r=ColumnType.INT,
+                       f=ColumnType.FLOAT, v=ColumnType.FLOAT)
+    node = AggregateNode(
+        child=SourceNode("s", schema),
+        group_keys=[Col(g) for g in groups],
+        window=SessionWindow(1000, grace_ms=0),
+        aggs=[AggSpec(AggKind.COUNT_ALL, "c")])
+    ex = SessionExecutor(node, schema)
+    ex.use_device_sessions = False
+    ex._KEY_CACHE_MAX = 40
+    rng = np.random.default_rng(4)
+    resets = 0
+    for b in range(10):
+        rows = []
+        for i in rng.integers(b * 20, b * 20 + 30, 50).tolist():
+            row = {"k": f"k{i}", "r": i % 3, "f": (i % 4) * 0.25, "v": 1.0}
+            if i % 11 == 0:
+                del row["k"]  # an absent cell is the key None
+            rows.append(row)
+        size = len(ex._code_of)
+        ex.process(rows, [BASE + b * 5000 + j for j in range(50)])
+        if size > 40:
+            resets += 1
+            assert len(ex._code_rev) <= 50  # only this batch's keys
+        assert ex.session_stats["code_cols_builds"] == resets
+        assert_code_columns_fresh(ex)
+    assert ex._dev is None
+    if groups:
+        assert resets >= 2
+    else:  # one key (): nothing to bound
+        assert resets == 0 and ex._code_rev == [()]
+    assert ex._code_cols_filled == len(ex._code_rev)

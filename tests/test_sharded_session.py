@@ -11,6 +11,7 @@ mesh sizes (1 chip <-> 8-device mesh, re-shard on restore).
 
 import numpy as np
 import pytest
+from helpers import assert_code_columns_fresh
 
 from hstream_tpu.engine import ColumnType, Schema
 from hstream_tpu.engine.expr import Col
@@ -247,3 +248,53 @@ def test_session_mesh_size_migration(mesh, mode):
     assert dx._dev is None or dx._dev.get("ssl") is None
     assert base == up
     assert base == down
+
+
+@pytest.mark.parametrize("mode", ["record", "segment"])
+@pytest.mark.parametrize("defer", [False, True])
+def test_sharded_decode_columns_keep_holes_and_live_keys(mesh, mode,
+                                                         defer):
+    """The residue-preserving compaction leaves holes in `_code_rev`:
+    the decode columns it sets with it (ISSUE 30) hold None there and
+    the live codes' keys, equal to a from-scratch build after every
+    batch; later codes append behind them; closed, deferred and open
+    rows are the host engine's."""
+    aggs = AGGS[:2]
+
+    def make(m):
+        kw = {} if m is None else {"mesh": m}
+        ex = SessionExecutor(node_of(500, 0, aggs), SCHEMA, **kw)
+        ex.device_session_mode = mode
+        return ex
+
+    exs, exh = make(mesh), make(None)
+    exh.use_device_sessions = False
+    exs.defer_close_decode = defer
+    exs._KEY_CACHE_MAX = 96
+    rng = np.random.default_rng(17)
+    got, want, holes = [], [], 0
+    for b in range(12):
+        ids = rng.integers(b * 31, b * 31 + 70, 160)
+        ts = (BASE + b * 2000 + rng.integers(0, 400, 160)).tolist()
+        rows = [{"k": f"u{int(i)}", "v": float(i % 9)} for i in ids]
+        got.extend(to_rows(exs.process(rows, ts)))
+        want.extend(to_rows(exh.process(rows, ts)))
+        assert_code_columns_fresh(exs)
+        holes += sum(k is None for k in exs._code_rev)
+        if defer and b % 4 == 3:
+            got.extend(to_rows(exs.drain_closed()))
+    got.extend(to_rows(exs.drain_closed()))
+    assert exs._dev is not None and exs._dev.get("ssl") is not None
+    assert exs.device_fallbacks == 0
+    remaps = exs.session_stats["remap_dispatches"]
+    assert remaps >= 2 and holes > 0
+    assert exs.session_stats["code_cols_builds"] == 1 + remaps
+    names = ("c", "sv")
+    assert canon(got, names) == canon(want, names)
+    assert canon(to_rows(exs.peek()), names) \
+        == canon(to_rows(exh.peek()), names)
+    # the degrade path reads the (holed) dictionary itself
+    exs._degrade_to_host("test: host view of a holed dictionary")
+    assert_code_columns_fresh(exs)
+    assert canon(to_rows(exs.peek()), names) \
+        == canon(to_rows(exh.peek()), names)
