@@ -243,9 +243,12 @@ def log_batches(ctx, stream: str) -> tuple[int, int]:
                 payloads += len(b.payloads)
 
 
-def assert_on_device(ctx, task, kind: str) -> dict:
+def assert_on_device(ctx, task, kind: str, dry_run: bool) -> dict:
     """The device did the work: right executor class, no degradation
-    to a host twin, query RUNNING and healthy."""
+    to a host twin, query RUNNING and healthy. A dry run reports
+    DEGRADED and does not fail on it: a toy run on a CPU it shares
+    cannot hold the health plane to OK (`pipeline_occupancy` counts a
+    cold compile on a loaded host as busy)."""
     from hstream_tpu.server.health import evaluate_query
     from hstream_tpu.server.persistence import TaskStatus
     from hstream_tpu.stats.prometheus import render_metrics
@@ -266,10 +269,12 @@ def assert_on_device(ctx, task, kind: str) -> dict:
     status = ctx.persistence.get_query(qid).status
     check(status == TaskStatus.RUNNING, f"{qid}: status {status}")
     health = evaluate_query(ctx, qid)
-    check(health["verdict"] == "OK",
+    check(health["verdict"] == "OK"
+          or (dry_run and health["verdict"] == "DEGRADED"),
           f"{qid}: health {health['verdict']} {health['reasons']}")
     return {"executor": kind, "device_fallbacks": fallbacks,
-            "health": health["verdict"]}
+            "health": health["verdict"],
+            "health_reasons": health["reasons"]}
 
 
 # ---- phase: served_tumbling -------------------------------------------------
@@ -277,8 +282,7 @@ def assert_on_device(ctx, task, kind: str) -> dict:
 
 def tumbling_frame(seed: int, w: int, f: int, size: dict) -> tuple:
     """Frame f of window w: uniform keys, one-decimal f32 temps (the
-    codec-canonical form bench.BatchSource makes), event times shuffled
-    inside the window."""
+    codec-canonical form), event times shuffled inside the window."""
     rng = np.random.default_rng([seed, 1, w, f])
     n = size["frame_rows"]
     kids = rng.integers(0, size["keys"], n).astype(np.int32)
@@ -405,7 +409,7 @@ def check_window(client: Client, w: int, ref: dict,
 
 def phase_served_tumbling(ctx, client: Client, ledger: CompileLedger,
                           seed: int, size: dict, platform: str,
-                          mesh: str | None) -> dict:
+                          mesh: str | None, dry_run: bool) -> dict:
     from hstream_tpu.common.tracing import RetraceGuard
     from hstream_tpu.stats.devicecost import backend_hbm_bytes
 
@@ -497,7 +501,7 @@ def phase_served_tumbling(ctx, client: Client, ledger: CompileLedger,
 
     out = assert_on_device(
         ctx, task,
-        "ShardedQueryExecutor" if mesh else "QueryExecutor")
+        "ShardedQueryExecutor" if mesh else "QueryExecutor", dry_run)
     ex = task.executor
     check(bool(ex._fused_close_ok), "fused close degraded")
     check(last_guard == 0,
@@ -578,7 +582,8 @@ def host_reference(select_sql: str, feed: list[tuple], attr: str,
 
 
 def phase_session_device(ctx, client: Client, ledger: CompileLedger,
-                         seed: int, size: dict, platform: str) -> dict:
+                         seed: int, size: dict, platform: str,
+                         dry_run: bool) -> dict:
     from hstream_tpu.engine.sketches import QuantileConfig
 
     rng = np.random.default_rng([seed, 3])
@@ -639,7 +644,7 @@ def phase_session_device(ctx, client: Client, ledger: CompileLedger,
             worst = max(worst, d)
             check(d <= rel, f"session {key} {col}: {got[key][col]} vs "
                   f"host {ref[col]}")
-    out = assert_on_device(ctx, task, "SessionExecutor")
+    out = assert_on_device(ctx, task, "SessionExecutor", dry_run)
     check(ex._dev is not None, "sessions left the device mid-run")
     out.update({"events": sum(len(ts) for _s, ts, _c in feed),
                 "sessions": len(want), "mode": mode,
@@ -651,7 +656,7 @@ def phase_session_device(ctx, client: Client, ledger: CompileLedger,
 
 
 def phase_join_device(ctx, client: Client, ledger: CompileLedger,
-                      seed: int, size: dict) -> dict:
+                      seed: int, size: dict, dry_run: bool) -> dict:
     rng = np.random.default_rng([seed, 4])
     n, frames = size["join_rows"], size["join_frames"]
     keys = np.array([f"k{i:05d}" for i in range(size["join_keys"])])
@@ -711,7 +716,7 @@ def phase_join_device(ctx, client: Client, ledger: CompileLedger,
             if got.get(k) != want.get(k)]
     check(not diff, f"join rows differ from the host engine's in "
           f"{len(diff)} of {len(want)} rows, first {diff[:1]}")
-    out = assert_on_device(ctx, task, "JoinExecutor")
+    out = assert_on_device(ctx, task, "JoinExecutor", dry_run)
     with task.state_lock:
         ex = task.executor
     check(ex._dev is not None and ex.use_device_join,
@@ -825,17 +830,17 @@ def main(argv=None) -> int:
         try:
             phases["served_tumbling"] = phase_served_tumbling(
                 ctx, client, ledger, args.seed, size,
-                device["platform"], args.mesh)
+                device["platform"], args.mesh, args.dry_run)
             say(f"served_tumbling passed: "
                 f"{json.dumps(phases['served_tumbling'])}")
             if args.mesh is None:  # the mesh run is the tumbling view's
                 phases["session_device"] = phase_session_device(
                     ctx, client, ledger, args.seed, size,
-                    device["platform"])
+                    device["platform"], args.dry_run)
                 say(f"session_device passed: "
                     f"{json.dumps(phases['session_device'])}")
                 phases["join_device"] = phase_join_device(
-                    ctx, client, ledger, args.seed, size)
+                    ctx, client, ledger, args.seed, size, args.dry_run)
                 say(f"join_device passed: "
                     f"{json.dumps(phases['join_device'])}")
             logid = ctx.streams.get_logid("sensors")

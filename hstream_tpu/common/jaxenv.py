@@ -1,9 +1,10 @@
 """Where this process runs JAX: the device it got, and where compiled
 programs are kept between runs.
 
-Called from process entry points only (`server.main.main`, `bench.py`,
-`chip_smoke.py`, `__graft_entry__.py`) — never from `serve()` or any
-import — so in-process test servers write nothing to disk.
+Called from process entry points only (`server.main.main`,
+`benchmarks/run.py`, `chip_smoke.py`, `__graft_entry__.py`) — never from
+`serve()` or any import — so in-process test servers write nothing to
+disk (`tests/test_chip_smoke.py` holds where the cache lands).
 """
 
 from __future__ import annotations

@@ -313,8 +313,9 @@ class SpanCollector:
 
     `active` is a plain attribute (False at sample rate 0) — the
     disarmed hot-path cost is one attribute read + one branch, the
-    FlowGovernor / FAULTS discipline; `bench.py --smoke` gates that
-    arming the collector compiles nothing."""
+    FlowGovernor / FAULTS discipline;
+    `tests/test_append_framed.py::test_served_steady_state_compiles_nothing`
+    gates that arming the collector compiles nothing."""
 
     def __init__(self, sample_rate: float = 0.0, *,
                  ring_capacity: int = 512, max_scopes: int = 256):

@@ -605,8 +605,9 @@ def build_reset_slot(spec: LatticeSpec):
 # documented: the executor drivers declare `# contract: dispatches<=N
 # fetches<=M` budgets checked by the tools/analyze dispatch pass, the
 # lru_cache'd factories here are the retrace pass's sanctioned
-# memoization shape, and the runtime RetraceGuard (bench --smoke, CI)
-# asserts zero steady-state recompiles through these kernels.
+# memoization shape, and the runtime RetraceGuard (the tier-1
+# test_retrace_guard_zero_steady_state_* tests) asserts zero
+# steady-state recompiles through these kernels.
 
 
 def _reset_slots_tree(spec: LatticeSpec, state, rs):

@@ -28,7 +28,10 @@ this module makes the device a first-class subsystem of /metrics:
   the `kernel_device_ms{family}` histogram next to the host-wall
   `kernel_dispatch_ms`. Disarmed cost is ONE attribute read + one
   branch (the FAULTS / FlowGovernor / locktrace discipline), and the
-  disarmed sampler records ZERO state — `bench.py --smoke` gates both.
+  disarmed sampler records ZERO state after a served run
+  (`tests/test_append_framed.py::test_served_steady_state_compiles_nothing`);
+  armed at rate 1 it records and the steady state still compiles nothing
+  (`tests/test_devicecost.py::test_armed_sampler_records_and_compiles_nothing`).
 """
 
 from __future__ import annotations
@@ -256,11 +259,12 @@ class DeviceTimeSampler:
     `active` is a plain attribute (False while disarmed) — the
     disarmed hot-path cost inside `kernel_family` is one attribute
     read + one branch, and the disarmed sampler holds ZERO state (no
-    tick counters, no sample rings): `bench.py --smoke` gates both.
+    tick counters, no sample rings): the served retrace test of
+    `tests/test_append_framed.py` gates both.
     Armed, every Nth dispatch per family is measured as a fenced
     block-until-ready pair; the milliseconds land in the bounded
-    per-family rings (bench attribution) and in every registered stats
-    sink's `kernel_device_ms{family}` histogram."""
+    per-family rings and in every registered stats sink's
+    `kernel_device_ms{family}` histogram."""
 
     MAX_SAMPLES = 256
 
@@ -351,21 +355,6 @@ class DeviceTimeSampler:
             return {"counts": dict(self._counts),
                     "samples": {k: len(v)
                                 for k, v in self._samples.items()}}
-
-    def percentiles(self) -> dict[str, dict[str, float]]:
-        """family -> {count, p50, p99} over the bounded sample rings
-        (the bench's device_time_ms attribution)."""
-        out: dict[str, dict[str, float]] = {}
-        with self._lock:
-            rings = {k: sorted(v) for k, v in self._samples.items() if v}
-        for fam, xs in rings.items():
-            n = len(xs)
-            out[fam] = {
-                "count": n,
-                "p50": round(xs[n // 2], 4),
-                "p99": round(xs[min(n - 1, (n * 99) // 100)], 4),
-            }
-        return out
 
 
 DEVICE_TIME = DeviceTimeSampler()

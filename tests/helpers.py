@@ -9,6 +9,8 @@ from __future__ import annotations
 
 import time
 
+import numpy as np
+
 
 def wait_attached(ctx, query_id: str, timeout: float = 10.0):
     """Block until the query's task is registered AND attached to its
@@ -40,12 +42,22 @@ def wait_any_attached(ctx, timeout: float = 10.0, *, exclude=()):
     raise TimeoutError("no (new) query task attached")
 
 
+def wait_watermark(task, target: int, timeout: float = 60.0) -> None:
+    """Block until the task's executor has taken every event up to
+    `target` (absolute ms): the batch that ended there is absorbed."""
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        ex = task.executor
+        if ex is not None and ex.watermark_abs >= target:
+            return
+        time.sleep(0.005)
+    raise TimeoutError(f"the task did not drain to {target}")
+
+
 def fresh_code_columns(ex) -> list:
     """A session executor's decode columns built from scratch, the plain
     way: one walk of the WHOLE code dictionary a group column, a
     sharded compaction's holes left None."""
-    import numpy as np
-
     out = []
     for g in range(len(ex.group_cols)):
         arr = np.empty(len(ex._code_rev), object)
@@ -69,3 +81,144 @@ def assert_code_columns_fresh(ex) -> None:
     for g, w in zip(got, want):
         assert g.dtype == object and g.shape == w.shape
         assert typed(g.tolist()) == typed(w.tolist())
+
+
+# ---- retrace-gate configurations (zero compiles in steady state) ------------
+
+
+SMOKE_BASE = 1_700_000_000_000
+
+
+def _tumbling_uniq(n: int = 512) -> list:
+    rng = np.random.default_rng(0)
+    return [(rng.integers(0, 100, n).astype(np.int32),
+             (np.rint(rng.normal(20, 5, n) * 10).astype(np.float32)
+              * np.float32(0.1)))
+            for _ in range(4)]
+
+
+_TUMBLING_UNIQ = _tumbling_uniq()
+_TUMBLING_TS = (np.arange(512, dtype=np.int64) * 200) // 512
+
+
+def smoke_tumbling_batch(i: int) -> tuple:
+    """(key ids, temps, ts) of batch i of the fused-close retrace
+    gate's stream: 512 rows over 100 keys, 200 ms of stream a batch.
+    Four pre-generated batches cycled on a FIXED ts template: the
+    adaptive wire codec's combo — and so the compiled step — is
+    identical batch to batch; fresh random data per batch would
+    legitimately grow a new combo mid-run."""
+    kids, temps = _TUMBLING_UNIQ[i % 4]
+    return kids, temps, SMOKE_BASE + i * 200 + _TUMBLING_TS
+
+
+def smoke_tumbling_config():
+    """(executor, feed(i), warm_batches) for the fused-close retrace
+    gate — shared by the tier-1 RetraceGuard tests."""
+    from hstream_tpu.engine import (
+        AggKind, AggSpec, AggregateNode, ColumnType, QueryExecutor,
+        Schema, SourceNode, TumblingWindow,
+    )
+    from hstream_tpu.engine.expr import Col
+
+    schema = Schema.of(device=ColumnType.STRING, temp=ColumnType.FLOAT)
+    node = AggregateNode(
+        child=SourceNode("s", schema), group_keys=[Col("device")],
+        window=TumblingWindow(1_000, grace_ms=0),
+        aggs=[AggSpec(AggKind.COUNT_ALL, "c"),
+              AggSpec(AggKind.SUM, "t", input=Col("temp"))])
+    ex = QueryExecutor(node, schema, emit_changes=False,
+                       initial_keys=256, batch_capacity=1024)
+    for k in range(100):
+        ex.key_id_for((f"d{k}",))
+
+    def feed(i):
+        kids, temps, ts = smoke_tumbling_batch(i)
+        return ex.process_columnar(kids, ts, {"temp": temps})
+
+    # warmup spans >= 2 close cycles at 1s windows / 200ms batches
+    return ex, feed, 15
+
+
+def smoke_join_config(mesh=None):
+    """(executor, feed(b), warm_batches) for the device-join retrace
+    gate — shared by the tier-1 RetraceGuard tests. With `mesh`, the
+    join runs key-sharded (ISSUE 16) and the feed asserts the sharded
+    stores actually activated (no silent degrade)."""
+    from hstream_tpu.sql.codegen import make_executor, stream_codegen
+
+    plan = stream_codegen(
+        "SELECT l.k, COUNT(*) AS c FROM l INNER JOIN r "
+        "WITHIN (INTERVAL 1 SECOND) ON l.k = r.k "
+        "GROUP BY l.k, TUMBLING (INTERVAL 2 SECOND) "
+        "GRACE BY INTERVAL 0 SECOND EMIT CHANGES;")
+    ex = make_executor(plan, sample_rows=[{"k": "k0", "x": 1.0}],
+                       batch_capacity=4096, mesh=mesh)
+    rng = np.random.default_rng(1)
+    keys = np.array([f"k{i}" for i in range(500)], object)
+    n = 256
+    xcol = np.ones(n, np.float32)
+    kcols = [keys[rng.integers(0, 500, n)] for _ in range(4)]
+    ts_template = (np.arange(n, dtype=np.int64) * 200) // n
+
+    def feed(b):
+        ex.process_columnar(
+            SMOKE_BASE + b * 200 + ts_template,
+            {"k": kcols[b % 4], "x": xcol},
+            stream="l" if b % 2 else "r")
+        if mesh is not None and b == 5:
+            assert ex._dev is not None and \
+                ex._dev.get("sjl") is not None, \
+                f"join did not shard: {ex._device_refusal}"
+
+    # warmup must reach the FIRST real eviction (stores half full at
+    # ~32 batches) so the evict kernel's shape compiles before the
+    # guarded region, alongside activation, fused-probe and close
+    return ex, feed, 40
+
+
+def smoke_session_config(mesh=None):
+    """(executor, feed(b), warm_batches) for the device-session retrace
+    gate — shared by the tier-1 RetraceGuard tests. With `mesh`, the
+    session arena runs key-sharded (ISSUE 16) and the feed asserts the
+    sharded arena actually activated."""
+    from hstream_tpu.engine import ColumnType, Schema
+    from hstream_tpu.engine.expr import Col
+    from hstream_tpu.engine.plan import AggKind, AggregateNode, AggSpec, \
+        SourceNode
+    from hstream_tpu.engine.session import SessionExecutor
+    from hstream_tpu.engine.window import SessionWindow
+
+    schema = Schema.of(user=ColumnType.STRING, lat=ColumnType.FLOAT)
+    node = AggregateNode(
+        child=SourceNode("s", schema), group_keys=[Col("user")],
+        window=SessionWindow(2_000, grace_ms=0),
+        aggs=[AggSpec(AggKind.COUNT_ALL, "c"),
+              AggSpec(AggKind.APPROX_QUANTILE, "p50", input=Col("lat"),
+                      quantile=0.5)])
+    kw = {} if mesh is None else {"mesh": mesh}
+    ex = SessionExecutor(node, schema, emit_changes=False, **kw)
+    ex.defer_close_decode = True
+    rng = np.random.default_rng(2)
+    n = 512
+    users = np.array([f"u{i}" for i in range(64)])
+    # cycled pre-generated batches with a FIXED ts template so shapes
+    # and segment counts are stable
+    kcols = [users[rng.integers(0, 64, n)] for _ in range(4)]
+    vcols = [np.abs(rng.normal(50, 20, n)) for _ in range(4)]
+    ts_template = (np.arange(n, dtype=np.int64) % 500)
+    stride = 10_000  # > 2*gap: prior sessions close every batch
+
+    def feed(b):
+        ex.process_columnar(SMOKE_BASE + b * stride + ts_template,
+                            {"user": kcols[b % 4], "lat": vcols[b % 4]})
+        if b % 8 == 7:
+            ex.drain_closed()  # stacked-drain shapes compile in warmup
+        if mesh is not None and b == 5:
+            assert ex._dev is not None and \
+                ex._dev.get("ssl") is not None, \
+                f"sessions did not shard: {ex._device_refusal}"
+
+    # warmup spans activation, the first grow, close cycles, and every
+    # stacked-drain depth the steady state uses
+    return ex, feed, 20

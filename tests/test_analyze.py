@@ -16,6 +16,8 @@ import textwrap
 
 import pytest
 
+import helpers
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
@@ -1675,7 +1677,6 @@ def test_cli_json_output(tmp_path):
     (mini / "tools").mkdir()
     (mini / "hstream_tpu" / "box.py").write_text(
         textwrap.dedent(LOCKED_CLASS.format(waiver="")))
-    (mini / "bench.py").write_text("")
     base = str(tmp_path / "b.json")
     r = subprocess.run(
         [sys.executable, "-m", "tools.analyze", "--only", "locks",
@@ -1712,7 +1713,6 @@ def test_cli_json_stable_order_and_pass_names(tmp_path):
     mini = tmp_path / "mini"
     (mini / "hstream_tpu").mkdir(parents=True)
     (mini / "tools").mkdir()
-    (mini / "bench.py").write_text("")
     # two findings from two passes in one file: locks + waitholding
     (mini / "hstream_tpu" / "box.py").write_text(textwrap.dedent('''
     import threading
@@ -1757,24 +1757,6 @@ def test_cli_json_stable_order_and_pass_names(tmp_path):
 # ---- RetraceGuard: runtime recompile contract (ISSUE 7) --------------------
 
 
-@pytest.fixture
-def retrace_guard():
-    """Context factory asserting ZERO XLA compiles inside the block —
-    the runtime complement of the static retrace pass."""
-    import contextlib
-
-    from hstream_tpu.common.tracing import RetraceGuard
-
-    @contextlib.contextmanager
-    def guard_zero():
-        with RetraceGuard() as g:
-            yield g
-        assert g.count == 0, \
-            f"steady state compiled {g.count} new XLA executable(s)"
-
-    return guard_zero
-
-
 def test_retrace_guard_counts_first_compile():
     import jax
     import jax.numpy as jnp
@@ -1792,10 +1774,8 @@ def test_retrace_guard_counts_first_compile():
 
 def test_retrace_guard_zero_steady_state_fused_close(retrace_guard):
     """50 post-warmup fused-close batches compile NOTHING (the
-    acceptance contract; same config the CI smoke gate runs)."""
-    import bench
-
-    ex, feed, warm = bench._smoke_tumbling_config()
+    acceptance contract)."""
+    ex, feed, warm = helpers.smoke_tumbling_config()
     for i in range(warm):
         feed(i)
     ex.block_until_ready()
@@ -1805,12 +1785,28 @@ def test_retrace_guard_zero_steady_state_fused_close(retrace_guard):
         ex.block_until_ready()
 
 
-def test_retrace_guard_zero_steady_state_device_session(retrace_guard):
-    """50 post-warmup device-session micro-batches (steps, close
-    extracts, stacked deferred drains) compile NOTHING (ISSUE 10)."""
-    import bench
+def _mesh(shape):
+    """None, or the 1x8 key-sharded mesh over the 8 virtual devices
+    tests/conftest.py gives the process."""
+    if shape is None:
+        return None
+    import jax
 
-    ex, feed, warm = bench._smoke_session_config()
+    from hstream_tpu.parallel import make_mesh
+
+    assert jax.device_count() >= 8, f"{jax.device_count()} devices"
+    return make_mesh(n_data=1, n_key=8)
+
+
+@pytest.mark.parametrize("mesh", [None, "1x8"])
+def test_retrace_guard_zero_steady_state_device_session(retrace_guard,
+                                                        mesh):
+    """50 post-warmup device-session micro-batches (steps, close
+    extracts, stacked deferred drains) compile NOTHING (ISSUE 10),
+    single-chip and key-sharded over 8 devices (ISSUE 16: sharded
+    activation, arena step/merge and stacked drains all compile during
+    warm-up)."""
+    ex, feed, warm = helpers.smoke_session_config(mesh=_mesh(mesh))
     for b in range(warm):
         feed(b)
     ex.flush_changes()
@@ -1825,11 +1821,12 @@ def test_retrace_guard_zero_steady_state_device_session(retrace_guard):
     assert st["step_dispatches"] == st["batches"]
 
 
-def test_retrace_guard_zero_steady_state_device_join(retrace_guard):
-    """50 post-warmup device-join micro-batches compile NOTHING."""
-    import bench
-
-    ex, feed, warm = bench._smoke_join_config()
+@pytest.mark.parametrize("mesh", [None, "1x8"])
+def test_retrace_guard_zero_steady_state_device_join(retrace_guard, mesh):
+    """50 post-warmup device-join micro-batches compile NOTHING,
+    single-chip and key-sharded over 8 devices (fused probe+insert and
+    evict included)."""
+    ex, feed, warm = helpers.smoke_join_config(mesh=_mesh(mesh))
     for b in range(warm):
         feed(b)
     ex.flush_changes()
@@ -1946,7 +1943,6 @@ def test_cli_baseline_gate(tmp_path):
     (mini / "tools").mkdir()
     bad = textwrap.dedent(LOCKED_CLASS.format(waiver=""))
     (mini / "hstream_tpu" / "box.py").write_text(bad)
-    (mini / "bench.py").write_text("")
     base = str(tmp_path / "b.json")
 
     def cli(*extra):

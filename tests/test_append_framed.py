@@ -4,6 +4,7 @@ path (same rows, same record ids), the streaming variant, and the
 malformed/torn/overlong-frame refusal contract (typed INVALID_ARGUMENT,
 never a partial ingest)."""
 
+import contextlib
 import time
 
 import grpc
@@ -21,7 +22,7 @@ from hstream_tpu.server.appendfront import AppendFront
 from hstream_tpu.server.main import serve
 from hstream_tpu.store.memstore import MemLogStore
 
-from helpers import wait_attached
+from helpers import wait_attached, wait_watermark
 
 BASE = 1_700_000_000_000
 
@@ -224,15 +225,22 @@ def test_append_front_per_log_fifo_and_errors():
 
 # ---- server: equivalence + streaming + refusals ---------------------------
 
+@contextlib.contextmanager
+def _served(**options):
+    server, ctx = serve("127.0.0.1", 0, "mem://", **options)
+    channel = grpc.insecure_channel(f"127.0.0.1:{ctx.port}")
+    try:
+        yield HStreamApiStub(channel), ctx
+    finally:
+        channel.close()
+        server.stop(grace=1)
+        ctx.shutdown()
+
+
 @pytest.fixture(scope="module")
 def server_stub():
-    server, ctx = serve("127.0.0.1", 0, "mem://")
-    channel = grpc.insecure_channel(f"127.0.0.1:{ctx.port}")
-    stub = HStreamApiStub(channel)
-    yield stub, ctx
-    channel.close()
-    server.stop(grace=1)
-    ctx.shutdown()
+    with _served() as pair:
+        yield pair
 
 
 def _mk_batches(n_batches, n, seed=3):
@@ -261,14 +269,14 @@ def _view_rows(stub, view, pred, timeout=30):
     return rows
 
 
-def _mk_view(stub, ctx, view, src):
+def _mk_view(stub, ctx, view, src, metadata=None):
     stub.CreateStream(pb.Stream(stream_name=src))
     stub.ExecuteQuery(pb.CommandQuery(
         stmt_text=f"CREATE VIEW {view} AS SELECT device, COUNT(*) AS c, "
                   f"SUM(temp) AS s FROM {src} "
                   f"GROUP BY device, TUMBLING (INTERVAL 10 SECOND) "
-                  f"GRACE BY INTERVAL 0 SECOND;"))
-    wait_attached(ctx, f"view-{view}")
+                  f"GRACE BY INTERVAL 0 SECOND;"), metadata=metadata)
+    return wait_attached(ctx, f"view-{view}")
 
 
 def test_framed_equals_protobuf_append(server_stub):
@@ -328,6 +336,71 @@ def test_streaming_append_one_call_many_batches(server_stub):
         lambda rs: sum(r["c"] for r in rs if "c" in r) >= 8 * 256)
     assert sum(r["c"] for r in rows
                if r.get("winStart") < BASE + 60_000) == 8 * 256
+
+
+def test_served_steady_state_compiles_nothing(retrace_guard):
+    """The served retrace gate (ISSUE 12): AppendColumnarStream -> frame
+    door -> append front -> store -> query task -> staged device step ->
+    window close hits only shapes the warm-up compiled, over 50 steady
+    batches. Tracing runs at sample rate 1 (every call carries a request
+    id, so RPC and task-stage spans record), the stats reporter folds
+    and the placer loop sweeps DURING the guarded run, and the guarded
+    region scrapes the stats planes: all host-only by construction. The
+    lock witness and the device-time sampler stay disarmed throughout,
+    and must come out of a served run holding no state at all (the
+    one-attribute-read + one-branch disarmed contract)."""
+    from hstream_tpu.common.locktrace import LOCKTRACE
+    from hstream_tpu.stats.devicecost import DEVICE_TIME
+    from hstream_tpu.stats.prometheus import render_metrics
+
+    assert not LOCKTRACE.active and not DEVICE_TIME.active
+    warm, steady = 20, 50
+    # four batches cycled, each at its own offsets inside its second:
+    # stable wire combos -> stable shapes (fresh random data a batch
+    # would legitimately grow a new combo mid-run)
+    uniq = _mk_batches(4, 512)
+
+    def frame(b):
+        ts, cols = uniq[b % 4]
+        ts = ts + (b - b % 4) * 1000
+        return int(ts[-1]), encode_batch(ts, cols)
+
+    with _served(trace_sample=1.0, load_report_interval_ms=500,
+                 placer_interval_ms=200) as (stub, ctx):
+        task = _mk_view(stub, ctx, "rtv", "rtsrc",
+                        metadata=(("x-request-id", "retrace-create"),))
+
+        def stream_batches(lo, hi):
+            frames = [frame(b) for b in range(lo, hi)]
+            stub.AppendColumnarStream(
+                iter([pb.AppendColumnarRequest(stream_name="rtsrc",
+                                               blocks=[f])
+                      for _last, f in frames]),
+                metadata=(("x-request-id", f"retrace-{lo}"),))
+            wait_watermark(task, frames[-1][0])
+
+        for b in range(3):  # slow path first: one batch per poll
+            last, f = frame(b)
+            stub.AppendColumnar(pb.AppendColumnarRequest(
+                stream_name="rtsrc", blocks=[f]))
+            wait_watermark(task, last)
+        stream_batches(3, warm)  # burst: spans window closes
+        with retrace_guard():
+            stream_batches(warm, warm + steady)
+            render_metrics(ctx)
+            for verb, args in (("stats", {"entity": "streams"}),
+                               ("placer", {})):
+                stub.SendAdminCommand(pb.AdminCommandRequest(
+                    command=verb, args=rec.dict_to_struct(args)))
+            stub.ClusterStats(pb.ClusterStatsRequest())
+        # the planes were armed, not just configured
+        assert ctx.tracing.spans("rtsrc") and ctx.tracing.spans("view-rtv")
+        assert ctx.placer.armed
+    assert LOCKTRACE.edge_count() == 0
+    assert not LOCKTRACE.status()["locks"]
+    state = DEVICE_TIME.state()
+    assert not any(state["counts"].values())
+    assert not any(state["samples"].values())
 
 
 def test_bad_frame_refused_no_partial_ingest(server_stub):

@@ -35,7 +35,7 @@ from hstream_tpu.http_gateway import serve_gateway
 from hstream_tpu.server.main import serve
 from hstream_tpu.sql.codegen import make_executor, stream_codegen
 
-from helpers import wait_attached
+from helpers import smoke_tumbling_config, wait_attached
 
 BASE = 1_700_000_000_000
 
@@ -145,6 +145,37 @@ def test_plane_bytes_skips_non_arrays_and_empty():
                        "empty": np.zeros((0,), np.int32),
                        "scalarish": 7})
     assert got == {"a": 32}
+
+
+# ---- per-dispatch device time ------------------------------------------------
+
+
+def test_armed_sampler_records_and_compiles_nothing(retrace_guard):
+    """The sampler ARMED at rate 1 fences and times every dispatch: it
+    records samples for every family it ticked, and 50 steady batches
+    still compile nothing (block_until_ready is a sync, never a trace).
+    The DISARMED half of the contract, no state after a served run, is
+    tests/test_append_framed.py::test_served_steady_state_compiles_nothing."""
+    from hstream_tpu.stats.devicecost import DEVICE_TIME
+
+    assert not DEVICE_TIME.active
+    ex, feed, warm = smoke_tumbling_config()
+    DEVICE_TIME.arm(1)
+    try:
+        for i in range(warm):
+            feed(i)
+        ex.block_until_ready()
+        with retrace_guard():
+            for i in range(warm, warm + 50):
+                feed(i)
+            ex.block_until_ready()
+        state = DEVICE_TIME.state()
+    finally:
+        DEVICE_TIME.disarm()
+        DEVICE_TIME.reset()
+    assert state["counts"] and all(
+        state["samples"].get(family) for family in state["counts"])
+    assert not any(DEVICE_TIME.state().values())
 
 
 # ---- compiled-program inventory --------------------------------------------

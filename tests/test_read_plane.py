@@ -34,7 +34,7 @@ from hstream_tpu.server.views import (
 from hstream_tpu.sql.codegen import stream_codegen
 from hstream_tpu.store import open_store
 
-from helpers import wait_attached
+from helpers import smoke_tumbling_batch, wait_attached, wait_watermark
 
 BASE = 1_700_000_000_000
 
@@ -476,6 +476,50 @@ def test_drop_view_invalidates_server_cache(server_stub):
     stub.ExecuteQuery(pb.CommandQuery(stmt_text="DROP VIEW dview;"))
     assert all(k[1] != "dview" for k in ctx.read_cache._entries
                if k[0] == "snap")
+
+
+def test_steady_state_pulls_compile_nothing(server_stub, retrace_guard):
+    """The read-plane retrace gate (ISSUE 20): over a live view whose
+    windows keep closing (1 s windows, 200 ms of stream a batch), 50
+    steady batches each followed by a version-miss pull (one batched
+    peek extract), the same pull again (a cache hit, no device) and a
+    closed-only pull (the fast path, never peeks) compile ZERO new XLA
+    executables."""
+    from hstream_tpu.client.producer import encode_batch
+
+    stub, ctx = server_stub
+    stub.CreateStream(pb.Stream(stream_name="rgsrc"))
+    stub.ExecuteQuery(pb.CommandQuery(
+        stmt_text="CREATE VIEW rgview AS SELECT device, COUNT(*) AS c, "
+                  "SUM(temp) AS t FROM rgsrc GROUP BY device, "
+                  "TUMBLING (INTERVAL 1 SECOND) "
+                  "GRACE BY INTERVAL 0 SECOND;"))
+    task = wait_attached(ctx, "view-rgview")
+    warm, steady = 15, 50
+    devices = np.array([f"d{k}" for k in range(100)])
+
+    def step(i):  # the fused-close gate's stream, served
+        kids, temp, ts = smoke_tumbling_batch(i)
+        stub.AppendColumnar(pb.AppendColumnarRequest(
+            stream_name="rgsrc", blocks=[encode_batch(
+                ts, {"device": devices[kids], "temp": temp})]))
+        wait_watermark(task, int(ts[-1]))
+        for sql in ("SELECT * FROM rgview;", "SELECT * FROM rgview;",
+                    "SELECT * FROM rgview WHERE winEnd < 1;"):
+            stub.ExecuteQuery(pb.CommandQuery(stmt_text=sql))
+
+    for i in range(warm):
+        step(i)
+    before = ctx.read_cache.stats()
+    with retrace_guard():
+        for i in range(warm, warm + steady):
+            step(i)
+    after = ctx.read_cache.stats()
+    # all three kinds of serve ran under the guard: a batch gives one
+    # recompute that peeks, hits, and one recompute that does not peek
+    d = {k: after[k] - before[k] for k in ("hits", "misses", "extracts")}
+    assert d["extracts"] >= steady and d["hits"] > 0
+    assert d["misses"] - d["extracts"] >= steady
 
 
 # ---- concurrent readers under the lock-order witness ------------------------

@@ -660,21 +660,6 @@ def test_migrate_store_int32_span_guard():
     assert ex.device_fallbacks == 1  # no re-activation attempts
 
 
-def test_measure_rtt_jit_is_memoized():
-    """retrace-uncached-jit fix: bench.measure_rtt built a fresh
-    jax.jit wrapper per call; the kernel now comes from an lru_cache
-    factory, so repeated calls reuse ONE compiled executable."""
-    import bench
-    from hstream_tpu.common.tracing import RetraceGuard
-
-    assert bench._rtt_step() is bench._rtt_step()
-    bench.measure_rtt()  # warm (compiles once)
-    with RetraceGuard() as g:
-        bench.measure_rtt()
-        bench.measure_rtt()
-    assert g.count == 0, "measure_rtt retraced after warmup"
-
-
 # ---- ISSUE 8: fault injection + self-healing hardening ----------------------
 
 

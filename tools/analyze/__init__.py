@@ -77,7 +77,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 
 # production code the passes scan; tests are excluded on purpose (they
 # deliberately exercise error paths, fake blocking, etc.)
-SCAN_ROOTS = ("hstream_tpu", "tools", "bench.py")
+SCAN_ROOTS = ("hstream_tpu", "tools")
 # generated protobuf output: no hand-written invariants to check
 SKIP_PARTS = ("__pycache__", os.path.join("hstream_tpu", "proto"),
               os.path.join("tools", "analyze"))
