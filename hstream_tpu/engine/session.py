@@ -21,8 +21,10 @@ The HOST path below is retained in full as the equivalence reference
 (`use_device_sessions=False`): per-batch segmentation vectorized in
 numpy, per-segment accumulators via reduceat, segment merges into
 per-key Python session state. The device path keeps an exact host-side
-interval MIRROR (code, t0, t1 — no accumulators) of the arena, updated
-with the numpy twin of the kernel's sort+scan: the mirror decides
+interval MIRROR (code, t0, t1 — no accumulators) of the arena, kept
+sorted as the arena is: a batch's segments are merged into it with the
+numpy twin of the kernel's sort+scan over the rows of the keys the
+batch names (merge_into_mirror_np). The mirror decides
 late-record drops (the order-dependent part of the reference
 semantics), close cycles, capacity, and slot indices with zero device
 syncs. The executor degrades per-executor to the host path — PR 8
@@ -157,12 +159,16 @@ def merge_chains_np(code: np.ndarray, t0: np.ndarray, t1: np.ndarray,
     break a chain at a code change or where t0 exceeds the running max
     end + gap — the exact fixpoint of sequential merge-on-overlap
     (interval clustering is confluent: merging only grows intervals).
-    This is the numpy twin of lattice._session_chain_slots, so the
-    returned chains are, in order, exactly the device arena's slots.
+    This is the numpy twin of lattice._session_chain_slots and the one
+    statement of the chain rule on the host. It sorts whatever it is
+    handed: a batch hands it the mirror rows of the keys it names and
+    its own segments (merge_into_mirror_np), the whole mirror only
+    where the mirror's order is not known.
 
-    Returns (code, t0, t1) per chain plus the max number of the FIRST
-    `n_first` input entries (the open-session mirror) landing in one
-    chain — the pathological-overlap-chain detector."""
+    Returns (code, t0, t1) per chain, sorted by (code, t0), plus the
+    max number of the FIRST `n_first` input entries (the open-session
+    mirror) landing in one chain — the pathological-overlap-chain
+    detector."""
     n = len(code)
     if n == 0:
         e = np.empty(0, np.int64)
@@ -195,6 +201,58 @@ def merge_chains_np(code: np.ndarray, t0: np.ndarray, t1: np.ndarray,
         if len(first):
             fanin = int(np.bincount(first).max())
     return mcode, mt0, mt1, fanin
+
+
+def merge_into_mirror_np(mir_code: np.ndarray, mir_t0: np.ndarray,
+                         mir_t1: np.ndarray, live: np.ndarray,
+                         seg_code: np.ndarray, seg_t0: np.ndarray,
+                         seg_t1: np.ndarray, gap: int,
+                         ordered: bool = True
+                         ) -> tuple[np.ndarray, np.ndarray, np.ndarray,
+                                    int, int]:
+    """The open-session mirror after a batch's segments: what
+    merge_chains_np returns over the `live` mirror rows and the
+    segments together (the same arrays, element for element, and the
+    same fanin), for the cost of the keys the batch names. The live
+    rows are what the last merge left, less the rows that closed:
+    sorted by (code, t0), and two rows of one code lie more than a gap
+    apart. So a row whose code no segment carries is a chain of one and
+    stays as it is; the rows of the codes the batch names (two
+    searchsorted give each code's run) go through merge_chains_np with
+    the segments, and their chains are put back among the untouched
+    rows by code alone: a touched code and an untouched one are never
+    equal. With `ordered` False (the rows' global order is not known:
+    after an activation, after a key-sharded code compaction) every
+    live row counts as touched.
+
+    Returns (code, t0, t1, fanin, n_touched): the new mirror, the
+    largest number of open sessions one chain merged, and the mirror
+    rows handed to the chain merge."""
+    touched = np.flatnonzero(live)
+    n_live = len(touched)
+    if ordered:
+        code = mir_code[touched]
+        named = np.unique(seg_code)
+        lo = np.searchsorted(code, named, side="left")
+        cnt = np.searchsorted(code, named, side="right") - lo
+        # each run's rows lo .. lo+cnt-1, the runs back to back
+        touched = touched[np.repeat(lo - (np.cumsum(cnt) - cnt), cnt)
+                          + np.arange(int(cnt.sum()))]
+    n_touched = len(touched)
+    mcode, mt0, mt1, fanin = merge_chains_np(
+        np.concatenate([mir_code[touched], seg_code]),
+        np.concatenate([mir_t0[touched], seg_t0]),
+        np.concatenate([mir_t1[touched], seg_t1]),
+        gap, n_first=n_touched)
+    if n_touched == n_live:
+        return mcode, mt0, mt1, fanin, n_touched
+    keep = live.copy()
+    keep[touched] = False
+    kcode = mir_code[keep]
+    # a chain goes in behind the untouched rows of smaller codes
+    at = np.searchsorted(kcode, mcode)
+    return (np.insert(kcode, at, mcode), np.insert(mir_t0[keep], at, mt0),
+            np.insert(mir_t1[keep], at, mt1), max(fanin, 1), n_touched)
 
 
 # ---- session state ---------------------------------------------------------
@@ -346,6 +404,11 @@ class SessionExecutor:
             # made over the whole dictionary (activation, compaction,
             # host reset), and entries appended to them since
             "code_cols_builds": 0, "code_cols_appended": 0,
+            # the mirror update: mirror rows handed to the chain merge
+            # (the rows of the keys a batch names), and batches that
+            # handed it every row (the first after an activation or a
+            # key-sharded code compaction)
+            "mirror_rows_merged": 0, "mirror_full_merges": 0,
         }
         # observability plane (ISSUE 13): per-family dispatch observer,
         # late-record drop count (both engines decide lateness on the
@@ -1014,7 +1077,8 @@ class SessionExecutor:
     # micro-batch is ONE fused sort + segmented-scan merge dispatch and
     # ZERO fetches; close cycles and peeks are one pow2-padded extract
     # dispatch + one fetch each. The host keeps an exact interval
-    # mirror (merge_chains_np — the numpy twin of the kernel's scan)
+    # mirror (merge_into_mirror_np, over merge_chains_np — the numpy
+    # twin of the kernel's scan)
     # that decides late-record drops, close sets, capacity, and slot
     # indices with no device sync. The host engine above is the
     # equivalence reference and the degrade target (PR 8 pattern).
@@ -1212,6 +1276,11 @@ class SessionExecutor:
             "mir_t0": mir_t0,
             "mir_t1": mir_t1,
             "mir_live": np.ones(n, np.bool_),
+            # whether the live rows are known to be what a chain merge
+            # leaves: sorted by (code, t0), rows of one code more than
+            # a gap apart. The first batch settles it with a merge of
+            # every row (host sessions are taken as they come)
+            "mir_ordered": False,
             "bcaps": set(),
             "scaps": set(),
             "pcaps": set(),
@@ -1699,8 +1768,12 @@ class SessionExecutor:
     # contract: dispatches<=1 fetches<=0
     def _process_device(self, codes, ts, feed, pre_max):
         """One device micro-batch: mirror-side late walk + segmentation
-        + chain merge (numpy), then ONE fused kernel dispatch and NO
-        fetch — the session ingest contract. Closes ride
+        (numpy), the segments merged into the sorted mirror
+        (merge_into_mirror_np: the chain merge runs over the rows of
+        the keys the batch names, over every row only where
+        `mir_ordered` is False), then ONE fused kernel dispatch and NO
+        fetch — the session ingest contract. Row i of the mirror is
+        slot i of the arena after the step. Closes ride
         _close_due_device (their own one-dispatch-one-fetch budget)."""
         from hstream_tpu.engine import lattice
 
@@ -1738,12 +1811,13 @@ class SessionExecutor:
             seg_code = ks[starts]
             seg_t0 = tss[starts]
             seg_t1 = tss[ends - 1]
-            live = dev["mir_live"]
-            mcode, mt0, mt1, fanin = merge_chains_np(
-                np.concatenate([dev["mir_code"][live], seg_code]),
-                np.concatenate([dev["mir_t0"][live], seg_t0]),
-                np.concatenate([dev["mir_t1"][live], seg_t1]),
-                gap, n_first=int(live.sum()))
+            ordered = dev["mir_ordered"]
+            mcode, mt0, mt1, fanin, touched = merge_into_mirror_np(
+                dev["mir_code"], dev["mir_t0"], dev["mir_t1"],
+                dev["mir_live"], seg_code, seg_t0, seg_t1, gap, ordered)
+            self.session_stats["mirror_rows_merged"] += touched
+            if not ordered:
+                self.session_stats["mirror_full_merges"] += 1
             mirror.end()
             if fanin > self.chain_merge_limit:
                 self._degrade_to_host(
@@ -1802,6 +1876,7 @@ class SessionExecutor:
             dev["mir_t0"] = mt0
             dev["mir_t1"] = mt1
             dev["mir_live"] = np.ones(len(mcode), np.bool_)
+            dev["mir_ordered"] = True
         mirror.end()  # a batch the late walk emptied
         return self._advance_and_close_device(pre_max)
 
@@ -2166,6 +2241,10 @@ class SessionExecutor:
             # shard residue (negative = poison, residue survives the
             # floor modulo) so per-shard slot ranks stay aligned
             new_code[~live] = dev["mir_code"][~live] % ns - ns
+            # class-strided codes keep every shard's order and break
+            # the global one; the rows must stay on their slots until
+            # the next step, whose merge takes every row and sorts
+            dev["mir_ordered"] = False
         dev["mir_code"] = new_code
         # sharded new codes are class-strided, so the reverse index may
         # carry holes (None); only live codes ever decode through it

@@ -83,6 +83,74 @@ def assert_code_columns_fresh(ex) -> None:
         assert typed(g.tolist()) == typed(w.tolist())
 
 
+def assert_mirror_is_the_arena(ex) -> None:
+    """Row i of a session executor's interval mirror is slot i of its
+    device arena (per shard: its rank within its residue class): the
+    arena's code / t0 / t1 fetched at the live rows' slots equal the
+    mirror's, and the arena holds no more sessions than the mirror has
+    rows (a closed row keeps its slot until the next step)."""
+    import jax
+
+    from hstream_tpu.engine.lattice import SESSION_SENT_CODE
+
+    dev = ex._dev
+    arena = jax.device_get({k: dev["arena"][k] for k in ("code", "t0", "t1")})
+    held = int((arena["code"] != SESSION_SENT_CODE).sum())
+    if dev.get("ssl") is not None:
+        cls, slot = ex._shard_slots()
+        arena = {k: v[cls, slot] for k, v in arena.items()}
+    live = np.nonzero(dev["mir_live"])[0]
+    assert len(live) <= held <= len(dev["mir_live"])
+    np.testing.assert_array_equal(arena["code"][live], dev["mir_code"][live])
+    for t in ("t0", "t1"):
+        np.testing.assert_array_equal(
+            arena[t][live].astype(np.int64) + ex.epoch, dev["mir_" + t][live])
+
+
+def mirror_run(big: int, seed: int = 11, n_batches: int = 14):
+    """(rows, ts) batches of a session stream (gap 500 ms, grace 0) that
+    holds closes with every batch, ONE batch of `big` distinct keys (an
+    arena growth) and, with `_KEY_CACHE_MAX` low, code compactions
+    before and after it; some keys hold several open sessions."""
+    rng = np.random.default_rng(seed)
+    for b in range(n_batches):
+        n = big if b == 6 else 160
+        ids = b * 31 + (np.arange(n) if b == 6 else rng.integers(0, 70, n))
+        # two bursts a batch, more than a gap apart: a key in both has
+        # two sessions open until the close
+        ts = SMOKE_BASE + b * 2000 + rng.integers(0, 300, n) \
+            + 900 * rng.integers(0, 2, n)
+        yield ([{"k": f"u{int(i)}", "v": float(i % 9)} for i in ids],
+               ts.tolist())
+
+
+def assert_mirror_tracks_the_arena(ex, batches) -> dict:
+    """Drive `ex` through `batches`: the mirror equals the arena after
+    every step, and between a code compaction and the step behind it;
+    `mirror_full_merges` rises with the first batch (activation) and
+    with the step behind a key-sharded compaction, never else. Returns
+    the executor's `session_stats`."""
+    compact = ex._compact_codes_device
+
+    def compacting():
+        compact()
+        assert_mirror_is_the_arena(ex)
+
+    ex._compact_codes_device = compacting
+    st = ex.session_stats
+    for i, (rows, ts) in enumerate(batches):
+        remaps, full = st["remap_dispatches"], st["mirror_full_merges"]
+        ex.process(rows, ts)
+        assert ex._dev is not None and ex.device_fallbacks == 0
+        assert_mirror_is_the_arena(ex)
+        sharded = ex._dev.get("ssl") is not None
+        compacted = st["remap_dispatches"] - remaps
+        assert st["mirror_full_merges"] - full == (
+            1 if i == 0 or (sharded and compacted) else 0), i
+        assert ex._dev["mir_ordered"]
+    return st
+
+
 # ---- retrace-gate configurations (zero compiles in steady state) ------------
 
 

@@ -16,12 +16,21 @@ from __future__ import annotations
 
 import numpy as np
 import pytest
-from helpers import assert_code_columns_fresh, typed
+from helpers import (
+    assert_code_columns_fresh,
+    assert_mirror_tracks_the_arena,
+    mirror_run,
+    typed,
+)
 
 from hstream_tpu.engine import ColumnType, Schema
 from hstream_tpu.engine.expr import Col
 from hstream_tpu.engine.plan import AggKind, AggregateNode, AggSpec, SourceNode
-from hstream_tpu.engine.session import SessionExecutor
+from hstream_tpu.engine.session import (
+    SessionExecutor,
+    merge_chains_np,
+    merge_into_mirror_np,
+)
 from hstream_tpu.engine.window import SessionWindow
 
 BASE = 1_700_000_000_000
@@ -953,3 +962,210 @@ def test_decode_columns_across_capture_and_restore(mode):
     assert keyed_rows(od, ("k", "u")) == keyed_rows(oh, ("k", "u"))
     assert keyed_rows(restored.peek(), ("k", "u")) \
         == keyed_rows(exh.peek(), ("k", "u"))
+
+
+# ---- the mirror update: a merge into a sorted array (ISSUE 32) --------------
+
+MIRROR_GAP = 100
+
+
+def open_mirror(rng, keys, per_key):
+    """A mirror as a chain merge leaves it: `per_key` open sessions for
+    each code of `keys`, sorted by (code, t0), sessions of one code
+    more than a gap apart."""
+    code = np.repeat(np.asarray(keys, np.int64), per_key)
+    t0 = np.tile(np.arange(per_key) * 1000, len(keys)) \
+        + rng.integers(0, 300, len(code))
+    t1 = t0 + rng.integers(0, 400, len(code))
+    mcode, mt0, mt1, _ = merge_chains_np(code, t0, t1, MIRROR_GAP)
+    assert len(mcode) == len(code)  # already chains of one
+    return mcode, mt0, mt1
+
+
+def segments(rng, keys, n):
+    """`n` batch segments over `keys`, sorted by (code, t0) as the
+    segmentation's argsort leaves them."""
+    code = rng.choice(np.asarray(keys, np.int64), n)
+    t0 = rng.integers(-200, 5200, n)
+    t1 = t0 + rng.integers(0, 250, n)
+    order = np.lexsort((t0, code))
+    return code[order], t0[order], t1[order]
+
+
+def _rows(*ivs):
+    """(code, t0, t1) arrays of the given (code, t0, t1) intervals."""
+    a = np.array(ivs, np.int64).reshape(-1, 3)
+    return a[:, 0], a[:, 1], a[:, 2]
+
+
+def union_merge(mc, m0, m1, live, sc, s0, s1):
+    """The oracle: `merge_chains_np` over the live mirror rows and the
+    segments together, as every batch ran it before ISSUE 32."""
+    return merge_chains_np(np.concatenate([mc[live], sc]),
+                           np.concatenate([m0[live], s0]),
+                           np.concatenate([m1[live], s1]), MIRROR_GAP,
+                           n_first=int(live.sum()))
+
+
+def _mirror_case(name):
+    """(mirror, live mask, segments) of one named case."""
+    rng = np.random.default_rng(sum(name.encode()))
+    keys = list(range(3, 60, 2))
+    mir = open_mirror(rng, keys, 4)
+    live = np.ones(len(mir[0]), np.bool_)
+    if name == "empty_mirror":
+        return _rows(), np.ones(0, np.bool_), segments(rng, keys, 40)
+    if name == "no_open_key":      # even codes: none is in the mirror
+        return mir, live, segments(rng, [2, 8, 30, 58, 60, 90], 25)
+    if name == "every_key":
+        return mir, live, segments(rng, keys, 400)
+    if name == "few_keys":
+        return mir, live, segments(rng, keys[5:9], 12)
+    if name == "several_open_sessions":
+        return mir, live, segments(rng, keys[:2], 6)
+    if name == "dead_rows":
+        return mir, rng.random(len(live)) < 0.6, segments(rng, keys, 60)
+    if name == "all_rows_dead":
+        return mir, ~live, segments(rng, keys, 30)
+    # one key, two open sessions [0, 50] and [400, 450], gap 100
+    two = _rows((7, 0, 50), (7, 400, 450), (9, 0, 10))
+    seg = {
+        "bridge": _rows((7, 120, 320)),          # joins both
+        "gap_away_joins": _rows((7, 150, 200)),   # 150 - 50 == gap
+        "gap_plus_one_splits": _rows((7, 151, 299)),  # and 400 - 299
+        "joins_the_later_only": _rows((7, 151, 300)),  # 400 - 300 == gap
+        "first_and_last_codes": _rows((1, 5, 6), (7, 60, 70), (11, 0, 1)),
+    }[name]
+    return two, np.ones(3, np.bool_), seg
+
+
+MIRROR_CASES = [
+    "empty_mirror", "no_open_key", "every_key", "few_keys",
+    "several_open_sessions", "dead_rows", "all_rows_dead", "bridge",
+    "gap_away_joins", "gap_plus_one_splits", "joins_the_later_only",
+    "first_and_last_codes"]
+
+
+@pytest.mark.parametrize("name", MIRROR_CASES)
+def test_the_mirror_update_equals_the_merge_of_the_union(name):
+    """`merge_into_mirror_np` returns what `merge_chains_np` returns
+    over the live mirror rows and the segments together: the same
+    arrays, element for element, the same fanin; and it hands the chain
+    merge the rows of the named keys alone."""
+    (mc, m0, m1), live, (sc, s0, s1) = _mirror_case(name)
+    want = union_merge(mc, m0, m1, live, sc, s0, s1)
+    *got, touched = merge_into_mirror_np(mc, m0, m1, live, sc, s0, s1,
+                                         MIRROR_GAP)
+    for g, w in zip(got[:3], want[:3]):
+        assert g.dtype == w.dtype == np.int64
+        np.testing.assert_array_equal(g, w)
+    assert got[3] == want[3]
+    assert touched == int(np.isin(mc[live], sc).sum())
+    # a mirror whose order is not known: every live row is touched, and
+    # the rows may come in any order
+    shuffle = np.random.default_rng(1).permutation(len(mc))
+    *got, touched = merge_into_mirror_np(
+        mc[shuffle], m0[shuffle], m1[shuffle], live[shuffle], sc, s0, s1,
+        MIRROR_GAP, ordered=False)
+    for g, w in zip(got[:3], want[:3]):
+        np.testing.assert_array_equal(g, w)
+    assert got[3] == want[3] and touched == int(live.sum())
+
+
+def test_the_named_cases_say_what_they_name():
+    """The hand-made cases do what their names say (the property test
+    would pass as well on cases that all did the same thing)."""
+    def chains(name):
+        (mc, m0, m1), live, seg = _mirror_case(name)
+        c, t0, t1, fanin, _ = merge_into_mirror_np(mc, m0, m1, live,
+                                                   *seg, MIRROR_GAP)
+        return list(zip(c.tolist(), t0.tolist(), t1.tolist())), fanin
+
+    assert chains("bridge") == ([(7, 0, 450), (9, 0, 10)], 2)
+    assert chains("gap_away_joins") == (
+        [(7, 0, 200), (7, 400, 450), (9, 0, 10)], 1)
+    assert chains("gap_plus_one_splits") == (
+        [(7, 0, 50), (7, 151, 299), (7, 400, 450), (9, 0, 10)], 1)
+    assert chains("joins_the_later_only") == (
+        [(7, 0, 50), (7, 151, 450), (9, 0, 10)], 1)
+    assert chains("first_and_last_codes")[0] == [
+        (1, 5, 6), (7, 0, 70), (7, 400, 450), (9, 0, 10), (11, 0, 1)]
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_the_mirror_update_over_random_streams(seed):
+    """Batch after batch over a seeded stream, feeding each result back
+    as the next mirror and closing rows at random in between: the
+    update and the merge of the union never part."""
+    rng = np.random.default_rng(seed)
+    keys = np.arange(0, 400, int(rng.integers(1, 4)))
+    mc, m0, m1 = _rows()
+    merged = 0
+    for b in range(30):
+        n = int(rng.integers(1, 80))
+        sc = rng.choice(keys, n)
+        s0 = b * 150 + rng.integers(0, 600, n)
+        s1 = s0 + rng.integers(0, 120, n)
+        order = np.lexsort((s0, sc))
+        sc, s0, s1 = sc[order], s0[order], s1[order]
+        live = rng.random(len(mc)) < 0.9
+        want = union_merge(mc, m0, m1, live, sc, s0, s1)
+        mc, m0, m1, fanin, touched = merge_into_mirror_np(
+            mc, m0, m1, live, sc, s0, s1, MIRROR_GAP)
+        for g, w in zip((mc, m0, m1), want[:3]):
+            np.testing.assert_array_equal(g, w)
+        assert fanin == want[3]
+        merged += touched
+    assert 0 < merged < 30 * len(mc)
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_the_mirror_is_the_arena_after_every_step(mode):
+    """Row i of the mirror is slot i of the arena, batch after batch,
+    through closes, an arena growth and code compactions; on one device
+    a compaction keeps the mirror's order, so only the first batch
+    hands the chain merge every row."""
+    exd = make_ex([AggSpec(AggKind.COUNT_ALL, "c")], device=True,
+                  mode=mode, gap=500, grace=0)
+    exd._KEY_CACHE_MAX = 64
+    st = assert_mirror_tracks_the_arena(exd, mirror_run(400))
+    assert st["grows"] >= 1 and st["remap_dispatches"] >= 2
+    assert st["close_cycles"] >= 10
+    assert st["mirror_full_merges"] == 1
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_a_batch_merges_the_rows_of_its_keys_not_the_open_sessions(mode):
+    """Cost by counter, not by clock: with 1 500 sessions open and a
+    dozen keys a batch, `mirror_rows_merged` grows with each batch by
+    the open sessions of the keys the batch names and by no more, and
+    no batch after the first takes the whole mirror."""
+    exd = make_ex([AggSpec(AggKind.COUNT_ALL, "c")], device=True,
+                  mode=mode, gap=1000, grace=600_000)
+    rng = np.random.default_rng(5)
+    ids = np.arange(1500)
+    exd.process([{"k": f"u{i}", "v": 1.0} for i in ids],
+                (BASE + rng.integers(0, 500, len(ids))).tolist())
+    st = exd.session_stats
+    assert st["mirror_rows_merged"] == 0  # the mirror was empty
+    assert st["mirror_full_merges"] == 1  # the batch behind activation
+    grown = []
+    for b in range(1, 9):
+        named = rng.choice(ids, 12, replace=False)
+        fresh = [f"n{b}_{j}" for j in range(3)]  # no open session yet
+        open_of = {c: int(n) for c, n in zip(*np.unique(
+            exd._dev["mir_code"], return_counts=True))}
+        expect = sum(open_of[exd._code_of[(f"u{int(i)}",)]]
+                     for i in named)
+        before = st["mirror_rows_merged"]
+        # 5 s on: in grace, more than a gap from every open session, so
+        # a named key holds one session more after each batch
+        exd.process([{"k": k, "v": 1.0}
+                     for k in [f"u{int(i)}" for i in named] + fresh],
+                    [BASE + b * 5000] * 15)
+        grown.append(st["mirror_rows_merged"] - before)
+        assert grown[-1] == expect >= 12
+    live = int(exd._dev["mir_live"].sum())
+    assert live == 1500 + 8 * 15 and exd.device_fallbacks == 0
+    assert st["mirror_full_merges"] == 1
+    assert max(grown) < live // 50 and sum(grown) > 8 * 12

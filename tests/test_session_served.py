@@ -173,6 +173,19 @@ def test_the_decode_columns_counters_show_in_admin_stats_queries(run):
     assert len(closed) <= g["code_cols_appended"] <= len(bidders)
 
 
+def test_the_mirror_counters_show_in_admin_stats_queries(run):
+    """`mirror_rows_merged` / `mirror_full_merges` (ISSUE 32) reach
+    `admin stats queries` as `session_<stat>`: one batch, the first
+    after activation, handed the chain merge every mirror row; the rest
+    handed it the open sessions of the bidders they named, a small part
+    of the sessions open."""
+    stats, g = run["stats"], run["gauges_before_closer"]
+    assert stats["session_mirror_full_merges"] \
+        == g["mirror_full_merges"] == 1
+    assert stats["session_mirror_rows_merged"] == g["mirror_rows_merged"]
+    assert 0 < g["mirror_rows_merged"] < N_FRAMES * g["live"] // 8
+
+
 def test_the_session_paths_stages_are_spans_inside_step(run):
     stages = run["stages"]
     batches = N_FRAMES + 1  # and the closer

@@ -11,7 +11,11 @@ mesh sizes (1 chip <-> 8-device mesh, re-shard on restore).
 
 import numpy as np
 import pytest
-from helpers import assert_code_columns_fresh
+from helpers import (
+    assert_code_columns_fresh,
+    assert_mirror_tracks_the_arena,
+    mirror_run,
+)
 
 from hstream_tpu.engine import ColumnType, Schema
 from hstream_tpu.engine.expr import Col
@@ -298,3 +302,20 @@ def test_sharded_decode_columns_keep_holes_and_live_keys(mesh, mode,
     assert_code_columns_fresh(exs)
     assert canon(to_rows(exs.peek()), names) \
         == canon(to_rows(exh.peek()), names)
+
+
+@pytest.mark.parametrize("mode", ["record", "segment"])
+def test_sharded_mirror_is_the_arena_after_every_step(mesh, mode):
+    """[1x8]: each shard's arena holds its residue class's mirror rows
+    in mirror order (`_shard_slots`), batch after batch, through closes,
+    a growth and compactions. A key-sharded compaction renumbers within
+    each class, so the mirror's global order is unknown until the step
+    behind it, which hands the chain merge every row (ISSUE 32):
+    `mirror_full_merges` counts those steps and the first batch."""
+    exs = SessionExecutor(node_of(500, 0, AGGS[:1]), SCHEMA, mesh=mesh)
+    exs.device_session_mode = mode
+    exs._KEY_CACHE_MAX = 64
+    st = assert_mirror_tracks_the_arena(exs, mirror_run(2600))
+    assert exs._dev.get("ssl") is not None
+    assert st["grows"] >= 1 and st["close_cycles"] >= 10
+    assert st["mirror_full_merges"] == 1 + st["remap_dispatches"] >= 3
