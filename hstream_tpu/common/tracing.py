@@ -236,6 +236,10 @@ TRACE_STAGES = frozenset({
     # waited, so a label that is absent means a renamed stage
     "read_wait", "state_wait", "ring_wait", "stage_wait",
     "close_fetch", "close_decode",
+    # a window-lattice query's full key table freeing the ids of dead
+    # group keys (engine/executor.py _retire_keys), on the task thread
+    # inside key_encode
+    "key_retire",
     # the task's helper threads: encode workers, store prefetch
     "encode", "store_read",
     # the device session path inside `step` (engine/session.py): the
@@ -264,6 +268,7 @@ TRACE_STAGES = frozenset({
 TRACE_PARENT = {
     "ring_wait": "step", "stage_wait": "step", "close": "step",
     "close_fetch": "close", "close_decode": "close",
+    "key_retire": "key_encode",
     "session_key_codes": "step", "session_mirror": "step",
     "session_pack": "step", "session_close": "step",
     "session_close_fetch": "session_close",

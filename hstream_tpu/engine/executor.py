@@ -53,7 +53,7 @@ from hstream_tpu.engine.expr import (
     eval_host_vec,
 )
 from hstream_tpu.engine.keytable import KeyTable
-from hstream_tpu.engine.plan import AggKind, AggregateNode, AggSpec
+from hstream_tpu.engine.plan import AggKind, AggregateNode, AggSpec, WindowTop
 from hstream_tpu.engine.types import (
     ColumnType,
     HostBatch,
@@ -69,6 +69,11 @@ log = get_logger("executor")
 REBASE_THRESHOLD = 1 << 30  # re-anchor epoch when relative time passes this
 
 EmitFn = Callable[[list[dict[str, Any]]], None]
+
+# `_key_last` of an id no key holds, and of one handed out by
+# `key_id_for` and not dated since (`note_key_use`): never retired
+_KEY_FREE = -(1 << 62)
+_KEY_PINNED = 1 << 62
 
 # Shared device->host change-drain workers: ONE small pool for every
 # executor in the process, so N concurrent queries batch their blocking
@@ -161,12 +166,36 @@ class QueryExecutor:
             if t == ColumnType.STRING
         }
 
+        # the key dictionary: `_key_ids` key -> id, `_key_rev` id -> key
+        # (None where the id is free), `_key_cols` the same per group
+        # column for the vectorized decode, `_key_last` the newest event
+        # time an id was named at (`note_key_use`; _KEY_PINNED until
+        # then, _KEY_FREE while no key holds it), `_free` the ids of
+        # retired keys, descending, so the smallest is taken first
         self._key_ids: dict[tuple, int] = {}
-        self._key_rev: list[tuple] = []
-        # what the two above said, kept for the task's key_encode stage
-        # to resolve a batch's dictionary in one call; derived, rebuilt
+        self._key_rev: list[tuple | None] = []
+        self._key_cols: list[np.ndarray] = [
+            np.empty(initial_keys, object) for _ in self.group_cols]
+        self._key_last = np.full(initial_keys, _KEY_FREE, np.int64)
+        self._free: list[int] = []
+        self._named_hi = -1  # newest event time any batch was dated at
+        self.key_stats = {"key_retirements": 0, "keys_retired": 0,
+                          "key_ids_reused": 0}
+        # what `_key_ids` said, kept for the task's key_encode stage to
+        # resolve a batch's dictionary in one call; derived, rebuilt
         # from _key_rev, never persisted
         self._key_table = KeyTable()
+        self._top: WindowTop | None = node.top
+        if self._top is not None:
+            if emit_changes:
+                raise SQLCodegenError(
+                    "QUALIFY ... OVER (a window's top across groups) is "
+                    "not supported with EMIT CHANGES: the extreme is "
+                    "known when the window closes")
+            if self.window is None:
+                raise SQLCodegenError(
+                    "QUALIFY ... OVER needs a TUMBLING or HOPPING "
+                    "window to take the extreme over")
 
         # Pre-encode string literals (fills the column dictionaries) so the
         # expressions are hashable and compiled functions can be shared.
@@ -227,19 +256,19 @@ class QueryExecutor:
         # ONE lattice-kernel dispatch and (outside changelog mode) ONE
         # device->host fetch per close cycle, regardless of how many
         # windows are due — tests and bench assert on these
+        # a top close adds what the device's own reduce said of each
+        # closed window (groups that held a count, rows that reached
+        # the extreme) and the times more rows tied than its buffer
+        # holds, each a second fetch of the full column
         self.close_stats = {"close_cycles": 0, "close_dispatches": 0,
-                            "close_fetches": 0}
+                            "close_fetches": 0, "close_rows_kept": 0,
+                            "close_groups": 0, "close_tie_refetches": 0}
         # fused-close health: a fused kernel failure (activation /
         # compile / injected fault) permanently degrades THIS executor
         # to the retained per-slot reference close; the query task
         # mirrors device_fallbacks into device_path_fallbacks
         self._fused_close_ok = True
         self.device_fallbacks = 0
-        # cached reverse key-index columns for vectorized key decode:
-        # (version = len(_key_rev) when built, [object array per group
-        # column]); _key_rev is append-only so a stale cache is only
-        # ever too short
-        self._key_cols_cache: tuple[int, list[np.ndarray]] = (0, [])
         # Deferred CHANGE decode (emit_changes mode): keep the touched
         # extract as a device value and decode it one batch later, so
         # the blocking device->host fetch overlaps the next batch's host
@@ -313,6 +342,7 @@ class QueryExecutor:
 
     def _compile(self) -> None:
         n_per = self.spec.windows_per_record
+        self._transport.reserve_key_ids(self.spec.n_keys)
         self._layout = tuple(
             (name, lattice.layout_tag(self.schema.type_of(name)))
             for name in self._needed_cols)
@@ -333,6 +363,10 @@ class QueryExecutor:
         self._extract_slots = fns.extract_slots  # peek: read path
         self._reset_slots = self._count_close_kernel(fns.reset_slots)
         self._extract_touched = fns.extract_touched
+        if self._top is not None:
+            self._extract_top_reset = self._count_close_kernel(
+                lattice.compiled_top_close(self.spec, self._top.agg,
+                                           self._top.extreme))
         # (null-flag stream name, referenced columns) per null-tracked agg
         self._null_specs = [
             (key, sorted(columns_of(agg.input)))
@@ -424,8 +458,17 @@ class QueryExecutor:
     def _key_id(self, row: Mapping[str, Any]) -> int:
         return self.key_id_for(tuple(row.get(c) for c in self.group_cols))
 
+    def _grow_key_arrays(self, new_k: int) -> None:
+        """The host's per-id arrays at the planes' new capacity."""
+        extra = new_k - len(self._key_last)
+        self._key_last = np.concatenate(
+            [self._key_last, np.full(extra, _KEY_FREE, np.int64)])
+        self._key_cols = [np.concatenate([c, np.empty(extra, object)])
+                          for c in self._key_cols]
+
     def _grow_keys(self) -> None:
         new_k = self.spec.n_keys * 2
+        self._grow_key_arrays(new_k)
         self.state = lattice.grow_keys(self.state, self.spec, new_k)
         self.spec = lattice.LatticeSpec(
             n_keys=new_k, window=self.spec.window, aggs=self.spec.aggs,
@@ -617,6 +660,7 @@ class QueryExecutor:
         key_ids = np.zeros(cap, dtype=np.int32)
         for i, row in enumerate(rows):
             key_ids[i] = self._key_id(row)
+        self.note_key_use(key_ids[:n], max(ts_ms))
 
         batch = HostBatch.from_rows(self.schema, rows, ts_ms, self.dicts,
                                     capacity=cap)
@@ -948,16 +992,181 @@ class QueryExecutor:
     def key_id_for(self, key: tuple) -> int:
         """Dense id for a group-key tuple (columnar-path key dictionary).
         Float key values are canonicalized through float32 so JSON and
-        columnar producers agree on group identity."""
+        columnar producers agree on group identity. The id is pinned
+        (never retired) until a `note_key_use` dates it."""
         key = canon_key(key)
         kid = self._key_ids.get(key)
         if kid is None:
-            kid = len(self._key_rev)
-            if kid >= self.spec.n_keys:
-                self._grow_keys()
-            self._key_ids[key] = kid
-            self._key_rev.append(key)
+            return int(self._assign_new([key])[0])
+        self._key_last[kid] = _KEY_PINNED
         return kid
+
+    def key_ids_for(self, keys: list[tuple], *,
+                    canonical: bool = False) -> np.ndarray:
+        """`key_id_for` over many keys at once: their ids in the order
+        given, the unknown ones registered in one piece and in that
+        order (so every key gets the id a per-key walk would give it).
+        `canonical`: the caller vouches that no value is a float."""
+        if not canonical:
+            keys = [canon_key(k) for k in keys]
+        kids = np.fromiter(
+            map(self._key_ids.get, keys, itertools.repeat(-1)),
+            np.int32, len(keys))
+        miss = np.flatnonzero(kids < 0)
+        # the known ones first: registering the others may set off a
+        # retirement, which must not take an id this call hands out
+        self.pin_keys(kids[kids >= 0])
+        if len(miss):
+            new = list(dict.fromkeys(keys[i] for i in miss.tolist()))
+            ids = self._assign_new(new)
+            if len(new) == len(miss):
+                kids[miss] = ids
+            else:  # two spellings of one canonical key in one call
+                at = dict(zip(new, ids.tolist()))
+                kids[miss] = [at[keys[i]] for i in miss.tolist()]
+        return kids
+
+    def _assign_new(self, keys: list[tuple]) -> np.ndarray:
+        """Ids for canonical keys the dictionary does not hold: the
+        free ids of retired keys first (smallest first), then the next
+        never used; room is made by `_make_room`. Pinned until dated."""
+        n = len(keys)
+        self._make_room(n)
+        rev = self._key_rev
+        kids = np.empty(n, np.int32)
+        take = min(n, len(self._free))
+        if take:
+            reused = self._free[:-take - 1:-1]
+            del self._free[-take:]
+            kids[:take] = reused
+            for kid, key in zip(reused, keys):
+                rev[kid] = key
+            self.key_stats["key_ids_reused"] += take
+        if take < n:
+            kids[take:] = np.arange(len(rev), len(rev) + n - take)
+            rev.extend(keys[take:])
+        self._key_ids.update(zip(keys, kids.tolist()))
+        self._key_last[kids] = _KEY_PINNED
+        for g, col in enumerate(self._key_cols):
+            col[kids] = [k[g] for k in keys]
+        return kids
+
+    # contract: dispatches<=0 fetches<=0
+    def pin_keys(self, kids: np.ndarray) -> None:
+        """Ids resolved outside `key_id_for` (the key table's hits)
+        that the batch in hand names: not to be retired before
+        `note_key_use` dates them."""
+        self._key_last[kids] = _KEY_PINNED
+
+    # contract: dispatches<=0 fetches<=0
+    def note_key_use(self, kids: np.ndarray, ts_hi: int) -> None:
+        """Date the ids a batch names (each at least once; more do no
+        harm; negative entries are no id and are skipped), once all of
+        them are resolved: no event of the batch is
+        later than `ts_hi` (absolute ms). The date only ever moves
+        forward, so batches out of order keep an id alive longer, never
+        shorter. Ids that are never dated (a caller that keeps ids
+        across batches) stay pinned. A plan that can never retire a key
+        (no window, EMIT CHANGES) keeps no dates."""
+        if self.window is None or self.emit_changes:
+            return
+        if ts_hi > self._named_hi:
+            self._named_hi = int(ts_hi)
+        if len(kids) and kids.min() < 0:
+            kids = kids[kids >= 0]
+        self._key_last[kids] = self._named_hi
+
+    def _free_ids(self) -> int:
+        return len(self._free) + self.spec.n_keys - len(self._key_rev)
+
+    def _make_room(self, need: int) -> None:
+        """Room for `need` more ids. Retire before growing: a full
+        table first frees the ids of dead keys, and the planes double
+        (a recompile) only where that left under a quarter of the
+        table free, so that a live set that fits stops growing it and
+        one that churns is not retired a few keys at a time."""
+        if self._free_ids() >= need:
+            return
+        self._retire_keys()
+        while self._free_ids() < max(need, self.spec.n_keys // 4):
+            self._grow_keys()
+
+    # contract: dispatches<=0 fetches<=0
+    def _retire_keys(self) -> int:
+        """Free the ids of dead group keys; returns how many.
+
+        A key is dead when every window it was named in is closed: the
+        newest window an id was dated in (`note_key_use`) is past
+        end + grace at the watermark, so the device drops as late
+        whatever a batch still in the pipeline holds for it, and is
+        older than every window still open, so its slot was reset at
+        its close and the id's row is zero in every plane: nothing is
+        remapped and no device program runs. Ids handed out by
+        `key_id_for` and not dated since stay pinned. Never with EMIT
+        CHANGES or a deferred close pending: their extracts name ids
+        that are decoded later. A windowless plan's keys never die."""
+        w = self.window
+        if (w is None or self.emit_changes or self._pending_closes
+                or self.watermark_abs < 0):
+            return 0
+        with trace_span(self.tracer, "key_retire"):
+            rev = self._key_rev
+            last = self._key_last[:len(rev)]
+            horizon = self.watermark_abs - w.size_ms - w.grace_ms
+            if self._open:
+                horizon = min(horizon, min(self._open) - w.advance_ms)
+            dead = np.flatnonzero(
+                (last - last % w.advance_ms <= horizon)
+                & (last > _KEY_FREE))
+            if len(dead) == 0:
+                return 0
+            self.key_stats["key_retirements"] += 1
+            ids = dead.tolist()
+            keys = [rev[kid] for kid in ids]
+            for kid in ids:
+                rev[kid] = None
+            deque(map(self._key_ids.__delitem__, keys), maxlen=0)
+            self._key_table.forget(keys)
+            self._key_last[dead] = _KEY_FREE
+            for col in self._key_cols:
+                col[dead] = None
+            self._free = np.union1d(
+                np.asarray(self._free, np.int64), dead)[::-1].tolist()
+            self.key_stats["keys_retired"] += len(ids)
+            return len(ids)
+
+    # contract: dispatches<=0 fetches<=0
+    def key_gauges(self) -> dict[str, int]:
+        """The key dictionary's and the top close's counts for `admin
+        stats queries` and /metrics: host ints."""
+        out = dict(self.key_stats)
+        out["keys_live"] = len(self._key_ids)
+        out["key_capacity"] = self.spec.n_keys
+        for k in ("close_rows_kept", "close_groups",
+                  "close_tie_refetches"):
+            out[k] = self.close_stats[k]
+        return out
+
+    def _load_keys(self, key_rev: list, last=None) -> None:
+        """Install a restored dictionary: `key_rev` with None where an
+        id is free, `last` its dates (None: every key pinned)."""
+        self._key_rev = list(key_rev)
+        self._key_ids = {k: i for i, k in enumerate(self._key_rev)
+                         if k is not None}
+        n = self.spec.n_keys
+        self._key_last = np.full(n, _KEY_FREE, np.int64)
+        self._key_cols = [np.empty(n, object) for _ in self.group_cols]
+        held = np.asarray([i for i, k in enumerate(self._key_rev)
+                           if k is not None], np.int64)
+        self._key_last[held] = _KEY_PINNED if last is None \
+            else np.asarray(last, np.int64)[held]
+        for g, col in enumerate(self._key_cols):
+            col[held] = [self._key_rev[i][g] for i in held.tolist()]
+        self._free = [i for i in range(len(self._key_rev) - 1, -1, -1)
+                      if self._key_rev[i] is None]
+        dated = self._key_last[held]
+        dated = dated[dated < _KEY_PINNED]
+        self._named_hi = int(dated.max()) if len(dated) else -1
 
     # ---- emission ----------------------------------------------------------
 
@@ -1030,8 +1239,9 @@ class QueryExecutor:
 
     def _drain_job(self, batch: list) -> list[dict[str, Any]]:
         """One async drain unit (drain-pool thread). Reads only
-        append-only / immutable executor state: _key_rev grows
-        monotonically, spec.aggs never changes (grow_keys swaps n_keys
+        append-only / immutable executor state: with EMIT CHANGES no
+        key is ever retired, so the key columns only gain entries,
+        spec.aggs never changes (grow_keys swaps n_keys
         only), and the packed buffers are immutable device values."""
         t0 = time.perf_counter()
         try:
@@ -1187,6 +1397,11 @@ class QueryExecutor:
                 # the changelog already carried final values: batched
                 # reset only, no extract and no fetch
                 self.state = self._reset_slots(self.state, slots)
+            elif self._top is not None:
+                # the window's extreme is taken on the device: `packed`
+                # holds the rows that reach it, `full` stays there
+                self.state, packed, full = self._extract_top_reset(
+                    self.state, slots)
             else:
                 self.state, packed = self._extract_reset_slots(
                     self.state, slots)
@@ -1201,8 +1416,9 @@ class QueryExecutor:
             return self._close_windows_ref(ows)
         if self.emit_changes:
             rows = []
-        elif self.defer_close_decode:
+        elif self.defer_close_decode and self._top is None:
             # keep the packed batch as a device value; no host sync
+            # (a top close is never deferred: what it fetches is small)
             self._pending_closes.append((list(starts), packed))
             rows = []
         else:
@@ -1225,10 +1441,50 @@ class QueryExecutor:
                 self.state = prev_state
                 return self._close_windows_ref(ows)
             with trace_span(self.tracer, "close_decode"):
-                rows = self._decode_extract_batch(packed_host, starts)
+                if self._top is not None:
+                    rows = self._decode_top_batch(packed_host, full,
+                                                  starts)
+                else:
+                    rows = self._decode_extract_batch(packed_host,
+                                                      starts)
         for s in starts:
             self._no_close.discard(s)
         return rows
+
+    # contract: dispatches<=0 fetches<=1
+    def _decode_top_batch(self, top: np.ndarray, full,
+                          starts: Sequence[int]
+                          ) -> "ColumnarEmit | list[dict[str, Any]]":
+        """Decode a top close's survivors `top` [P, 2+rows, R] (see
+        lattice.build_extract_top_reset_slots) into a ColumnarEmit.
+        Where more groups tie for a window's extreme than `top` holds,
+        the masked full column `full` (still on the device) is fetched
+        in their place, counted, so that a tie is never cut."""
+        stats = self.close_stats
+        widx, kids, outs = [], [], []
+        overflow = False
+        for p in range(len(starts)):
+            n_keep, n_groups, kid, out = lattice.unpack_top_rows(
+                self.spec, top[p])
+            stats["close_rows_kept"] += n_keep
+            stats["close_groups"] += n_groups
+            overflow |= n_keep > len(kid)
+            widx.append(np.full(len(kid), p, np.int64))
+            kids.append(kid.astype(np.int64))
+            outs.append(out)
+        if overflow:
+            stats["close_tie_refetches"] += 1
+            stats["close_fetches"] += 1
+            full_host = np.asarray(full)
+            self.transfer_stats["d2h_bytes"] += full_host.nbytes
+            return self._decode_extract_batch(full_host, starts)
+        widx = np.concatenate(widx)
+        if len(widx) == 0:
+            return []
+        return self._finish_extract(
+            widx, np.concatenate(kids),
+            {a.out_name: np.concatenate([o[a.out_name] for o in outs])
+             for a in self.spec.aggs}, starts)
 
     def _close_windows_ref(self, ows: list) -> list[dict[str, Any]]:
         """The retained per-slot reference close (the equivalence path
@@ -1246,7 +1502,17 @@ class QueryExecutor:
                     self.state, np.int32(slot)))
                 count, _sr, outs = lattice.unpack_extract_rows(
                     self.spec, packed)
-                for kid in np.nonzero(count > 0)[0]:
+                live = count > 0
+                if self._top is not None and live.any():
+                    # the filter across groups, on the host
+                    agg = next(a for a in self.spec.aggs
+                               if a.out_name == self._top.agg)
+                    vals = count if agg.kind == AggKind.COUNT_ALL \
+                        else outs[agg.out_name]
+                    best = vals[live].max() if self._top.extreme == "max" \
+                        else vals[live].min()
+                    live &= vals == best
+                for kid in np.nonzero(live)[0]:
                     row = self._agg_row(int(kid), outs, int(kid), s)
                     if row is not None:
                         rows.append(row)
@@ -1311,17 +1577,9 @@ class QueryExecutor:
     def _key_rev_columns(self) -> list[np.ndarray]:
         """Per-group-column object arrays over the key dictionary, for
         vectorized key decode (one gather per column instead of one
-        _decode_key dict per row). Rebuilt only when keys were added."""
-        version = len(self._key_rev)
-        if self._key_cols_cache[0] != version:
-            cols = []
-            for g in range(len(self.group_cols)):
-                arr = np.empty(version, object)
-                for i, key in enumerate(self._key_rev):
-                    arr[i] = key[g]
-                cols.append(arr)
-            self._key_cols_cache = (version, cols)
-        return self._key_cols_cache[1]
+        _decode_key dict per row): kept as ids are assigned and
+        retired, never rebuilt."""
+        return self._key_cols
 
     def _decode_extract_batch(self, packed: np.ndarray,
                               starts: Sequence[int | None]
@@ -1335,10 +1593,22 @@ class QueryExecutor:
         widx, kids = np.nonzero(count > 0)
         if len(widx) == 0:
             return []
+        return self._finish_extract(
+            widx, kids,
+            lattice.gather_extract_batch(self.spec, packed, widx, kids),
+            starts)
+
+    def _finish_extract(self, widx: np.ndarray, kids: np.ndarray,
+                        outs: Mapping[str, np.ndarray],
+                        starts: Sequence[int | None]
+                        ) -> "ColumnarEmit | list[dict[str, Any]]":
+        """The extracted (window, key) pairs as emitted columns: keys
+        decoded through the reverse index, aggregates finalized to
+        their emitted types, the window's bounds, then HAVING and the
+        projections."""
         cols: dict[str, Any] = {}
         for name, arr in zip(self.group_cols, self._key_rev_columns()):
             cols[name] = arr[kids]
-        outs = lattice.gather_extract_batch(self.spec, packed, widx, kids)
         for agg in self.spec.aggs:
             v = outs[agg.out_name]
             if agg.kind in (AggKind.TOPK, AggKind.TOPK_DISTINCT):
@@ -1351,7 +1621,7 @@ class QueryExecutor:
                               AggKind.APPROX_COUNT_DISTINCT):
                 cols[agg.out_name] = np.rint(v).astype(np.int64)
             else:
-                cols[agg.out_name] = v
+                cols[agg.out_name] = v.astype(np.float64)
         if self.window is not None and starts and starts[0] is not None:
             ws = np.asarray(starts, np.int64)[widx]
             cols["winStart"] = ws
@@ -1429,7 +1699,13 @@ class QueryExecutor:
         the view store that owns this executor. ONE batched extract
         dispatch + ONE fetch covers every open window. The whole of it
         is the `peek` family's span on the caller's (a pull's) thread:
-        `kernel_dispatch_ms{peek}` is dispatch + D2H sync + decode."""
+        `kernel_dispatch_ms{peek}` is dispatch + D2H sync + decode.
+
+        A plan with QUALIFY ... OVER has no live rows: a window's
+        extreme is known when it closes, so an open window gives a pull
+        nothing, and the view's closed rows are the whole answer."""
+        if self._top is not None:
+            return []
         if self.window is None:
             starts, slots = [None], [0]
         else:

@@ -2,11 +2,16 @@
 `key_encode` stage, server/tasks.py `_columnar_key_ids`).
 
 Derived state: the executor's `_key_ids` / `_key_rev` stay the truth and
-assign every id (`key_id_for`); this table only remembers what they said,
-and is rebuilt from `_key_rev` whenever it is out of step with it (an
-executor restored from a snapshot, keys registered on another path), so
-nothing of it is persisted. It is bounded by the key space itself: only
-canonical values, the ones `_key_rev` holds, are kept.
+assign every id (`key_id_for`, `key_ids_for`); this table only remembers
+what they said, and is rebuilt from `_key_rev` whenever it is out of step
+with it (an executor restored from a snapshot, keys registered on another
+path), so nothing of it is persisted. It is bounded by the live key set:
+only canonical values, the ones `_key_rev` holds, are kept, and a key the
+executor retires (`QueryExecutor._retire_keys`: every window it was named
+in has closed, and its id goes to the next new key) is forgotten in the
+same breath (`forget`), so the table never answers with an id that has
+passed to another key. An id the table answers with is the caller's to
+protect until its batch is dated: `pin_keys`, then `note_key_use`.
 
 Two forms behind one interface. With the native library
 (cpp/encode.cpp `kt_*`) strings live there and a batch's whole
@@ -58,6 +63,8 @@ class KeyTable:
         self.get = self._memo.get
         # len(_key_rev) this table was last in step with
         self.covered = 0
+        # the native form holds keys the executor has retired since
+        self._stale = False
         self.lookups = 0
         self.misses = 0
 
@@ -70,24 +77,43 @@ class KeyTable:
         n = len(self._memo)
         return n if self._h is None else n + self._lib.kt_size(self._h)
 
-    def sync(self, key_rev: list[tuple]) -> None:
-        """Rebuild from the executor's `_key_rev` if out of step."""
-        if self.covered == len(key_rev):
+    def sync(self, key_rev: list) -> None:
+        """Rebuild from the executor's `_key_rev` (None where an id is
+        free) if out of step."""
+        if self.covered == len(key_rev) and not self._stale:
             return
+        self._stale = False
         self._memo.clear()
         if self._h is not None:
             self._lib.kt_free(self._h)
             self._h = self._lib.kt_new()
-        vals = [k[0] for k in key_rev]
-        if self._h is None:
-            self._memo.update(zip(vals, range(len(vals))))
-        else:
-            native = [type(v) is str and "\0" not in v for v in vals]
-            kids = np.flatnonzero(native).astype(np.int32)
-            self._insert([vals[i] for i in kids.tolist()], kids)
-            self._memo.update((v, i) for i, v in enumerate(vals)
-                              if not native[i])
+        native: list[tuple] = []   # (string, id): the library's
+        other: list[tuple] = []    # (value, id): the dict's
+        for i, k in enumerate(key_rev):
+            if k is None:
+                continue
+            v = k[0]
+            if self._h is not None and type(v) is str and "\0" not in v:
+                native.append((v, i))
+            else:
+                other.append((v, i))
+        self._memo.update(other)
+        if native:
+            self._insert([v for v, _i in native],
+                         np.asarray([i for _v, i in native], np.int32))
         self.covered = len(key_rev)
+
+    def forget(self, keys: list[tuple]) -> None:
+        """Keys the executor has retired: their ids pass to other
+        keys, so no lookup may answer with them. The native form has no
+        erase: it is rebuilt from `_key_rev` at the next `sync`."""
+        pop = self._memo.pop
+        elsewhere = False   # a key the dict did not hold
+        for k in keys:
+            if pop(k[0], None) is None:
+                elsewhere = True
+        if elsewhere and self._h is not None:
+            self._stale = True
 
     def resolve(self, d: list) -> "np.ndarray | None":
         """Key ids of a whole string dictionary, -1 where the table has
@@ -116,12 +142,28 @@ class KeyTable:
     def register_strings(self, ex, strs: list[str]) -> list[int]:
         """The misses of `resolve`, in the order given: each gets its id
         from the executor and is remembered."""
-        kids = [ex.key_id_for((s,)) for s in strs]
+        kids = ex.key_ids_for([(s,) for s in strs], canonical=True)
         self.misses += len(strs)
         if self._h is None:
-            self._memo.update(zip(strs, kids))
+            self._memo.update(zip(strs, kids.tolist()))
         else:
-            self._insert(strs, np.asarray(kids, np.int32))
+            self._insert(strs, kids)
+        self.covered = len(ex._key_rev)
+        return kids.tolist()
+
+    def register_values(self, ex, vals: list, *,
+                        canonical: bool) -> np.ndarray:
+        """The values `get` did not know, in the order given, in one
+        registration; each is remembered if it is the canonical value
+        itself (`canonical`: none is a float, so all are)."""
+        kids = ex.key_ids_for([(v,) for v in vals], canonical=canonical)
+        self.misses += len(vals)
+        if canonical:
+            self._memo.update(zip(vals, kids.tolist()))
+        else:
+            rev = ex._key_rev
+            self._memo.update((v, k) for v, k in zip(vals, kids.tolist())
+                              if rev[k][0] == v)
         self.covered = len(ex._key_rev)
         return kids
 

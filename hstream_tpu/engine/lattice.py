@@ -61,6 +61,8 @@ EMPTY_START = -(1 << 31)  # slot_start sentinel for "slot unoccupied"
 # together, and say so to whoever reads traces.
 STEP_PROGRAM = "jit_step"                    # build_step_encoded / _packed
 CLOSE_PROGRAM = "jit_extract_and_reset"      # build_extract_reset_slots
+# the close of a plan that keeps a window's extreme groups (plan.WindowTop)
+TOP_CLOSE_PROGRAM = "jit_extract_top_and_reset"  # build_extract_top_reset_slots
 PEEK_PROGRAM = "jit_peek_slots"              # build_extract_slots: it alone
 # the device session lattice's three (record mode)
 SESSION_STEP_PROGRAM = "jit_session_step"        # session_step_kernel
@@ -665,6 +667,146 @@ def build_extract_reset_slots(spec: LatticeSpec):
         return _reset_slots_tree(spec, state, rs), packed
 
     return extract_and_reset
+
+
+# ---- the top across groups at a close ---------------------------------------
+#
+# A plan with a `WindowTop` (SQL: `QUALIFY agg >= MAX(agg) OVER
+# (PARTITION BY winStart, winEnd)`) keeps, of every closing window, the
+# groups whose aggregate is the window's extreme. The extreme is taken
+# over the key axis of the closing slot ON THE DEVICE, inside the fused
+# close, so the host fetches and decodes the rows that survive (a few)
+# and not every key's row (`packed i32[P, 2+rows, K]`).
+
+TOP_ROWS = 256    # surviving rows a top close ships per window
+_TOP_BLOCK = 256  # keys a block: survivors are looked for block by block
+_TOP_BLOCKS = 64  # blocks with a survivor that are looked into
+
+
+def top_rows(n_keys: int) -> int:
+    """Width of the survivors' buffer: the header needs four cells."""
+    return max(4, min(n_keys, TOP_ROWS))
+
+
+def _first_true(mask, n_keys: int, width: int):
+    """(the first `width` positions where `mask` [K] is True, ascending
+    and zero-filled; how many of them are real). In two levels, so that
+    no prefix sum runs over the whole key axis (at 2^20 keys one takes
+    the chip's compiler a quarter of a minute, and a table that doubles
+    its way there compiles it at every size): the blocks of `_TOP_BLOCK`
+    keys that hold a True, the first `_TOP_BLOCKS` of them, and the
+    positions inside those. What lies past either bound is not shown,
+    which the count says."""
+    block = _TOP_BLOCK if n_keys % _TOP_BLOCK == 0 else n_keys
+    n_blocks = n_keys // block
+    look = min(n_blocks, _TOP_BLOCKS)
+    blocks = mask.reshape(n_blocks, block)
+    holds = jnp.any(blocks, axis=1)
+    chosen = jnp.nonzero(holds, size=look, fill_value=0)[0]
+    real = jnp.arange(look) < jnp.sum(holds.astype(jnp.int32))
+    sub = jnp.where(real[:, None], blocks[chosen], False).reshape(-1)
+    at = jnp.nonzero(sub, size=width, fill_value=0)[0]
+    shown = jnp.minimum(jnp.sum(sub.astype(jnp.int32)), width)
+    pos = chosen[at // block] * block + at % block
+    return jnp.where(jnp.arange(width) < shown, pos, 0), shown
+
+
+def _top_values(spec: LatticeSpec, agg: AggSpec, col, outs):
+    """The column the extreme is taken over: a count plane as the int32
+    it is (exact past 2^24), every other aggregate as finalized."""
+    if agg.kind == AggKind.COUNT_ALL:
+        return col["count"]
+    if agg.kind == AggKind.COUNT:
+        return col[_plane_name(spec.aggs.index(agg), agg)]
+    return outs[agg.out_name]
+
+
+def build_extract_top_reset_slots(spec: LatticeSpec, top_agg: str,
+                                  extreme: str):
+    """extract_top_and_reset(state, slots i32[P]) ->
+    (state', top i32[P, 2+rows, R], full i32[P, 2+rows, K]).
+
+    The fused close of `build_extract_reset_slots`, with the window's
+    extreme of aggregate `top_agg` ("max" | "min") taken over the groups
+    that hold a count in the closing slot. `top` is what the host
+    fetches: row 0 the header (cell 0 the groups that reach the extreme,
+    cell 1 the groups that held a count, cell 2 the window's start,
+    cell 3 how many of the first are shown), row 1 the key ids of those
+    shown, rows 2+ their finalized aggregates in `_agg_out_rows` order
+    (`_first_true` says which are shown: at most `top_rows(K)`). `full`
+    is the whole packed column with the count of every other group
+    zeroed: it stays on the device unless more groups tie than `top`
+    shows (the host then fetches it, and counts that it did), so a tie
+    is never cut. Padding entries (slot < 0) give zeros and reset
+    nothing."""
+    agg = next(a for a in spec.aggs if a.out_name == top_agg)
+    if agg.kind in _TOPK_KINDS:
+        raise ValueError(f"{agg.kind.value} has no single value a "
+                         "group to take an extreme of")
+    if extreme not in ("max", "min"):
+        raise ValueError(f"extreme {extreme!r}")
+    width = top_rows(spec.n_keys)
+
+    def one(state, slot):
+        col = {k: v[:, slot] for k, v in state.items()
+               if k not in ("slot_start", "touched")}
+        with jax.named_scope("finalize"):
+            outs = finalize_column(spec, col)
+        with jax.named_scope("top"):
+            live = col["count"] > 0
+            vals = _top_values(spec, agg, col, outs)
+            if jnp.issubdtype(vals.dtype, jnp.floating):
+                worst = -jnp.inf if extreme == "max" else jnp.inf
+            else:
+                info = jnp.iinfo(vals.dtype)
+                worst = info.min if extreme == "max" else info.max
+            masked = jnp.where(live, vals, worst)
+            best = jnp.max(masked) if extreme == "max" \
+                else jnp.min(masked)
+            keep = live & (vals == best)
+            n_keep = jnp.sum(keep.astype(jnp.int32))
+            n_groups = jnp.sum(live.astype(jnp.int32))
+            kids, shown = _first_true(keep, spec.n_keys, width)
+            ok = jnp.arange(width) < shown
+            win_start = state["slot_start"][slot]
+            header = jnp.zeros((width,), jnp.int32).at[0].set(
+                n_keep).at[1].set(n_groups).at[2].set(win_start).at[
+                3].set(shown)
+            rows = [header, kids.astype(jnp.int32)]
+            rows.extend(jnp.where(ok, r[kids], 0)
+                        for r in _agg_out_rows(spec, outs))
+        full = pack_extract_rows(
+            spec, jnp.where(keep, col["count"], 0), win_start, outs)
+        return jnp.stack(rows), full
+
+    @jax.jit
+    def extract_top_and_reset(state, slots):  # TOP_CLOSE_PROGRAM
+        valid = slots >= 0
+        top, full = jax.vmap(lambda s: one(state, s))(
+            jnp.where(valid, slots, 0))
+        top = jnp.where(valid[:, None, None], top, 0)
+        full = jnp.where(valid[:, None, None], full, 0)
+        rs = jnp.where(valid, slots, spec.n_slots)  # OOB -> drop
+        return _reset_slots_tree(spec, state, rs), top, full
+
+    return extract_top_and_reset
+
+
+@functools.lru_cache(maxsize=512)
+def compiled_top_close(spec: LatticeSpec, top_agg: str,
+                       extreme: str) -> Callable:
+    """Shared, cached top close of one (spec, aggregate, extreme)."""
+    return build_extract_top_reset_slots(spec, top_agg, extreme)
+
+
+def unpack_top_rows(spec: LatticeSpec, top: np.ndarray):
+    """One window's survivors from `top[p]`: (groups that reach the
+    extreme, groups that held a count, key ids [n], {name: [n] or
+    [n, width] f32}), n the survivors shown: fewer than the first
+    number where more tie than the buffer shows."""
+    n_keep, n_groups, n = int(top[0, 0]), int(top[0, 1]), int(top[0, 3])
+    return n_keep, n_groups, top[1, :n], _unpack_agg_rows(
+        spec, top[2:, :n])
 
 
 def build_extract_slots(spec: LatticeSpec):

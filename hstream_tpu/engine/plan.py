@@ -39,6 +39,17 @@ class AggSpec:
     k: int | None = None           # for TOPK
 
 
+@dataclass(frozen=True)
+class WindowTop:
+    """Keep, of every closed window, the groups whose aggregate `agg`
+    (an `AggSpec.out_name` of the node) equals the window's extreme of
+    it over all groups: SQL's `QUALIFY agg >= MAX(agg) OVER (PARTITION
+    BY winStart, winEnd)`. Ties all stay; a window without a group emits
+    nothing."""
+    agg: str
+    extreme: str                   # "max" | "min"
+
+
 @dataclass
 class PlanNode:
     pass
@@ -73,6 +84,8 @@ class AggregateNode(PlanNode):
     having: Expr | None = None
     # host-side projections over aggregate outputs, e.g. SUM(x)/2 AS y
     post_projections: list[tuple[str, Expr]] = field(default_factory=list)
+    # the filter across groups at a window's close (None: every group)
+    top: WindowTop | None = None
 
 
 @dataclass
@@ -100,3 +113,18 @@ def plan_source(node: PlanNode) -> SourceNode:
         else:
             raise ValueError(f"no single source under {type(node).__name__}")
     return node
+
+
+def single_chip_reason(node: AggregateNode) -> str | None:
+    """Why an aggregate cannot execute over the device mesh (None: it
+    shards). One predicate for EXPLAIN, the task's gate, the executor
+    factory and a snapshot's restore."""
+    if any(a.kind in (AggKind.TOPK, AggKind.TOPK_DISTINCT)
+           for a in node.aggs):
+        return ("TOPK/TOPK_DISTINCT planes have no elementwise shard "
+                "merge; the query runs single-chip")
+    if node.top is not None:
+        return ("a window's top across groups (QUALIFY ... OVER) is "
+                "taken over the whole key axis on one chip; the query "
+                "runs single-chip")
+    return None

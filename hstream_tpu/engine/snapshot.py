@@ -229,6 +229,7 @@ def _lattice_state(ex) -> tuple[dict, dict[str, np.ndarray]]:
         raise SQLCodegenError(
             "snapshot with deferred changes pending; flush_changes() "
             "first")
+    key_rev, key_arrays = _keys_state(ex)
     meta = {
         "kind": "lattice",
         "n_keys": ex.spec.n_keys,
@@ -237,22 +238,74 @@ def _lattice_state(ex) -> tuple[dict, dict[str, np.ndarray]]:
         "watermark_abs": ex.watermark_abs,
         "emit_changes": ex.emit_changes,
         "open": [[s, ow.slot] for s, ow in sorted(ex._open.items())],
-        "key_rev": [_enc(k) for k in ex._key_rev],
+        "key_rev": key_rev,
         "dicts": {name: d._values for name, d in ex.dicts.items()},
         "null_sticky": sorted(ex._null_sticky),
         "schema": [[n, t.value] for n, t in ex.schema.fields],
     }
-    # by reference: jax arrays are immutable; np.asarray (the device sync)
-    # happens in serialize_capture, outside the caller's lock.
-    # Sharded executors (leading data axis on every plane) canonicalize:
-    # merge the partial lattices with each plane's monoid op so the blob
-    # is mesh-portable (restorable single-chip or onto any mesh).
-    if hasattr(ex, "_sharded"):
-        arrays = {f"s/{k}": v
-                  for k, v in _merge_partials(ex).items()}
-    else:
-        arrays = {f"s/{k}": v for k, v in ex.state.items()}
+    arrays = lattice_planes(ex)
+    # the newest event time each id was named at: what decides when a
+    # key may be retired (a copy: the executor writes it in place)
+    arrays["k/last"] = ex._key_last[:len(ex._key_rev)].copy()
+    arrays.update(key_arrays)
     return meta, arrays
+
+
+def lattice_planes(ex) -> dict[str, Any]:
+    """The planes of a lattice executor as a snapshot holds them, by
+    reference: jax arrays are immutable; np.asarray (the device sync)
+    happens in serialize_capture, outside the caller's lock.
+    Sharded executors (leading data axis on every plane) canonicalize:
+    merge the partial lattices with each plane's monoid op so the blob
+    is mesh-portable (restorable single-chip or onto any mesh)."""
+    if hasattr(ex, "_sharded"):
+        return {f"s/{k}": v for k, v in _merge_partials(ex).items()}
+    return {f"s/{k}": v for k, v in ex.state.items()}
+
+
+def _keys_state(ex) -> tuple[list | None, dict[str, np.ndarray]]:
+    """The key dictionary for a snapshot: (`key_rev` for the meta, with
+    None where an id is free (its key retired), no arrays), so that a
+    restore hands the same ids to the same keys and the free ones out
+    again in the same order. Where the schema types every group column
+    a number and the values are integers (int64 is what numpy infers
+    from them: no null, no fraction, none past int64), the dictionary
+    goes as arrays instead (None, {`k/held`: which ids hold a key,
+    `k/c<g>`: their values of group column g}): NEXmark's auction ids
+    are a million entries, and a million JSON objects encoded under the
+    executor's lock stop the query for seconds. Every other dictionary
+    (string keys, bools) goes as JSON, its values unread."""
+    from hstream_tpu.engine.executor import _KEY_FREE
+
+    types = dict(ex.schema.fields)
+    if ex.group_cols and all(
+            types.get(c) in (ColumnType.FLOAT, ColumnType.INT)
+            for c in ex.group_cols):
+        n = len(ex._key_rev)
+        held = ex._key_last[:n] > _KEY_FREE
+        cols = [np.array(c[:n][held].tolist()) for c in ex._key_cols]
+        if all(c.dtype == np.int64 for c in cols):
+            out = {f"k/c{g}": c for g, c in enumerate(cols)}
+            out["k/held"] = held
+            return None, out
+    return [_enc(k) for k in ex._key_rev], {}
+
+
+def _keys_from(meta: dict, arrays: dict) -> list:
+    """`key_rev` as `_keys_state` left it, in either form."""
+    if meta["key_rev"] is not None:
+        return [None if k is None else tuple(_dec(k))
+                for k in meta["key_rev"]]
+    held = np.asarray(arrays["k/held"], np.bool_)
+    cols = []
+    g = 0
+    while f"k/c{g}" in arrays:
+        cols.append(np.asarray(arrays[f"k/c{g}"]).tolist())
+        g += 1
+    rev: list = [None] * len(held)
+    for i, key in zip(np.flatnonzero(held).tolist(), zip(*cols)):
+        rev[i] = key
+    return rev
 
 
 def _merge_partials(ex) -> dict[str, Any]:
@@ -294,11 +347,10 @@ def _restore_lattice(node, meta, arrays, *, batch_capacity: int = 4096,
     schema = Schema(tuple((n, ColumnType(t)) for n, t in meta["schema"]))
     cap = meta.get("batch_capacity", batch_capacity)
     if mesh is not None:
-        from hstream_tpu.engine.plan import AggKind
+        from hstream_tpu.engine.plan import single_chip_reason
 
-        if any(a.kind in (AggKind.TOPK, AggKind.TOPK_DISTINCT)
-               for a in node.aggs):
-            mesh = None  # no elementwise shard merge for TOPK planes
+        if single_chip_reason(node) is not None:
+            mesh = None  # as make_executor decides for a fresh one
     if mesh is not None:
         from hstream_tpu.parallel import ShardedQueryExecutor
 
@@ -319,8 +371,9 @@ def _restore_lattice(node, meta, arrays, *, batch_capacity: int = 4096,
         for v in values:
             d.encode(v)
         ex.dicts[name] = d
-    ex._key_rev = [tuple(_dec(k)) for k in meta["key_rev"]]
-    ex._key_ids = {k: i for i, k in enumerate(ex._key_rev)}
+    # a snapshot from before ids had dates has no "k/last": its keys
+    # stay pinned (never retired), as they were
+    ex._load_keys(_keys_from(meta, arrays), arrays.get("k/last"))
     ex.epoch = meta["epoch"]
     ex.watermark_abs = meta["watermark_abs"]
     ex._open = {s: _OpenWindow(start_abs=s, slot=slot)

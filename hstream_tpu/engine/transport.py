@@ -271,6 +271,15 @@ class BitpackTransport:
         self._bits[name] = bits
         return bits
 
+    def reserve_key_ids(self, n_keys: int) -> None:
+        """Key ids lie in [0, n_keys): take the width that holds any
+        span of them now. The width of `__kid` is then a function of the
+        key table's capacity alone, and not of which ids a batch happens
+        to name: with retirement a batch's new keys take freed ids from
+        anywhere in the table, and a span that first passed a ladder
+        rung in the middle of a run would recompile the step there."""
+        self._widen("__kid", _bits_for(max(n_keys - 1, 0)))
+
     def _plan_uint(self, name: str, vals: np.ndarray
                    ) -> tuple[StreamPlan, int, np.ndarray]:
         """(plan, base, payload) for an integer stream. The payload is

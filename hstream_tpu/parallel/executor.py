@@ -84,6 +84,7 @@ class ShardedQueryExecutor(QueryExecutor):
         import jax
 
         new_k = self.spec.n_keys * 2
+        self._grow_key_arrays(new_k)
         kinds = se_lattice.plane_merge_kinds(self.spec)
         extra = new_k - self.spec.n_keys
         # key growth re-shards through the host: one fetch per plane is

@@ -106,6 +106,9 @@ def pack_signature(plan):
                            f"unpackable window {type(w).__name__}")
     if node.having is not None:
         return PackRefusal("having", "HAVING predicates are per-query")
+    if node.top is not None:
+        return PackRefusal("qualify",
+                           "a window's top across groups is per-query")
     for g in node.group_keys:
         if not isinstance(g, Col):
             return PackRefusal("computed-key",

@@ -328,8 +328,17 @@ def sample_health(ctx) -> None:
         if shards > 1:
             stats.gauge_set("mesh_shards", qid, shards)
             live.add(("mesh_shards", qid))
+        # the window lattice's key dictionary (ISSUE 33): absent for
+        # an engine that has none
+        keys = task.engine_gauges()
+        if "keys_live" in keys:
+            stats.gauge_set("keys_live", qid, keys["keys_live"])
+            stats.gauge_set("key_capacity", qid, keys["key_capacity"])
+            live.add(("keys_live", qid))
+            live.add(("key_capacity", qid))
     for metric in ("query_watermark_ms", "query_watermark_lag_ms",
-                   "query_health_level", "mesh_shards"):
+                   "query_health_level", "mesh_shards", "keys_live",
+                   "key_capacity"):
         for label in stats.gauge_labels(metric):
             if (metric, label) not in live:
                 stats.gauge_drop(metric, label)

@@ -21,6 +21,7 @@ after the last snapshot.
 
 from __future__ import annotations
 
+import itertools
 import json
 import queue
 import threading
@@ -43,6 +44,7 @@ from hstream_tpu.common.tracing import (
 from hstream_tpu.engine.pipeline import IngestPipeline
 from hstream_tpu.engine.snapshot import (
     capture_executor,
+    lattice_planes,
     open_blob,
     restore_executor,
     seal_blob,
@@ -166,6 +168,7 @@ class QueryTask(threading.Thread):
         self._last_snapshot_ms = 0.0
         self._last_persist_ms = 0.0   # cost of the last state write
         self._last_inline_ms = 0.0    # capture-side stall of last snap
+        self._pin_keys_built = 0      # key capacity `_pin` is built for
         # condition over a traced re-entrant lock: waits release the
         # lock through the wrapper, so the held-set stays truthful
         self._persist_cv = threading.Condition(
@@ -188,6 +191,9 @@ class QueryTask(threading.Thread):
         # engine-counter mirrors (ISSUE 13): late drops + H2D/D2H
         # bytes, delta-based like the fallback mirror
         self._late_seen = 0
+        # the key dictionary's and the top close's counts (ISSUE 33),
+        # mirrored the same way: what `key_gauges` last said
+        self._key_counts_seen: dict[str, int] = {}
         self._h2d_seen = 0
         self._d2h_seen = 0
         # multi-chip plane (ISSUE 16): shard_map dispatch mirror (a
@@ -247,7 +253,8 @@ class QueryTask(threading.Thread):
         that had to go through `key_id_for`: after warm-up the second
         stands still unless new keys arrive."""
         with trace_span(self.tracer, "key_encode"):
-            key_ids = _columnar_key_ids(ex, cols, len(ts), nulls=nulls)
+            key_ids = _columnar_key_ids(ex, cols, len(ts), nulls=nulls,
+                                        ts_hi=int(ts.max()))
             dev_cols, dnulls = _device_columns(ex, cols, len(ts),
                                                nulls=nulls)
         stats = getattr(self.ctx, "stats", None)
@@ -382,17 +389,22 @@ class QueryTask(threading.Thread):
         """The executor's own counts for `admin stats queries`, beside
         `consumed_events`: for a session executor its `session_stats`,
         the open sessions and the arena's capacity, each named
-        `session_<what>`. Host ints, no dispatch; {} for an executor
-        that states none."""
+        `session_<what>`; for a window-lattice executor its key
+        dictionary (`keys_live`, `key_capacity`, retirements) and what
+        its top closes kept (`QueryExecutor.key_gauges`). Host ints, no
+        dispatch; {} for an executor that states none."""
         with self.state_lock:  # executor is guarded (hstream-analyze)
             ex = self.executor
-        fn = getattr(ex, "session_gauges", None)
-        if fn is None:
-            return {}
         try:
-            return {f"session_{k}": int(v) for k, v in fn().items()}
+            fn = getattr(ex, "session_gauges", None)
+            if fn is not None:
+                return {f"session_{k}": int(v) for k, v in fn().items()}
+            fn = getattr(ex, "key_gauges", None)
+            if fn is not None:
+                return {k: int(v) for k, v in fn().items()}
         except Exception:  # noqa: BLE001 — a half-built executor must
-            return {}      # not kill the stats sweep
+            pass           # not kill the stats sweep
+        return {}
 
     def mesh_shards(self) -> int:
         """Key-axis size of the running executor's mesh, 0 when the
@@ -741,6 +753,9 @@ class QueryTask(threading.Thread):
                                       self.plan.source,
                                       d2h - self._d2h_seen)
                 self._d2h_seen = d2h
+            kg = getattr(ex, "key_gauges", None)
+            if kg is not None:
+                self._mirror_key_counts(stats, kg())
             # shard_map dispatches (ISSUE 16): read the executor attr
             # directly — JoinExecutor.sharded_dispatches is a property
             # that already folds its inner aggregate, so engine_total
@@ -753,6 +768,18 @@ class QueryTask(threading.Thread):
                 self._sharded_seen = sd
         except Exception:  # noqa: BLE001 — metrics must not kill
             pass           # the ingest loop
+
+    def _mirror_key_counts(self, stats, now: dict) -> None:
+        """Retirements and top closes since the last chunk, into the
+        query-labelled counters of /metrics."""
+        seen = self._key_counts_seen
+        for name in ("key_retirements", "keys_retired", "key_ids_reused",
+                     "close_rows_kept", "close_groups",
+                     "close_tie_refetches"):
+            delta = now[name] - seen.get(name, 0)
+            if delta > 0:
+                stats.stream_stat_add(name, self.info.query_id, delta)
+        self._key_counts_seen = now
 
     # ---- operator-state checkpointing --------------------------------------
 
@@ -873,6 +900,7 @@ class QueryTask(threading.Thread):
     def _maybe_snapshot(self) -> None:
         if not self._dirty:
             return
+        self._build_pin()
         now = time.monotonic() * 1000
         # cadence scales with the measured cost of a snapshot — both
         # the inline stall (pipeline barrier + capture + sink flush)
@@ -894,6 +922,20 @@ class QueryTask(threading.Thread):
             t0 = time.monotonic()
             self._snapshot_now()
             self._last_inline_ms = (time.monotonic() - t0) * 1000
+
+    def _build_pin(self) -> None:
+        """`_pin` is one device program a plane shape. Where the key
+        table has grown since it last ran, run it now, beside the step
+        and the close the growth rebuilt, and not at whichever snapshot
+        comes next: once the table has the size the live set needs, a
+        snapshot builds nothing."""
+        with self.state_lock:
+            ex = self.executor
+            if getattr(ex, "key_gauges", None) is None:
+                return  # no window lattice: no key table that doubles
+            if ex.spec.n_keys != self._pin_keys_built:
+                self._pin_keys_built = ex.spec.n_keys
+                _pin(lattice_planes(ex))
 
     def _snapshot_now(self, *, sync: bool = False) -> None:
         # pipeline barrier FIRST: _pending_ckps covers every submitted
@@ -934,16 +976,7 @@ class QueryTask(threading.Thread):
             if self.sink_dump is not None:
                 extra["sink"] = self.sink_dump()
             meta, arrays = capture_executor(self.executor, extra)
-            # break aliasing with the step's donated buffers: the async
-            # persist serializes AFTER later steps have donated (and so
-            # deleted) the captured arrays — a cheap on-device copy,
-            # dispatched under the lock, pins this capture's values
-            import jax
-            import jax.numpy as jnp
-
-            arrays = {k: (jnp.copy(v) if isinstance(v, jax.Array)
-                          else v)
-                      for k, v in arrays.items()}
+            arrays = _pin(arrays)
         # durability barrier: async sink appends for everything captured
         # must land before this capture's checkpoints can ever commit
         flush = getattr(self.sink, "flush", None)
@@ -1289,6 +1322,8 @@ class QueryTask(threading.Thread):
                 # (SURVEY §7 "protobuf decode off the critical path")
                 with trace_span(self.tracer, "key_encode"):
                     key_ids, cols, nulls = _columnarize_rows(ex, rows)
+                    if len(ts):
+                        ex.note_key_use(key_ids, int(max(ts)))
                 self._submit(ex, key_ids, np.asarray(ts, np.int64),
                              cols, nulls)
                 return
@@ -1499,6 +1534,18 @@ def _sample_rows(ts: "np.ndarray", cols: dict,
         drop_null=True)
 
 
+def _pin(arrays: dict) -> dict:
+    """Break aliasing with the step's donated buffers: the async
+    persist serializes AFTER later steps have donated (and so deleted)
+    the captured arrays — a cheap on-device copy, dispatched under the
+    lock, pins a capture's values."""
+    import jax
+    import jax.numpy as jnp
+
+    return {k: (jnp.copy(v) if isinstance(v, jax.Array) else v)
+            for k, v in arrays.items()}
+
+
 def _columnarize_rows(ex, rows: list) -> tuple:
     """Decoded JSON rows -> (key_ids, cols, nulls) for the staged
     columnar path: one pass per needed column instead of the per-row
@@ -1551,13 +1598,14 @@ def _columnarize_rows(ex, rows: list) -> tuple:
     return key_ids, cols, (nulls or None)
 
 
-def _dictionary_key_ids(ex, cols: dict, n: int,
-                        nulls: dict | None) -> "np.ndarray | None":
+def _dictionary_key_ids(ex, cols: dict, n: int, nulls: dict | None
+                        ) -> "tuple[np.ndarray, np.ndarray] | None":
     """Key ids for ONE string group column whose dictionary is no larger
     than the batch: the whole dictionary resolves against the executor's
     key table in one call (engine/keytable.py: native, GIL released),
-    and only its misses go through `key_id_for`. None when the input is
-    not of that shape (`_columnar_key_ids` then takes the general path).
+    and only its misses go through `key_id_for`. Gives (key id of every
+    row, the ids the batch names), or None when the input is not of
+    that shape (`_resolve_key_ids` then takes the general path).
 
     Misses keep the general path's rules, so every key gets the id it
     always got and snapshots stay byte-compatible: only entries whose
@@ -1575,40 +1623,66 @@ def _dictionary_key_ids(ex, cols: dict, n: int,
     if lut is None:
         return None
     nm = nulls.get(c) if nulls else None
-    live = None
+    live = null_kid = None
     if nm is not None and nm.any():
         live = ~nm
         codes = codes[live]
         null_kid = table.get(None)
         table.lookups += 1
-        if null_kid is None:
-            null_kid = table.register(ex, None)
     missed = np.flatnonzero(lut < 0)
     if len(missed):
         occurs = np.bincount(codes, minlength=len(d))[missed] > 0
         missed = missed[occurs]
+    if len(missed) or (live is not None and null_kid is None):
+        # the hits are this batch's: a registration may retire dead
+        # keys, and must not take an id the batch already names
+        ex.pin_keys(lut[lut >= 0])
+        if null_kid is not None:
+            ex.pin_keys(null_kid)
+    if live is not None and null_kid is None:
+        null_kid = table.register(ex, None)
+    if len(missed):
         lut[missed] = table.register_strings(
             ex, [d[p] for p in missed.tolist()])
+    # the ids the batch names: the dictionary's (-1 where an entry is
+    # unknown and in no row: `note_key_use` skips those)
     if live is None:
-        return lut[codes]
+        return lut[codes], lut
     key_ids = np.full(n, null_kid, np.int32)
     key_ids[live] = lut[codes]
-    return key_ids
+    return key_ids, np.append(lut, np.int32(null_kid))
 
 
 def _columnar_key_ids(ex, cols: dict, n: int,
-                      nulls: dict | None = None) -> "np.ndarray":
+                      nulls: dict | None = None,
+                      ts_hi: int | None = None) -> "np.ndarray":
     """Vectorized group-key encoding: per-column unique+inverse, then
-    one key_id_for call per DISTINCT combination (not per row). `nulls`
-    marks cells whose group value is None (native JSON decode)."""
+    one registration per DISTINCT combination (not per row). `nulls`
+    marks cells whose group value is None (native JSON decode). `ts_hi`
+    (no event of the batch is later) dates the ids the batch names
+    (`QueryExecutor.note_key_use`), so that the executor may retire a
+    key once every window it was named in has closed; without it the
+    ids stay pinned."""
+    key_ids, named = _resolve_key_ids(ex, cols, n, nulls)
+    if ts_hi is not None and ex.group_cols:
+        ex.note_key_use(named, ts_hi)
+    return key_ids
+
+
+def _resolve_key_ids(ex, cols: dict, n: int, nulls: dict | None
+                     ) -> "tuple[np.ndarray, np.ndarray]":
+    """(key id of every row, the ids the batch names: each at least
+    once, far fewer than rows where the batch's distinct values are
+    in hand)."""
     if not ex.group_cols:
-        return np.zeros(n, np.int32)
+        return np.zeros(n, np.int32), np.zeros(0, np.int32)
     if len(ex.group_cols) == 1:
-        key_ids = _dictionary_key_ids(ex, cols, n, nulls)
-        if key_ids is not None:
-            return key_ids
+        got = _dictionary_key_ids(ex, cols, n, nulls)
+        if got is not None:
+            return got
     col_vals: list[list] = []
     col_codes: list[np.ndarray] = []
+    floats = False  # a group value that canon_key may change
     for c in ex.group_cols:
         ent = cols.get(c)
         if ent is None:
@@ -1639,10 +1713,12 @@ def _columnar_key_ids(ex, cols: dict, n: int,
                 # number decoding JSON rows go through (records.py)
                 vals = [int(u) if float(u).is_integer() else float(u)
                         for u in uniq]
+                floats = True
             elif kind == "f32":
                 vals = [float(u) for u in uniq]
+                floats = True
             else:
-                vals = [int(u) for u in uniq]
+                vals = uniq.tolist()
             codes = inv.astype(np.int64)
         nm = nulls.get(c) if nulls else None
         if nm is not None and nm.any():
@@ -1663,22 +1739,32 @@ def _columnar_key_ids(ex, cols: dict, n: int,
         # value -> key id through the executor's key table: at
         # SURVEY-scale cardinality (100K+ live keys) the per-distinct
         # key_id_for canon+tuple work is ~100ms per batch; a dict hit
-        # is ~10x cheaper. kids never change once assigned, so the
-        # table cannot go stale, and it holds canonical values only,
-        # so the key space bounds it.
+        # is ~10x cheaper. The table holds canonical values of live
+        # keys only (the executor makes it forget a key it retires),
+        # so the live key set bounds it. Lookups and the registration
+        # of the misses are one C-level pass each: NEXmark's bid
+        # stream names thousands of auctions a call that no batch has
+        # named before.
         table = ex._key_table
         table.sync(ex._key_rev)
-        get = table.get
-        present = np.unique(codes).tolist()
+        present = np.unique(codes)
         table.lookups += len(present)
+        named = vals if len(present) == len(vals) \
+            else [vals[p] for p in present.tolist()]
+        kids = np.fromiter(
+            map(table.get, named, itertools.repeat(-1)), np.int32,
+            len(named))
+        missed = np.flatnonzero(kids < 0)
+        if len(missed):
+            # the hits are this batch's: registering the misses may
+            # retire dead keys, and must not take an id it names
+            ex.pin_keys(kids[kids >= 0])
+            kids[missed] = table.register_values(
+                ex, [named[i] for i in missed.tolist()],
+                canonical=not floats)
         kid_lut = np.zeros(len(vals), np.int32)
-        for p in present:
-            v = vals[p]
-            kid = get(v)
-            if kid is None:
-                kid = table.register(ex, v)
-            kid_lut[p] = kid
-        return kid_lut[codes]
+        kid_lut[present] = kids
+        return kid_lut[codes], kids
     radix = 1
     for vals in col_vals:
         radix *= max(len(vals), 1)
@@ -1688,8 +1774,9 @@ def _columnar_key_ids(ex, cols: dict, n: int,
         # high-cardinality group columns in one batch)
         arrs = [np.asarray(vals, object)[codes]
                 for vals, codes in zip(col_vals, col_codes)]
-        return np.fromiter((ex.key_id_for(t) for t in zip(*arrs)),
-                           np.int32, n)
+        key_ids = np.fromiter((ex.key_id_for(t) for t in zip(*arrs)),
+                              np.int32, n)
+        return key_ids, key_ids
     combined = col_codes[0]
     for codes, vals in zip(col_codes[1:], col_vals[1:]):
         combined = combined * len(vals) + codes
@@ -1704,7 +1791,7 @@ def _columnar_key_ids(ex, cols: dict, n: int,
         idxs.reverse()
         key = tuple(col_vals[k][i] for k, i in enumerate(idxs))
         kid_for_u[j] = ex.key_id_for(key)
-    return kid_for_u[inv]
+    return kid_for_u[inv], kid_for_u
 
 
 def _device_columns(ex, cols: dict, n: int, nulls: dict | None = None):

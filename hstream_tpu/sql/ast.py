@@ -41,6 +41,29 @@ class SetFunc(Expr):
     text: str = ""                # original SQL text, used as default name
 
 
+@dataclass(frozen=True)
+class OverFunc(Expr):
+    """`MAX(<set function>) OVER (PARTITION BY ...)`: the extreme of an
+    aggregate across the groups of one partition. Legal in QUALIFY
+    alone, partitioned by the window and nothing else (refine.py)."""
+
+    kind: SetFuncKind             # MAX or MIN
+    arg: Expr | None              # the aggregate the extreme is of
+    partition: tuple[str, ...]    # PARTITION BY names, as written
+    text: str = ""
+
+
+@dataclass(frozen=True)
+class Qualify:
+    """`QUALIFY <set function> <op> <OverFunc>`: keep, in every window,
+    the groups whose aggregate is the window's extreme of it."""
+
+    func: Expr                    # left of the comparison
+    op: str                       # ">=", "<=" or "="
+    over: Expr                    # right of it
+    text: str = ""
+
+
 # ---- intervals & windows ---------------------------------------------------
 
 _UNIT_MS = {
@@ -112,6 +135,7 @@ class Select:
     window: WindowExpr | None
     having: Expr | None
     emit_changes: bool                 # False = SelectView (pull query)
+    qualify: Qualify | None = None     # the top across groups, per window
 
 
 # ---- statements ------------------------------------------------------------

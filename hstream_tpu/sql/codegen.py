@@ -23,6 +23,8 @@ from hstream_tpu.engine.plan import (
     FilterNode,
     ProjectNode,
     SourceNode,
+    WindowTop,
+    single_chip_reason,
 )
 from hstream_tpu.engine.types import ColumnType, Schema
 from hstream_tpu.engine.window import (
@@ -120,7 +122,7 @@ class _AggCollector:
         kind = _AGG_KIND.get(sf.kind)
         if kind is None:
             raise SQLCodegenError(f"aggregate {sf.kind.value} not supported")
-        key = (kind, sf.arg, sf.arg2)
+        key = self._key(sf)
         name = self._by_key.get(key)
         if name is None:
             name = sf.text or f"agg{len(self.specs)}"
@@ -138,6 +140,14 @@ class _AggCollector:
                                       k=k))
             self._by_key[key] = name
         return Col(name)
+
+    @staticmethod
+    def _key(sf: ast.SetFunc) -> tuple:
+        return (_AGG_KIND.get(sf.kind), sf.arg, sf.arg2)
+
+    def name_of(self, sf: ast.SetFunc) -> str | None:
+        """The output name of an aggregate already interned."""
+        return self._by_key.get(self._key(sf))
 
     def rewrite(self, e: Expr) -> Expr:
         if isinstance(e, ast.SetFunc):
@@ -252,6 +262,15 @@ def lower_select(sel: ast.Select, sql: str = "") -> plans.SelectPlan:
         if not coll.specs:
             raise SQLCodegenError(
                 "GROUP BY queries need at least one aggregate in SELECT")
+        top = None
+        if sel.qualify is not None:
+            over = sel.qualify.over
+            name = coll.name_of(over.arg)
+            if name is None:
+                raise SQLCodegenError(
+                    f"QUALIFY names {over.arg.text}, which the SELECT "
+                    "list does not compute")
+            top = WindowTop(agg=name, extreme=over.kind.value.lower())
         node = AggregateNode(
             child=node,
             group_keys=list(sel.group_by),
@@ -259,6 +278,7 @@ def lower_select(sel: ast.Select, sql: str = "") -> plans.SelectPlan:
             aggs=coll.specs,
             having=having,
             post_projections=[] if natural else projected,
+            top=top,
         )
     else:
         exprs = [( _default_name(i, n), i.expr) for n, i in enumerate(items)]
@@ -362,16 +382,10 @@ def mesh_exclusion_reason(plan: plans.Plan) -> str | None:
     # the fused probe scatter into the sharded aggregate lattice, and
     # session windows shard their chain-merge arena per key shard — only
     # the downstream aggregate's own exclusions remain
-    from hstream_tpu.engine.plan import AggKind, AggregateNode
-
     node = plan.node
     if not isinstance(node, AggregateNode):
         return "stateless plans have no device state to shard"
-    if any(a.kind in (AggKind.TOPK, AggKind.TOPK_DISTINCT)
-           for a in node.aggs):
-        return ("TOPK/TOPK_DISTINCT planes have no elementwise shard "
-                "merge; the query runs single-chip")
-    return None
+    return single_chip_reason(node)
 
 
 def explain_text(plan: plans.Plan) -> str:
@@ -385,6 +399,14 @@ def explain_text(plan: plans.Plan) -> str:
             if isinstance(n, AggregateNode):
                 w = n.window
                 wtxt = (f" window={type(w).__name__}" if w else "")
+                if n.top is not None:
+                    # a line of its own, above what it filters: the
+                    # groups that reach the window's extreme
+                    fn = n.top.extreme.upper()
+                    lines.append(
+                        f"{pad}QUALIFY {n.top.agg} = {fn}({n.top.agg}) "
+                        "OVER (PARTITION BY winStart, winEnd) "
+                        "[top across groups, on the device at close]")
                 lines.append(
                     f"{pad}AGGREGATE keys={[getattr(g, 'name', '?') for g in n.group_keys]}"
                     f" aggs={[a.out_name for a in n.aggs]}{wtxt}"
@@ -492,10 +514,8 @@ def make_executor(plan: plans.SelectPlan, sample_rows=None, *,
             return SessionExecutor(node, schema,
                                    emit_changes=plan.emit_changes,
                                    mesh=mesh)
-        if mesh is not None and any(
-                a.kind in (AggKind.TOPK, AggKind.TOPK_DISTINCT)
-                for a in node.aggs):
-            mesh = None  # TOPK planes have no elementwise shard merge
+        if mesh is not None and single_chip_reason(node) is not None:
+            mesh = None  # said by mesh_exclusion_reason / EXPLAIN
         if mesh is not None:
             from hstream_tpu.parallel import ShardedQueryExecutor
 

@@ -4,7 +4,8 @@ Grammar parity with the reference's BNFC grammar (hstream-sql/etc/SQL.cf):
 statements SELECT / CREATE (STREAM [AS] | VIEW | SINK CONNECTOR) / INSERT
 (fields, 'json', "binary") / SHOW / DROP [IF EXISTS] / TERMINATE /
 EXPLAIN; SELECT with FROM + [JOIN ... WITHIN(...) ON ...] + WHERE +
-GROUP BY [, window] + HAVING + [EMIT CHANGES]; value expressions with
+GROUP BY [, window] + HAVING + [QUALIFY agg >= MAX(agg) OVER (PARTITION
+BY winStart, winEnd)] + [EMIT CHANGES]; value expressions with
 || && arithmetic, scalar functions, set functions, BETWEEN, NOT;
 search conditions with OR/AND/NOT. A select without EMIT CHANGES is a
 pull query against a view (SelectView in the reference).
@@ -309,13 +310,59 @@ class Parser:
         having = None
         if self.try_kw("HAVING"):
             having = self.parse_cond()
+        qualify = None
+        if self.at_kw("QUALIFY"):
+            qualify = self.parse_qualify()
         emit_changes = False
         if self.try_kw("EMIT"):
             self.eat_kw("CHANGES")
             emit_changes = True
         return ast.Select(items=items, source=source, join=join, where=where,
                           group_by=group_by, window=window, having=having,
-                          emit_changes=emit_changes)
+                          emit_changes=emit_changes, qualify=qualify)
+
+    def parse_qualify(self) -> ast.Qualify:
+        """QUALIFY <value> (>= | <= | =) <value>: one comparison; which
+        comparisons mean something is refine's to say."""
+        self.eat_kw("QUALIFY")
+        start = self.pos
+        left = self.parse_expr()
+        t = self.peek()
+        if not (t.kind == "SYM" and t.text in (">=", "<=", "=")):
+            self.err("QUALIFY is not supported but as <aggregate> >= "
+                     "MAX(<aggregate>) OVER (PARTITION BY winStart, "
+                     "winEnd) (or <= MIN, or =)")
+        op = self.next().text
+        right = self.parse_expr()
+        if self.at_kw("AND", "OR"):
+            self.err("QUALIFY takes one comparison: AND / OR are not "
+                     "supported")
+        return ast.Qualify(left, op, right,
+                           self.text_between(start, self.pos))
+
+    def parse_over(self, kind, args: list, name_t: Token,
+                   start: int) -> ast.OverFunc:
+        """`OVER (PARTITION BY a, b)` behind an aggregate call."""
+        if kind not in (ast.SetFuncKind.MAX, ast.SetFuncKind.MIN):
+            self.err(f"{name_t.text}(...) OVER is not supported: only "
+                     "MAX and MIN take OVER", name_t)
+        self.eat_kw("OVER")
+        self.eat_sym("(")
+        partition: list[str] = []
+        if self.try_kw("PARTITION"):
+            self.eat_kw("BY")
+            partition.append(self.ident("partition column"))
+            while self.try_sym(","):
+                partition.append(self.ident("partition column"))
+        if self.at_kw("ORDER"):
+            self.err("ORDER BY inside OVER is not supported")
+        if self.at_kw("ROWS", "RANGE", "GROUPS"):
+            self.err("a window frame (ROWS / RANGE / GROUPS) inside OVER "
+                     "is not supported")
+        self.eat_sym(")")
+        return ast.OverFunc(kind, args[0] if args else None,
+                            tuple(partition),
+                            self.text_between(start, self.pos))
 
     def parse_colname(self) -> Col:
         t = self.next()
@@ -552,6 +599,9 @@ class Parser:
         self.eat_sym("(")
         if fname == "COUNT" and self.try_sym("*"):
             self.eat_sym(")")
+            if self.at_kw("OVER"):
+                self.err("COUNT(*) OVER is not supported: only MAX and "
+                         "MIN take OVER", name_t)
             return ast.SetFunc(ast.SetFuncKind.COUNT_ALL, None, None,
                                "COUNT(*)")
         args: list[Expr] = []
@@ -571,6 +621,8 @@ class Parser:
                 return ast.SetFunc(kind, args[0], args[1].value, text)
             if len(args) != 1:
                 self.err(f"{fname} expects 1 argument", name_t)
+            if self.at_kw("OVER"):
+                return self.parse_over(kind, args, name_t, start)
             return ast.SetFunc(kind, args[0], None, text)
         if fname in _UNARY_FUNCS:
             if len(args) != 1:

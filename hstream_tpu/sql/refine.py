@@ -27,6 +27,82 @@ _NEEDS_ARG = {
 }
 
 
+def _over_funcs(e: Expr | None) -> list[ast.OverFunc]:
+    if isinstance(e, ast.OverFunc):
+        return [e]
+    if isinstance(e, ast.SetFunc):
+        return _over_funcs(e.arg)
+    if isinstance(e, BinOp):
+        return _over_funcs(e.left) + _over_funcs(e.right)
+    if isinstance(e, UnOp):
+        return _over_funcs(e.operand)
+    return []
+
+
+_QUALIFY_FORM = ("<aggregate> >= MAX(<aggregate>) OVER (PARTITION BY "
+                 "winStart, winEnd), or <= MIN(...), or = either")
+
+
+def _validate_qualify(sel: ast.Select) -> None:
+    """QUALIFY keeps, in every window, the groups whose aggregate is the
+    window's extreme of it: one form, and every other use is refused by
+    name (a second query, another partition, an order or a frame have
+    no program behind them)."""
+    q = sel.qualify
+    over, func = q.over, q.func
+    if not isinstance(over, ast.OverFunc):
+        raise SQLValidateError(
+            f"QUALIFY is not supported but as {_QUALIFY_FORM}")
+    if not isinstance(over.arg, ast.SetFunc):
+        raise SQLValidateError(
+            f"{over.kind.value}(...) OVER takes a set function the "
+            "statement computes (COUNT(*), SUM(x), ...): a column or an "
+            "expression as its argument is not supported")
+    if _set_funcs(over.arg)[1:] or _over_funcs(over.arg):
+        raise SQLValidateError(
+            "nested aggregate functions inside OVER's argument")
+    if func != over.arg:
+        raise SQLValidateError(
+            f"QUALIFY compares {over.arg.text} with its own extreme: "
+            f"the left side must be {over.arg.text}, not "
+            f"{getattr(func, 'text', None) or 'an expression'}")
+    if over.arg.kind in (ast.SetFuncKind.TOPK,
+                         ast.SetFuncKind.TOPKDISTINCT):
+        raise SQLValidateError(
+            f"{over.arg.kind.value} gives a list a group: an extreme "
+            "across groups of it is not supported")
+    ops = {ast.SetFuncKind.MAX: (">=", "="),
+           ast.SetFuncKind.MIN: ("<=", "=")}[over.kind]
+    if q.op not in ops:
+        raise SQLValidateError(
+            f"QUALIFY ... {q.op} {over.kind.value}(...) OVER keeps no "
+            f"group or every group: write {ops[0]} (or =)")
+    if sel.window is None:
+        raise SQLValidateError(
+            "QUALIFY ... OVER without a time window is not supported: "
+            "GROUP BY needs TUMBLING or HOPPING, whose close the "
+            "extreme is taken at")
+    if sel.window.kind == ast.WindowKind.SESSION:
+        raise SQLValidateError(
+            "QUALIFY ... OVER over a SESSION window is not supported: "
+            "sessions have no common window to partition by")
+    if sorted(p.lower() for p in over.partition) != ["winend", "winstart"]:
+        raise SQLValidateError(
+            "OVER (PARTITION BY ...) is supported for the window alone: "
+            "PARTITION BY winStart, winEnd, not "
+            f"({', '.join(over.partition) or 'nothing'})")
+    if sel.join is not None:
+        raise SQLValidateError(
+            "QUALIFY ... OVER on a JOIN is not supported")
+    if sel.emit_changes:
+        raise SQLValidateError(
+            "QUALIFY ... OVER with EMIT CHANGES is not supported: a "
+            "window's extreme is known when it closes (CREATE VIEW)")
+    if sel.having is not None:
+        raise SQLValidateError(
+            "QUALIFY ... OVER together with HAVING is not supported")
+
+
 def _set_funcs(e: Expr) -> list[ast.SetFunc]:
     if isinstance(e, ast.SetFunc):
         inner = _set_funcs(e.arg) if e.arg is not None else []
@@ -194,7 +270,14 @@ def _validate_select(sel: ast.Select) -> None:
     if dup:
         raise SQLValidateError(f"duplicate GROUP BY column(s) {sorted(dup)}")
     items = sel.items or []
+    for e in [i.expr for i in items] + [sel.where, sel.having]:
+        if _over_funcs(e):
+            raise SQLValidateError(
+                "OVER is supported in QUALIFY alone, not in SELECT, "
+                "WHERE or HAVING")
     _validate_aggs(items, sel.having)
+    if sel.qualify is not None:
+        _validate_qualify(sel)
     # alias uniqueness
     aliases = [i.alias for i in items if i.alias]
     if len(aliases) != len(set(aliases)):

@@ -96,6 +96,24 @@ PER_STREAM_COUNTERS = [
                                # the heartbeat-lease CAS (try_adopt_
                                # live), boot adoption NOT included
                                # (label: query id)
+    "key_retirements",         # times a window-lattice query's full key
+                               # table freed the ids of dead keys
+                               # before growing (label: query id)
+    "keys_retired",            # group keys whose every window had
+                               # closed and whose ids were freed
+                               # (label: query id)
+    "key_ids_reused",          # freed key ids handed to new keys
+                               # (label: query id)
+    "close_rows_kept",         # rows that reached their window's
+                               # extreme at a top close (QUALIFY ...
+                               # OVER; label: query id)
+    "close_groups",            # groups that held a count in a window
+                               # closed by a top close, by the device's
+                               # own reduce (label: query id)
+    "close_tie_refetches",     # top closes where more rows tied than
+                               # the survivors' buffer holds: a second
+                               # fetch, of the full column (label:
+                               # query id)
     "read_extracts",           # pull-query serves that actually ran an
                                # executor peek (read-plane contract:
                                # ~one per view per close cycle, not one
@@ -132,6 +150,10 @@ GAUGES = [
                               # store this server fronts
     "dedup_window_size",      # producer-dedup seqs remembered across
                               # all producers (bounded per producer)
+    "keys_live",              # per window-lattice query: group keys
+                              # that hold a key id
+    "key_capacity",           # per window-lattice query: key ids the
+                              # device planes have rows for
     "query_watermark_ms",     # per query: event-time watermark
                               # (absolute ms) of the query's executor
     "query_watermark_lag_ms", # per query: wall clock - watermark (the

@@ -43,7 +43,10 @@ PREFIX = "hstream"
 QUERY_LABEL_COUNTERS = frozenset({"query_restarts", "snapshot_fallbacks",
                                   "late_drops", "kernel_recompiles",
                                   "placement_decisions",
-                                  "queries_adopted"})
+                                  "queries_adopted", "key_retirements",
+                                  "keys_retired", "key_ids_reused",
+                                  "close_rows_kept", "close_groups",
+                                  "close_tie_refetches"})
 
 # counters whose label is a closed vocabulary outside both the stream
 # and query namespaces (kernel families): never liveness-filtered
@@ -87,6 +90,18 @@ _HELP = {
                             "append path",
     "late_drops": "records dropped as late (past the window close "
                   "boundary at the pre-batch watermark)",
+    "key_retirements": "times a full key table freed the ids of dead "
+                       "group keys before growing",
+    "keys_retired": "group keys retired (every window they were named "
+                    "in had closed) and their ids freed",
+    "key_ids_reused": "freed key ids handed to new group keys",
+    "close_rows_kept": "rows that reached their window's extreme at a "
+                       "top close (QUALIFY ... OVER)",
+    "close_groups": "groups that held a count in a window closed by a "
+                    "top close, by the device's own reduce",
+    "close_tie_refetches": "top closes that fetched the full column "
+                           "because more rows tied than the survivors' "
+                           "buffer holds",
     "device_h2d_bytes": "host-to-device bytes on the staging path",
     "device_d2h_bytes": "device-to-host bytes on the close/changelog "
                         "drain paths",
@@ -114,6 +129,9 @@ _HELP = {
                      "server fronts",
     "dedup_window_size": "producer-dedup seqs remembered across all "
                          "producers",
+    "keys_live": "group keys that hold a key id in the query's "
+                 "window lattice",
+    "key_capacity": "key ids the query's device planes have rows for",
     "query_watermark_ms": "event-time watermark of the query's "
                           "executor (absolute ms)",
     "query_watermark_lag_ms": "wall clock minus the query's event-time "

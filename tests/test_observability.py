@@ -231,6 +231,14 @@ def _golden_holder() -> StatsHolder:
     stats.stat_add("read_out_records", "v1", 9.0, now=BASE / 1000)
     stats.gauge_set("read_cache_hit_ratio", "", 0.75)
     stats.gauge_set("read_cache_bytes", "", 16384)
+    # the window lattice's key dictionary and its top close (ISSUE 33):
+    # query-labelled counters and the two gauges
+    for name, v in (("key_retirements", 2), ("keys_retired", 900),
+                    ("key_ids_reused", 850), ("close_rows_kept", 7),
+                    ("close_groups", 4000), ("close_tie_refetches", 1)):
+        stats.stream_stat_add(name, "q1", v)
+    stats.gauge_set("keys_live", "q1", 600)
+    stats.gauge_set("key_capacity", "q1", 1024)
     return stats
 
 
