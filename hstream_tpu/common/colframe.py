@@ -10,9 +10,11 @@ columnar block the encode workers already consume (``HSCB1``: ts vector
 + named fixed-width columns + null masks, ``common/columnar.py``),
 wrapped in a 13-byte frame the server can bounds-check WITHOUT
 materializing a single row. The server's whole job is: check the frame,
-check the block's declared sizes against its actual bytes, splice a
-precomputed record header around the payload (one memcpy — no protobuf
-walk), and hand the bytes to the append front.
+check the block's declared sizes against its actual bytes (and its
+string dictionaries' syntax and lengths natively, building none of
+their strings: `columnar.decode_columnar_nulls`), splice a precomputed
+record header around the payload (one memcpy — no protobuf walk), and
+hand the bytes to the append front.
 
 Frame layout (little-endian)::
 
@@ -83,17 +85,27 @@ def open_frame(frame: bytes) -> memoryview:
 
 
 # contract: dispatches<=0 fetches<=0
-def open_block(frame: bytes) -> tuple[memoryview, int, int]:
-    """Frame -> (payload view, n_rows, last_ts_ms), fully validated:
-    the frame envelope (open_frame) AND the embedded columnar block's
-    declared sizes (columnar.validate_block). The ONE door every framed
-    append passes through — after this returns, the payload is exactly
-    the columnar record the query tasks already decode."""
+def check_block(frame: bytes) -> tuple[memoryview, int, int, bool]:
+    """Frame -> (payload view, n_rows, last_ts_ms, native), fully
+    validated: the frame envelope (open_frame) AND the embedded columnar
+    block's declared sizes (columnar.validate_block). The ONE door every
+    framed append passes through — after this returns, the payload is
+    exactly the columnar record the query tasks already decode, by the
+    same function. `native` says how the header's dictionaries were
+    checked: by the native scan (syntax and counts, GIL released, no
+    string built), or, for a header the scan does not recognise, by
+    parsing the whole header as the tasks' decode will."""
     from hstream_tpu.common import columnar
 
     payload = open_frame(frame)
     try:
-        n, last_ts = columnar.validate_block(payload)
+        n, last_ts, native = columnar.validate_block(payload)
     except (ValueError, KeyError, TypeError) as e:
         raise InvalidFrame(f"bad columnar block: {e}") from e
-    return payload, n, last_ts
+    return payload, n, last_ts, native
+
+
+# contract: dispatches<=0 fetches<=0
+def open_block(frame: bytes) -> tuple[memoryview, int, int]:
+    """`check_block` without its last answer."""
+    return check_block(frame)[:3]

@@ -23,15 +23,35 @@ wire (the framed append path's staging layout): a masked cell behaves
 exactly like a field a per-record producer never sent. Payloads
 without the "nulls" header key are the legacy layout — old producers
 and old decoders interoperate unchanged.
+
+Reading a header (`decode_columnar_nulls`, the one decode the append
+door and every reader share): the dictionaries are most of it (one
+entry a distinct string: 4.6 MB of a 7 MB NEXmark bid frame), and
+`json.loads` over them holds the GIL for 4-16 ms to build strings that
+a plan may never read. So a native scan (`jsondec.cpp`
+`jd_header_dicts`, GIL released) checks each dictionary's syntax and
+counts its entries, `json.loads` parses the ~100 bytes that are left,
+every size check runs against the native counts, and a string column's
+dictionary is a `LazyDictionary`: its length is known, its strings are
+parsed the first time something reads one. A header the scan does not
+recognise (an escape, a byte outside printable ASCII, anything but the
+client encoder's own compact form), or a library that did not build,
+takes the whole-header parse, as before: same answers, same refusals.
 """
 
 from __future__ import annotations
 
+import ctypes
 import json
 from collections.abc import Sequence
 from typing import Any, Mapping
 
 import numpy as np
+
+from hstream_tpu.common import jsondec
+from hstream_tpu.common.logger import get_logger
+
+log = get_logger("columnar")
 
 MAGIC = b"HSCB1\x00"
 
@@ -102,16 +122,122 @@ def encode_columnar(ts_ms: np.ndarray,
     return bytes(out)
 
 
-def decode_columnar_nulls(payload) -> tuple[np.ndarray, dict[str, Any],
-                                            dict[str, np.ndarray] | None]:
-    """payload -> (ts i64[n], {name: (kind, array, dict|None)},
-    {name: bool[n]} | None).
+# string columns one header's scan reports; a header with more takes
+# the whole-header parse
+_MAX_SCANNED_DICTS = 64
 
-    Arrays are zero-copy views into the payload where alignment allows;
-    accepts bytes or a memoryview (the framed append path hands the
-    frame's payload view straight in). Every declared size is checked
-    against the actual bytes BEFORE any array is built — a forged or
-    torn payload fails here, not deep inside the engine."""
+_warned = False
+
+
+def load_native():
+    """The library that scans a header's dictionaries, built and loaded
+    on the first call (a server makes it at boot, so that no append
+    waits for a compiler); None, said once in the log, where it cannot
+    be built: every header then takes the whole-header parse."""
+    global _warned
+    lib = jsondec.load()
+    if lib is None and not _warned:
+        _warned = True
+        log.warning("native header scan unavailable (cpp/jsondec.cpp did "
+                    "not build or load): every columnar header is parsed "
+                    "whole, dictionaries and all, holding the GIL")
+    return lib
+
+
+class LazyDictionary(Sequence):
+    """A string column's dictionary whose entries are still the header's
+    bytes. The native scan has checked them (JSON strings of printable
+    ASCII, no escape) and counted them, so `len()` costs nothing; any
+    other use (`d[i]`, iteration, `list(d)`, `np.asarray(d)`) makes the
+    strings once and keeps the list. `built` says whether that has
+    happened: a column no plan reads never pays for it."""
+
+    __slots__ = ("_span", "_n", "_strs")
+
+    def __init__(self, span, n: int):
+        self._span = span     # the header's bytes from '[' to ']'
+        self._n = n
+        self._strs: list[str] | None = None
+
+    def __len__(self) -> int:
+        return self._n
+
+    @property
+    def built(self) -> bool:
+        return self._strs is not None
+
+    def strings(self) -> list[str]:
+        """The entries as a list, made on the first call. No entry
+        holds a quote or a backslash (the scan refuses both), so the
+        bytes between the quotes are the strings, and `","` occurs
+        between entries alone: a split is what `json.loads` gives, in
+        a third of its time."""
+        if self._strs is None:
+            self._strs = str(self._span[2:-2], "ascii").split('","') \
+                if self._n else []
+        return self._strs
+
+    def __getitem__(self, i):
+        return self.strings()[i]
+
+    def __iter__(self):
+        return iter(self.strings())
+
+    def __eq__(self, other):
+        if isinstance(other, (list, LazyDictionary)):
+            return self.strings() == list(other)
+        return NotImplemented
+
+    __hash__ = None
+
+    def __repr__(self) -> str:
+        return f"LazyDictionary(n={self._n}, built={self.built})"
+
+
+def _dictionary_spans(hdr) -> list[list[int]] | None:
+    """(offset of '[', offset past ']', entries) of every array under
+    the header's "dicts", in the order met, by the native scan; None
+    for a header it does not recognise, or without the library."""
+    lib = load_native()
+    if lib is None or not len(hdr):
+        return None
+    spans = np.empty((_MAX_SCANNED_DICTS, 3), np.int64)
+    k = lib.jd_header_dicts(
+        np.frombuffer(hdr, np.uint8).ctypes.data, len(hdr),
+        spans.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+        _MAX_SCANNED_DICTS)
+    return None if k < 0 else spans[:k].tolist()
+
+
+def _scan_header(hdr) -> tuple[dict, bool]:
+    """The header's JSON object, and whether its dictionaries were
+    checked natively: `header["dicts"]` then maps each name to a
+    `LazyDictionary` over `hdr`'s bytes, and nothing but the rest of
+    the header went through `json.loads`. Raises what `json.loads`
+    raises on the whole header."""
+    spans = _dictionary_spans(hdr)
+    if spans is None:
+        return json.loads(bytes(hdr)), False
+    # each array gives way to its index: a complete value for a complete
+    # value, so what is left parses, or fails, as the whole would; every
+    # member of "dicts" is such an index
+    rest, lazy, pos = [], [], 0
+    for i, (a, b, count) in enumerate(spans):
+        rest += (bytes(hdr[pos:a]), b"%d" % i)
+        lazy.append(LazyDictionary(hdr[a:b], count))
+        pos = b
+    rest.append(bytes(hdr[pos:]))
+    header = json.loads(b"".join(rest))
+    if lazy:
+        header["dicts"] = {name: lazy[i]
+                           for name, i in header["dicts"].items()}
+    return header, True
+
+
+def _decode(payload) -> tuple[np.ndarray, dict[str, Any],
+                              dict[str, np.ndarray] | None, bool]:
+    """`decode_columnar_nulls`, and whether the header's dictionaries
+    were checked natively (`_scan_header`)."""
     if not is_columnar(payload):
         raise ValueError("not a columnar payload")
     off = len(MAGIC)
@@ -122,7 +248,8 @@ def decode_columnar_nulls(payload) -> tuple[np.ndarray, dict[str, Any],
     if len(payload) - off < hlen:
         raise ValueError("columnar header shorter than declared")
     try:
-        header = json.loads(bytes(payload[off: off + hlen]))
+        header, native = _scan_header(
+            memoryview(payload)[off: off + hlen])
     except ValueError as e:
         raise ValueError(f"bad columnar header JSON: {e}") from None
     off += hlen
@@ -155,7 +282,7 @@ def decode_columnar_nulls(payload) -> tuple[np.ndarray, dict[str, Any],
             arr = arr.astype(np.bool_)
         d = header["dicts"].get(name)
         if kind == "str":
-            if not isinstance(d, list):
+            if not isinstance(d, (list, LazyDictionary)):
                 raise ValueError(f"string column {name!r} missing dict")
             if n and (int(arr.min()) < 0 or int(arr.max()) >= len(d)):
                 raise ValueError(
@@ -177,7 +304,23 @@ def decode_columnar_nulls(payload) -> tuple[np.ndarray, dict[str, Any],
         raise ValueError(
             f"columnar payload longer than header claims "
             f"({len(payload) - off} trailing bytes)")
-    return ts, cols, nulls
+    return ts, cols, nulls, native
+
+
+def decode_columnar_nulls(payload) -> tuple[np.ndarray, dict[str, Any],
+                                            dict[str, np.ndarray] | None]:
+    """payload -> (ts i64[n], {name: (kind, array, dict|None)},
+    {name: bool[n]} | None).
+
+    Arrays are zero-copy views into the payload where alignment allows;
+    accepts bytes or a memoryview (the framed append path hands the
+    frame's payload view straight in). Every declared size is checked
+    against the actual bytes BEFORE any array is built — a forged or
+    torn payload fails here, not deep inside the engine. A string
+    column's dictionary is a list, or a `LazyDictionary` where the
+    header's dictionaries were checked natively (module docstring):
+    equal to the list once read, and every check here is the same."""
+    return _decode(payload)[:3]
 
 
 def decode_columnar(payload) -> tuple[np.ndarray, dict[str, Any]]:
@@ -187,19 +330,20 @@ def decode_columnar(payload) -> tuple[np.ndarray, dict[str, Any]]:
     return ts, cols
 
 
-def validate_block(payload) -> tuple[int, int]:
+def validate_block(payload) -> tuple[int, int, bool]:
     """Bounds-check one columnar block withOUT materializing a single
-    row: header sizes vs actual bytes, column kinds, string dict
-    ranges, null-mask coverage (all via the zero-copy decode). Returns
-    (n_rows, last_ts_ms). Raises ValueError on anything malformed —
-    the ingress door (colframe.open_block) maps that to the typed
-    INVALID_ARGUMENT refusal. Empty blocks are refused: an append of
-    zero rows is a producer bug, not a no-op."""
-    ts, _cols, _nulls = decode_columnar_nulls(payload)
+    row or a single dictionary string: header sizes vs actual bytes,
+    column kinds, string dict ranges, null-mask coverage (all via the
+    zero-copy decode). Returns (n_rows, last_ts_ms, whether the
+    header's dictionaries were checked natively). Raises ValueError on
+    anything malformed — the ingress door (colframe.check_block) maps
+    that to the typed INVALID_ARGUMENT refusal. Empty blocks are
+    refused: an append of zero rows is a producer bug, not a no-op."""
+    ts, _cols, _nulls, native = _decode(payload)
     n = int(len(ts))
     if n == 0:
         raise ValueError("empty columnar block (n=0)")
-    return n, int(ts[-1])
+    return n, int(ts[-1]), native
 
 
 def to_rows(ts: np.ndarray, cols: dict,
@@ -220,7 +364,8 @@ def to_rows(ts: np.ndarray, cols: dict,
     masks = {}
     for name, (kind, arr, d) in cols.items():
         if kind == "str":
-            vals = [d[int(i)] for i in arr]
+            strs = list(d)  # a lazy dictionary's, made once
+            vals = [strs[int(i)] for i in arr]
         elif kind == "f64":
             vals = [int(v) if v.is_integer() else v
                     for v in arr.tolist()]

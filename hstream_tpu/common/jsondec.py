@@ -1,6 +1,8 @@
 """ctypes binding for the native batch record decoder (engine/cpp/
 jsondec.cpp): a whole appended batch of HStreamRecord payloads ->
-columnar arrays in one C++ pass.
+columnar arrays in one C++ pass. The same library holds the scan of a
+columnar block's header that `common/columnar.py` calls
+(`jd_header_dicts`).
 
 Feeds the server's JSON ingest (server/tasks._ingest_results): per-record
 protobuf + Struct decode in Python costs ~8us/record — at changelog
@@ -67,6 +69,11 @@ def load() -> C.CDLL | None:
         lib.jd_dict_data.argtypes = [C.c_void_p, C.c_int64, _p_u8,
                                      _p_i32]
         lib.jd_free.argtypes = [C.c_void_p]
+        # columnar block header -> its dictionaries' spans and counts
+        # (common/columnar.py); the address is a numpy view's
+        lib.jd_header_dicts.argtypes = [C.c_void_p, C.c_int64, _p_i64,
+                                        C.c_int64]
+        lib.jd_header_dicts.restype = C.c_int64
         _lib = lib
         return _lib
 

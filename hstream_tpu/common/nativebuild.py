@@ -20,7 +20,10 @@ def build_so(src: str, so: str, *, libs: tuple[str, ...] = (),
         if (not force and os.path.exists(so)
                 and os.path.getmtime(so) >= os.path.getmtime(src)):
             return so
-        tmp = so + ".tmp"
+        # a name of this process's own: test workers that find the
+        # library stale build it side by side, and each puts a whole
+        # file in place
+        tmp = f"{so}.{os.getpid()}.tmp"
         cmd = ["g++", "-std=c++17", opt, "-fPIC", "-shared", "-pthread",
                src, "-o", tmp] + [f"-l{lib}" for lib in libs]
         proc = subprocess.run(cmd, capture_output=True, text=True)

@@ -358,3 +358,153 @@ void jd_dict_data(void *h, int64_t i, uint8_t *concat, int32_t *lens) {
 void jd_free(void *h) { delete (Scan *)h; }
 
 }  // extern "C"
+
+// ---- columnar block header (common/columnar.py) -------------------------
+//
+// The header of an HSCB1 block is one JSON object whose "dicts" member
+// holds a string array a string column: tens of thousands of entries
+// that json.loads would turn into Python strings, under the GIL, to
+// learn one number, the array's length. This walks the object's first
+// level instead and, for every array under "dicts", checks that it is
+// nothing but JSON strings of printable ASCII with no backslash and
+// counts them. out gets (offset of '[', offset past ']', entries) an
+// array, in the order met; the return is their number, or -1 for a
+// header this does not recognise (the caller then parses the whole
+// header, as it always did).
+//
+// What makes the answer safe to use in place of the parse: the walk
+// keeps the state a JSON parser would have on every prefix such a
+// parser accepts (in a string or not, how deep), every span it reports
+// is a complete JSON value in a value position, and it reports either
+// every member of "dicts" or nothing. Whatever it cannot hold to that
+// without a guess is -1: bytes between tokens, a key with a backslash
+// (it could spell "dicts"), a second "dicts", a member of "dicts" that
+// is no such array, bytes after the object. Values of the other keys
+// are stepped over, not judged: the caller's parse of what is left
+// judges them.
+
+namespace {
+
+// one past the closing quote of the string whose opening quote is at
+// p, or nullptr; `plain` says no byte of the body was a backslash
+const uint8_t *skip_string(const uint8_t *p, const uint8_t *end,
+                           bool *plain) {
+    *plain = true;
+    for (++p; p < end; ++p) {
+        if (*p == '"') return p + 1;
+        if (*p == '\\') { *plain = false; ++p; }
+    }
+    return nullptr;
+}
+
+// the value that starts at p, to the ',' or the closing bracket that
+// ends it at this level
+const uint8_t *skip_value(const uint8_t *p, const uint8_t *end) {
+    int64_t depth = 0;
+    while (p < end) {
+        uint8_t c = *p;
+        if (c == '"') {
+            bool plain;
+            p = skip_string(p, end, &plain);
+            if (!p) return nullptr;
+            continue;
+        }
+        if (c == '[' || c == '{') {
+            ++depth;
+        } else if (c == ']' || c == '}') {
+            if (depth == 0) return p;
+            --depth;
+        } else if (c == ',' && depth == 0) {
+            return p;
+        }
+        ++p;
+    }
+    return nullptr;
+}
+
+// ["...","...",...] at p: printable ASCII bodies, no backslash, no
+// byte between the tokens. One past the ']' and the count, or nullptr.
+const uint8_t *plain_string_array(const uint8_t *p, const uint8_t *end,
+                                  int64_t *count) {
+    int64_t n = 0;
+    if (p >= end || *p != '[') return nullptr;
+    ++p;
+    if (p < end && *p == ']') { *count = 0; return p + 1; }
+    for (;;) {
+        if (p >= end || *p != '"') return nullptr;
+        ++p;
+        const uint8_t *q =
+            (const uint8_t *)std::memchr(p, '"', (size_t)(end - p));
+        if (!q) return nullptr;
+        uint8_t bad = 0;
+        for (const uint8_t *b = p; b < q; ++b)
+            bad |= (uint8_t)((*b < 0x20) | (*b > 0x7e) | (*b == '\\'));
+        if (bad) return nullptr;
+        ++n;
+        p = q + 1;
+        if (p >= end) return nullptr;
+        if (*p == ']') { *count = n; return p + 1; }
+        if (*p != ',') return nullptr;
+        ++p;
+    }
+}
+
+}  // namespace
+
+extern "C" {
+
+int64_t jd_header_dicts(const uint8_t *h, int64_t len, int64_t *out,
+                        int64_t cap) {
+    const uint8_t *end = h + len;
+    // '{"' first: what json.loads takes for UTF-8 without looking on
+    if (len < 4 || h[0] != '{' || h[1] != '"') return -1;
+    const uint8_t *p = h + 1;
+    int64_t k = 0;
+    bool seen = false;
+    for (;;) {
+        if (p >= end || *p != '"') return -1;
+        const uint8_t *key = p + 1;
+        bool plain;
+        p = skip_string(p, end, &plain);
+        if (!p || !plain || p >= end || *p != ':') return -1;
+        size_t klen = (size_t)(p - 1 - key);
+        ++p;
+        if (klen == 5 && std::memcmp(key, "dicts", 5) == 0) {
+            if (seen || p >= end || *p != '{') return -1;
+            seen = true;
+            ++p;
+            if (p < end && *p == '}') {
+                ++p;
+            } else {
+                for (;;) {
+                    if (p >= end || *p != '"') return -1;
+                    p = skip_string(p, end, &plain);
+                    if (!p || !plain || p >= end || *p != ':') return -1;
+                    ++p;
+                    int64_t count;
+                    const uint8_t *q = plain_string_array(p, end, &count);
+                    if (!q || k >= cap) return -1;
+                    out[3 * k] = p - h;
+                    out[3 * k + 1] = q - h;
+                    out[3 * k + 2] = count;
+                    ++k;
+                    p = q;
+                    if (p >= end) return -1;
+                    if (*p == '}') { ++p; break; }
+                    if (*p != ',') return -1;
+                    ++p;
+                }
+            }
+        } else {
+            p = skip_value(p, end);
+            if (!p) return -1;
+        }
+        if (p >= end) return -1;
+        if (*p == '}') { ++p; break; }
+        if (*p != ',') return -1;
+        ++p;
+    }
+    return p == end ? k : -1;
+}
+
+}  // extern "C"

@@ -12,6 +12,7 @@ from __future__ import annotations
 import threading
 import time
 
+from hstream_tpu.common import columnar
 from hstream_tpu.common.logger import get_logger
 from hstream_tpu.server.persistence import (
     MemPersistence,
@@ -187,6 +188,9 @@ class ServerContext:
         from hstream_tpu.server.appendfront import AppendFront
 
         self.append_front = AppendFront(store, lanes=append_lanes)
+        # the door's native header scan, built (if stale) and loaded
+        # here: no append waits for a compiler
+        columnar.load_native()
         # CAS-versioned cluster config (reference VersionedConfigStore);
         # first consumer: the boot-epoch counter below — each server
         # boot on a store CAS-increments it, so concurrent servers on

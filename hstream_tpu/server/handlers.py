@@ -466,15 +466,26 @@ class HStreamApiServicer:
         wraps: list[bytes] = []
         rows = 0
         nbytes = 0
+        scanned = 0
         for b in blocks:
-            payload, n, last_ts = colframe.open_block(b)
+            payload, n, last_ts, native = colframe.check_block(b)
             # the store sees NORMAL columnar records: one header
             # serialize + one memcpy each (no protobuf round-trip),
             # read side unchanged
             wraps.append(rec.wrap_raw_record(payload, last_ts))
             rows += n
             nbytes += len(b)
+            scanned += native
         t1 = time.perf_counter()
+        # how the headers' dictionaries were checked: by the native
+        # scan, or parsed whole (a header it does not recognise: the
+        # GIL is held for every string of every dictionary)
+        if scanned:
+            ctx.stats.stat_add("append_headers_lazy", stream,
+                               float(scanned))
+        if scanned < len(wraps):
+            ctx.stats.stat_add("append_headers_eager", stream,
+                               float(len(wraps) - scanned))
         if ctx.flow.active:
             ctx.flow.admit_append(stream, rows, nbytes)
         t2 = time.perf_counter()

@@ -1360,6 +1360,30 @@ class QueryTask(threading.Thread):
             log.warning("skipping malformed columnar record on logid %d",
                         logid)
             return
+        try:
+            self._step_columnar(ts, cols, nulls, logid)
+        finally:
+            self._note_dictionaries(cols)
+
+    def _note_dictionaries(self, cols: dict) -> None:
+        """Count the batch's string dictionaries whose strings were
+        built (`dictionaries_built`): the decode leaves each as header
+        bytes until something reads it, and a column the plan does not
+        name should never show here after the first batch."""
+        built = sum(1 for _kind, _arr, d in cols.values()
+                    if isinstance(d, columnar.LazyDictionary)
+                    and d.built)
+        stats = getattr(self.ctx, "stats", None)
+        if built and stats is not None:
+            try:
+                stats.stat_add("dictionaries_built", self.info.query_id,
+                               float(built))
+            except Exception:  # noqa: BLE001 — metrics must not kill
+                pass           # the ingest loop
+
+    def _step_columnar(self, ts, cols: dict, nulls: dict | None,
+                       logid: int) -> None:
+        """One decoded columnar batch into the executor, by its kind."""
         wait = begin_span(self.tracer, "state_wait")
         with self.state_lock:
             wait.end()

@@ -47,6 +47,18 @@ STAT_FAMILIES = [
                "read byte rate over the trailing window"),
     StatFamily("read_out_records", "stream", "records",
                "read record rate over the trailing window"),
+    # the framed append door (server/handlers.py `_append_blocks`): how
+    # each block's header had its string dictionaries checked
+    # (common/columnar.py): the second moves only for producers whose
+    # headers the native scan does not recognise (escapes, non-ASCII
+    # strings, another encoder's spacing), or with no native library
+    StatFamily("append_headers_lazy", "stream", "blocks",
+               "framed blocks whose header dictionaries the door "
+               "checked natively, building no string, over the "
+               "trailing window"),
+    StatFamily("append_headers_eager", "stream", "blocks",
+               "framed blocks whose whole header the door parsed, "
+               "dictionaries and all, over the trailing window"),
     # per-subscription delivery (reference subscription_time_series)
     StatFamily("delivered_records", "subscription", "records",
                "records delivered to consumers over the trailing "
@@ -78,6 +90,14 @@ STAT_FAMILIES = [
     StatFamily("key_misses", "query", "keys",
                "group values the key table did not know over the "
                "trailing window"),
+    # string dictionaries of the query's columnar batches whose strings
+    # were built (common/columnar.py `LazyDictionary`): a batch builds
+    # those its plan reads (a string group key, a string column of an
+    # aggregate) and the first batch all of them (the schema sample);
+    # a column no plan names never moves this
+    StatFamily("dictionaries_built", "query", "dictionaries",
+               "string dictionaries of columnar batches whose strings "
+               "the query built over the trailing window"),
     # multi-chip execution (ISSUE 16): device dispatches that ran
     # under shard_map — the rate a sharded query's fused kernels hit
     # the mesh (zero for single-chip queries)

@@ -10,6 +10,7 @@ from __future__ import annotations
 import time
 
 import numpy as np
+import pytest
 
 
 def wait_attached(ctx, query_id: str, timeout: float = 10.0):
@@ -290,3 +291,174 @@ def smoke_session_config(mesh=None):
     # warmup spans activation, the first grow, close cycles, and every
     # stacked-drain depth the steady state uses
     return ex, feed, 20
+
+
+# ---- columnar headers, read two ways (ISSUE 34) ----------------------------
+
+HEADER_BASE = 1_700_000_000_000
+
+
+def columnar_block(header: bytes, *arrays, n: int = 2,
+                   tail: bytes = b"") -> bytes:
+    """An HSCB1 payload whose header is `header`, byte for byte, over `n`
+    timestamps and `arrays` as the column (and mask) bytes."""
+    from hstream_tpu.common import columnar
+
+    ts = HEADER_BASE + np.arange(n, dtype=np.int64)
+    return (columnar.MAGIC + np.uint32(len(header)).tobytes() + header
+            + ts.tobytes()
+            + b"".join(np.ascontiguousarray(a).tobytes() for a in arrays)
+            + tail)
+
+
+def header_cases() -> list[tuple[str, bytes, bool]]:
+    """Odd and forged headers: (name, payload, whether the native scan
+    recognises the header). Most hold one string column `k` of two rows
+    with ids (0, 1); what each means is for the whole-header parse to
+    say: the decode must agree with it either way."""
+    ids = np.array([0, 1], np.int32)
+    k = b'"cols":[["k","str"]]'
+
+    def one(entries: bytes) -> bytes:
+        return b'{"n":2,' + k + b',"dicts":{"k":' + entries + b'}}'
+
+    plain = one(b'["a","b"]')
+    two_cols = b'"cols":[["j","str"],["k","str"]]'
+    many = [f"c{i}" for i in range(70)]
+    many_header = (
+        b'{"n":2,"cols":['
+        + b",".join(b'["%s","str"]' % c.encode() for c in many)
+        + b'],"dicts":{'
+        + b",".join(b'"%s":["a","b"]' % c.encode() for c in many) + b"}}")
+    cases = [
+        ("plain", columnar_block(plain, ids), True),
+        ("dicts_first", columnar_block(
+            b'{"dicts":{"k":["a","b"]},"n":2,' + k + b"}", ids), True),
+        ("one_entry", columnar_block(one(b'["a"]'), ids * 0), True),
+        ("escape_newline", columnar_block(one(b'["a\\n","b"]'), ids),
+         False),
+        ("escaped_quote", columnar_block(one(b'["a\\"b","c"]'), ids),
+         False),
+        ("u_pair", columnar_block(one(b'["\\ud83d\\ude00","b"]'), ids),
+         False),
+        ("lone_surrogate", columnar_block(one(b'["\\ud800","b"]'), ids),
+         False),
+        ("utf8", columnar_block(one('["é","b"]'.encode()), ids),
+         False),
+        ("invalid_utf8", columnar_block(one(b'["\xff","b"]'), ids),
+         False),
+        ("control_byte", columnar_block(one(b'["a\x01","b"]'), ids),
+         False),
+        ("del_byte", columnar_block(one(b'["a\x7f","b"]'), ids), False),
+        ("non_string_entry", columnar_block(one(b'["a",1]'), ids), False),
+        ("nested_array", columnar_block(one(b'["a",["b"]]'), ids), False),
+        ("space_in_array", columnar_block(one(b'["a", "b"]'), ids),
+         False),
+        ("default_separators", columnar_block(
+            b'{"n": 2, "cols": [["k", "str"]], "dicts": {"k": ["a", "b"]}}',
+            ids), False),
+        ("repeated_dicts_key", columnar_block(
+            b'{"n":2,' + k + b',"dicts":{"k":["x","y"]},'
+            b'"dicts":{"k":["a","b"]}}', ids), False),
+        ("spelled_dicts_key", columnar_block(
+            b'{"n":2,' + k + b',"d\\u0069cts":{"k":["a","b"]}}', ids),
+         False),
+        ("columns_named_like_header_keys", columnar_block(
+            b'{"n":2,"cols":[["dicts","str"],["n","str"]],'
+            b'"dicts":{"dicts":["a","b"],"n":["c","d"]}}', ids, ids), True),
+        ("brackets_in_a_name", columnar_block(
+            b'{"n":2,"cols":[["a]}","str"]],"dicts":{"a]}":["x","y"]}}',
+            ids), True),
+        ("backslash_in_a_name", columnar_block(
+            b'{"n":2,' + k + b',"dicts":{"k\\"x":["q"],"k":["a","b"]}}',
+            ids), False),
+        ("repeated_name", columnar_block(
+            b'{"n":2,' + k + b',"dicts":{"k":["x"],"k":["a","b"]}}', ids),
+         True),
+        ("truncated_array", columnar_block(
+            b'{"n":2,' + k + b',"dicts":{"k":["a","b"', ids), False),
+        ("truncated_string", columnar_block(
+            b'{"n":2,' + k + b',"dicts":{"k":["a","b', ids), False),
+        ("count_below_the_ids", columnar_block(one(b'["a"]'), ids), True),
+        ("empty_dictionary", columnar_block(one(b"[]"), ids), True),
+        ("trailing_payload_bytes", columnar_block(plain, ids, tail=b"x"),
+         True),
+        ("payload_cut_short", columnar_block(plain, ids)[:-1], True),
+        ("trailing_header_space", columnar_block(plain + b" ", ids),
+         False),
+        ("trailing_header_junk", columnar_block(plain + b"x", ids),
+         False),
+        ("missing_dict", columnar_block(
+            b'{"n":2,' + k + b',"dicts":{}}', ids), True),
+        ("no_dicts_key", columnar_block(b'{"n":2,' + k + b"}", ids),
+         True),
+        ("a_number_for_a_dictionary", columnar_block(
+            b'{"n":2,' + two_cols + b',"dicts":{"j":["a","b"],"k":0}}',
+            ids, ids), False),
+        ("header_not_an_object", columnar_block(b"[1,2]", ids), False),
+        ("empty_header", columnar_block(b"", ids), False),
+        ("utf16_header", columnar_block(
+            plain.decode().encode("utf-16"), ids), False),
+        ("utf8_bom", columnar_block(b"\xef\xbb\xbf" + plain, ids), False),
+        ("seventy_string_columns", columnar_block(
+            many_header, *([ids] * len(many))), False),
+        ("null_mask", columnar_block(
+            b'{"n":2,' + k + b',"dicts":{"k":["a","b"]},"nulls":["k"]}',
+            ids, np.array([1, 0], np.uint8)), True),
+        ("null_mask_of_no_column", columnar_block(
+            b'{"n":2,' + k + b',"dicts":{"k":["a","b"]},"nulls":["z"]}',
+            ids, np.array([1, 0], np.uint8)), True),
+        ("negative_n", columnar_block(
+            b'{"n":-1,' + k + b',"dicts":{"k":["a","b"]}}', ids), True),
+        ("float_n", columnar_block(
+            b'{"n":2.0,' + k + b',"dicts":{"k":["a","b"]}}', ids), True),
+        ("nan_n", columnar_block(
+            b'{"n":NaN,' + k + b',"dicts":{"k":["a","b"]}}', ids), True),
+        ("huge_n", columnar_block(
+            b'{"n":1000000000000,' + k + b',"dicts":{"k":["a","b"]}}',
+            ids), True),
+        ("unknown_kind", columnar_block(
+            b'{"n":2,"cols":[["k","u64"]],"dicts":{"k":["a","b"]}}', ids),
+         True),
+        ("dictionary_of_a_float_column", columnar_block(
+            b'{"n":2,"cols":[["v","f32"]],"dicts":{"v":["a"]}}',
+            np.array([1.5, 2.5], np.float32)), True),
+        ("ids_below_zero", columnar_block(plain, ids - 1), True),
+    ]
+    return cases
+
+
+def decode_outcome(payload) -> tuple:
+    """What `columnar._decode` makes of `payload`, in a form two runs
+    can be compared by: the values read (dictionaries as lists), or the
+    error's type and message (a JSON error's position left out: the
+    scan parses a shorter text); then whether the header's dictionaries
+    were checked natively (None where it raised)."""
+    from hstream_tpu.common import columnar
+
+    try:
+        ts, cols, nulls, native = columnar._decode(payload)
+    except Exception as e:  # noqa: BLE001: the outcome IS the error
+        msg = str(e)
+        if msg.startswith("bad columnar header JSON"):
+            msg = "bad columnar header JSON"
+        return ("raises", type(e).__name__, msg), None
+    return ("reads", ts.tolist(),
+            {name: (kind, arr.tolist(), None if d is None else list(d))
+             for name, (kind, arr, d) in cols.items()},
+            None if nulls is None else {name: m.tolist()
+                                        for name, m in nulls.items()}
+            ), native
+
+
+def whole_header_outcome(payload) -> tuple:
+    """`decode_outcome` with the native scan out of the way: the
+    whole-header parse, which defines what is right."""
+    from hstream_tpu.common import columnar
+
+    patch = pytest.MonkeyPatch()
+    patch.setattr(columnar, "load_native", lambda: None)
+    try:
+        return decode_outcome(payload)[0]
+    finally:
+        patch.undo()

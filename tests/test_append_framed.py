@@ -22,7 +22,8 @@ from hstream_tpu.server.appendfront import AppendFront
 from hstream_tpu.server.main import serve
 from hstream_tpu.store.memstore import MemLogStore
 
-from helpers import wait_attached, wait_watermark
+from helpers import (columnar_block, header_cases, wait_attached,
+                     wait_watermark, whole_header_outcome)
 
 BASE = 1_700_000_000_000
 
@@ -600,3 +601,141 @@ def test_framed_rows_visible_to_subscriptions(server_stub):
     (only,) = [rec.record_to_dict(rec.parse_record(r.record))
                for r in got.received_records]
     assert only == {"k": "d"}
+
+
+# ---- the door reads a header's dictionaries natively (ISSUE 34) -----------
+
+REFUSED = [(name, payload) for name, payload, _ in header_cases()
+           if whole_header_outcome(payload)[0] == "raises"]
+
+
+@pytest.mark.parametrize("name, payload", REFUSED,
+                         ids=[c[0] for c in REFUSED])
+def test_a_refused_header_stores_nothing(server_stub, name, payload):
+    """Validate-all-then-submit, whichever way a header is read: a good
+    frame in the same request as a block the whole-header parse refuses
+    is refused with it, and the log's tail does not move."""
+    stub, ctx = server_stub
+    stream = "refused_headers"
+    if not ctx.streams.stream_exists(stream):
+        stub.CreateStream(pb.Stream(stream_name=stream))
+    logid = ctx.streams.get_logid(stream)
+    tail = ctx.store.tail_lsn(logid)
+    (ts, cols), = _mk_batches(1, 8, seed=9)
+    with pytest.raises(grpc.RpcError) as ei:
+        stub.AppendColumnar(pb.AppendColumnarRequest(
+            stream_name=stream,
+            blocks=[encode_batch(ts, cols), colframe.encode_frame(payload)]))
+    assert ei.value.code() == grpc.StatusCode.INVALID_ARGUMENT
+    assert ctx.store.tail_lsn(logid) == tail
+    assert ctx.append_front.stats()["in_flight"] == 0
+
+
+def _admin_stats(stub, entity: str) -> dict:
+    import json
+
+    resp = stub.SendAdminCommand(pb.AdminCommandRequest(
+        command="stats", args=rec.dict_to_struct({"entity": entity})))
+    return json.loads(resp.result)
+
+
+def test_the_door_counts_how_each_header_was_read(server_stub):
+    """`append_headers_lazy` / `append_headers_eager`, a block: the
+    client encoder's headers are scanned natively; one whose strings
+    carry an escape is parsed whole, and both land."""
+    from hstream_tpu.common import jsondec
+    from hstream_tpu.stats.prometheus import render_metrics
+
+    if jsondec.load() is None:
+        pytest.skip("no toolchain for the native library")
+    stub, ctx = server_stub
+    stub.CreateStream(pb.Stream(stream_name="hdrcount"))
+    (ts, cols), = _mk_batches(1, 16, seed=2)
+    escaped = columnar_block(
+        b'{"n":2,"cols":[["device","str"]],'
+        b'"dicts":{"device":["tab\\there","b"]}}',
+        np.array([0, 1], np.int32))
+    r = stub.AppendColumnar(pb.AppendColumnarRequest(
+        stream_name="hdrcount",
+        blocks=[encode_batch(ts, cols), encode_batch(ts, cols),
+                colframe.encode_frame(escaped)]))
+    assert r.rows == 34
+    assert ctx.stats.stat_ladder(
+        "append_headers_lazy", "hdrcount")["total"] == 2
+    assert ctx.stats.stat_ladder(
+        "append_headers_eager", "hdrcount")["total"] == 1
+    row = _admin_stats(stub, "streams")["hdrcount"]
+    assert (row["append_headers_lazy_total"],
+            row["append_headers_eager_total"]) == (2, 1)
+    text = render_metrics(ctx)
+    assert 'hstream_append_headers_lazy_rate{stream="hdrcount"}' in text
+    assert 'hstream_append_headers_eager_rate{stream="hdrcount"}' in text
+
+
+@pytest.mark.parametrize("kind, select", [
+    ("session", "SELECT bidder, COUNT(*) AS bids FROM {src} "
+                "GROUP BY bidder, SESSION (INTERVAL 10 SECOND) "
+                "GRACE BY INTERVAL 0 SECOND"),
+    ("qualify", "SELECT auction, COUNT(*) AS num FROM {src} "
+                "GROUP BY auction, HOPPING (INTERVAL 10 SECOND, "
+                "INTERVAL 2 SECOND) GRACE BY INTERVAL 0 SECOND "
+                "QUALIFY COUNT(*) >= MAX(COUNT(*)) "
+                "OVER (PARTITION BY winStart, winEnd)"),
+], ids=["session", "qualify"])
+def test_a_string_column_no_plan_reads_is_built_once_for_the_schema(
+        server_stub, kind, select):
+    """NEXmark's bids carry `extra`, a string a row that neither query
+    names: the first batch builds its dictionary (the schema sample
+    reads every column), no later batch does, and the door builds none.
+    `dictionaries_built` is the count, in `admin stats queries`."""
+    from hstream_tpu.common import jsondec
+
+    if jsondec.load() is None:
+        pytest.skip("no toolchain for the native library")
+    stub, ctx = server_stub
+    src, view = f"bids_{kind}", f"unread_{kind}"
+    stub.CreateStream(pb.Stream(stream_name=src))
+    stub.ExecuteQuery(pb.CommandQuery(
+        stmt_text=f"CREATE VIEW {view} AS {select.format(src=src)};"))
+    task = wait_attached(ctx, f"view-{view}")
+    qid = task.info.query_id
+    rng = np.random.default_rng(5)
+    n, sent = 64, 0
+
+    def total(family: str) -> int:
+        return int(ctx.stats.stat_ladder(family, qid)["total"])
+
+    def built() -> int:
+        return total("dictionaries_built")
+
+    def send(i: int) -> None:
+        nonlocal sent
+        ts = BASE + i * 1500 + np.sort(rng.integers(0, 1500, n)) \
+            .astype(np.int64)
+        cols = {"auction": rng.integers(1000, 1010, n),
+                "bidder": rng.integers(1, 20, n),
+                "extra": [f"x{i}-{j}" for j in range(n)]}
+        stub.AppendColumnar(pb.AppendColumnarRequest(
+            stream_name=src, blocks=[encode_batch(ts, cols)]))
+        sent += n
+        deadline = time.time() + 60
+        while total("consumed_events") < sent:
+            assert time.time() < deadline and task.error is None
+            time.sleep(0.01)
+
+    send(0)
+    deadline = time.time() + 30
+    while built() < 1 and time.time() < deadline:
+        time.sleep(0.01)
+    assert built() == 1          # `extra`, for the schema sample
+    for i in range(1, 6):
+        send(i)
+    # the task is one thread: with batch 5 counted as consumed, batches
+    # 1-4 have been stepped and their dictionaries counted
+    assert built() == 1
+    assert ctx.stats.stat_ladder(
+        "append_headers_lazy", src)["total"] == 6
+    assert ctx.stats.stat_ladder(
+        "append_headers_eager", src)["total"] == 0
+    assert _admin_stats(stub, "queries")[qid][
+        "dictionaries_built_total"] == 1

@@ -2,19 +2,29 @@
 (common/columnar.py); query tasks feed it straight into the lattice
 (tasks._run_columnar) — the server-side product fast path."""
 
+import json
+import logging
+import os
+import sys
 import time
 
 import grpc
 import numpy as np
 import pytest
 
-from hstream_tpu.common import columnar
+from hstream_tpu.common import colframe, columnar, jsondec
 from hstream_tpu.common import records as rec
+from hstream_tpu.common.errors import InvalidFrame
 from hstream_tpu.proto import api_pb2 as pb
 from hstream_tpu.proto.rpc import HStreamApiStub
 from hstream_tpu.server.main import serve
 
-from helpers import wait_attached
+from helpers import (columnar_block, decode_outcome, header_cases,
+                     wait_attached, whole_header_outcome)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
 
 BASE = 1_700_000_000_000
 
@@ -231,3 +241,255 @@ def test_columnar_numeric_group_key(server_stub):
         lambda rs: len([r for r in rs if r.get("winStart") == BASE]) >= 3)
     got = {r["sensor"]: r["c"] for r in rows if r.get("winStart") == BASE}
     assert got == {1: 3, 2: 2, 3: 1}, rows
+
+
+# ---- a header's dictionaries: the native scan against the whole-header
+# ---- parse, which defines what is right (ISSUE 34) -------------------------
+
+@pytest.fixture
+def native():
+    if jsondec.load() is None:
+        pytest.skip("no toolchain for the native library")
+
+
+def _scan_recognises(payload: bytes) -> bool:
+    """Whether `jd_header_dicts` itself takes the payload's header."""
+    off = len(columnar.MAGIC) + 4
+    hlen = int(np.frombuffer(payload, np.uint32, 1, off - 4)[0])
+    return columnar._dictionary_spans(
+        memoryview(payload)[off: off + hlen]) is not None
+
+
+def _generator_frame(config: str) -> bytes:
+    """Frame 3 of a benchmark configuration at its dry sizes, as the
+    benchmark's producer encodes it; the block inside the frame."""
+    from benchmarks.harness import manifest, producer
+
+    cfg = manifest.load_json("configs", config + ".json")
+    size = manifest.size_of(cfg, True)
+    _stream, ts, cols, _n = manifest.generator_of(cfg).frame(size, 7, 3)
+    return bytes(colframe.open_frame(producer.encode_frame(ts, cols)))
+
+
+def _shapes() -> dict:
+    n = 6
+    ts = BASE + np.arange(n, dtype=np.int64)
+    names = [f"d{i % 3}" for i in range(n)]
+    return {
+        "nexmark_bids": lambda: _generator_frame("nexmark_q5"),
+        "sensor": lambda: _generator_frame("sensor_hll_100k"),
+        "null_masks": lambda: columnar.encode_columnar(
+            ts, {"k": names, "v": np.arange(n, dtype=np.float32)},
+            nulls={"k": np.arange(n) % 2 == 0, "v": np.arange(n) == 1}),
+        "no_rows_no_entries": lambda: columnar.encode_columnar(
+            ts[:0], {"k": np.array([], "U1")}),
+        "one_entry": lambda: columnar.encode_columnar(
+            ts, {"k": ["only"] * n}),
+        "three_string_columns": lambda: columnar.encode_columnar(
+            ts, {"a": names, "x": np.arange(n), "b": names[::-1],
+                 "c": [f"{i} {{[,]}} :" for i in range(n)]}),
+        "empty_strings": lambda: columnar.encode_columnar(
+            ts, {"k": ["", "a", ""] * 2, "e": [""] * n}),
+        "no_string_column": lambda: columnar.encode_columnar(
+            ts, {"x": np.arange(n), "ok": np.arange(n) % 2 == 0}),
+    }
+
+
+@pytest.mark.parametrize("shape", list(_shapes()))
+def test_the_scan_takes_the_encoders_frames_and_builds_no_string(
+        native, shape):
+    payload = _shapes()[shape]()
+    ts, cols, nulls, took = columnar._decode(payload)
+    assert took
+    dicts = [d for kind, _arr, d in cols.values() if kind == "str"]
+    assert all(isinstance(d, columnar.LazyDictionary) and not d.built
+               for d in dicts)
+    want = whole_header_outcome(payload)
+    assert want[0] == "reads"
+    # lengths are known without a string being built
+    assert [len(d) for d in dicts] == [
+        len(d) for _k, _a, d in want[2].values() if d is not None]
+    assert not any(d.built for d in dicts)
+    assert decode_outcome(payload)[0] == want
+    assert columnar.decode_columnar_nulls(payload)[1].keys() \
+        == want[2].keys()
+
+
+CASES = header_cases()
+
+
+@pytest.mark.parametrize("name, payload, recognised", CASES,
+                         ids=[c[0] for c in CASES])
+def test_an_odd_header_reads_or_fails_as_the_whole_parse_says(
+        native, name, payload, recognised):
+    got, took = decode_outcome(payload)
+    assert got == whole_header_outcome(payload)
+    assert _scan_recognises(payload) == recognised
+    if took is not None:
+        assert took == recognised
+
+
+@pytest.mark.parametrize("name, payload, recognised", CASES,
+                         ids=[c[0] for c in CASES])
+def test_the_door_refuses_what_the_whole_parse_refuses(
+        native, name, payload, recognised):
+    """`check_block` is the door: a block the whole-header parse reads
+    passes with its rows and last timestamp, every other one is the
+    typed refusal, whichever way its header was read."""
+    want = whole_header_outcome(payload)
+    frame = colframe.encode_frame(payload)
+    if want[0] == "reads":
+        _view, n, last_ts, took = colframe.check_block(frame)
+        assert (n, last_ts) == (len(want[1]), want[1][-1])
+        assert took == recognised
+        assert colframe.open_block(frame)[1:] == (n, last_ts)
+    else:
+        with pytest.raises(InvalidFrame, match="bad columnar block"):
+            colframe.check_block(frame)
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_mutated_headers_read_or_fail_as_the_whole_parse_says(native,
+                                                              seed):
+    """Headers of the client's encoder with a few bytes changed, dropped
+    or put in from the bytes JSON is made of: whatever comes of it, both
+    ways of reading the header say the same."""
+    rng = np.random.default_rng(seed)
+    ids = np.array([0, 1], np.int32)
+    base = (b'{"n":2,"cols":[["k","str"],["j","str"]],'
+            b'"dicts":{"k":["ab","c d"],"j":["e","f","g"]},"nulls":[]}')
+    alphabet = b'"\\[]{},: a0\x00\xc3\x7f'
+    took_both = 0
+    for _ in range(400):
+        hdr = bytearray(base)
+        for _ in range(int(rng.integers(1, 4))):
+            at = int(rng.integers(0, len(hdr)))
+            how = int(rng.integers(0, 3))
+            byte = alphabet[int(rng.integers(0, len(alphabet)))]
+            if how == 0:
+                hdr[at] = byte
+            elif how == 1:
+                del hdr[at]
+            else:
+                hdr.insert(at, byte)
+        payload = columnar_block(bytes(hdr), ids, ids)
+        got, took = decode_outcome(payload)
+        assert got == whole_header_outcome(payload), bytes(hdr)
+        took_both += bool(took)
+    assert took_both  # some mutants are still the encoder's form
+
+
+def test_without_the_library_the_whole_header_is_parsed_and_said_once(
+        monkeypatch):
+    monkeypatch.setattr(jsondec, "load", lambda: None)
+    monkeypatch.setattr(columnar, "_warned", False)
+    said = []
+    handler = logging.Handler(logging.WARNING)
+    handler.emit = said.append
+    columnar.log.addHandler(handler)
+    payload = columnar.encode_columnar(
+        BASE + np.arange(4, dtype=np.int64),
+        {"k": ["a", "b", "a", "c"], "v": np.arange(4, dtype=np.float32)},
+        nulls={"v": np.arange(4) == 2})
+    try:
+        first = columnar._decode(payload)
+        second = columnar._decode(payload)
+        n, last_ts, took = columnar.validate_block(payload)
+    finally:
+        columnar.log.removeHandler(handler)
+    assert [r.levelname for r in said] == ["WARNING"]
+    assert "header scan" in said[0].getMessage()
+    assert not first[3] and not second[3] and not took
+    assert (n, last_ts) == (4, BASE + 3)
+    assert first[1]["k"][2] == ["a", "b", "c"]
+    assert type(first[1]["k"][2]) is list
+    assert first[2]["v"].tolist() == [False, False, True, False]
+
+
+def test_a_lazy_dictionary_is_a_sequence_that_parses_once(native):
+    payload = columnar.encode_columnar(
+        BASE + np.arange(3, dtype=np.int64), {"k": ["b", "a", "c"]})
+    d = columnar.decode_columnar_nulls(payload)[1]["k"][2]
+    assert isinstance(d, columnar.LazyDictionary)
+    assert (len(d), bool(d), d.built) == (3, True, False)
+    assert d[1] == "b" and d.built
+    assert d.strings() is d.strings()
+    assert list(d) == ["a", "b", "c"] == list(reversed(list(d)[::-1]))
+    assert d == ["a", "b", "c"] and d != ["a", "b"] and "c" in d
+    assert d.index("c") == 2 and d[-1] == "c" and d[1:] == ["b", "c"]
+    assert np.asarray(d).tolist() == ["a", "b", "c"]
+    assert np.asarray(d, object)[np.array([2, 0])].tolist() == ["c", "a"]
+    assert json.dumps(list(d)) == '["a", "b", "c"]'
+
+
+def _consumers() -> dict:
+    from hstream_tpu.server import subscriptions, tasks
+    from test_key_table import _executor
+
+    def key_ids(ts, cols, nulls):
+        ex = _executor()
+        ids, named = tasks._dictionary_key_ids(ex, cols, len(ts), nulls)
+        return ids.tolist(), named.tolist(), list(ex._key_rev)
+
+    def general_key_ids(ts, cols, nulls):
+        ex = _executor()
+        # a dictionary larger than the batch takes the general path
+        ids = tasks._columnar_key_ids(ex, cols, len(ts), nulls=nulls)
+        return ids.tolist(), list(ex._key_rev)
+
+    return {
+        "to_rows": lambda ts, cols, nulls: columnar.to_rows(
+            ts, cols, nulls, drop_null=True),
+        "to_rows_nones": lambda ts, cols, nulls: columnar.to_rows(
+            ts, cols, nulls),
+        "sample_rows": lambda ts, cols, nulls: tasks._sample_rows(
+            ts, cols, nulls),
+        "plain_columns": lambda ts, cols, nulls: {
+            k: v.tolist() for k, v in tasks._plain_columns(cols).items()},
+        "session_columns": lambda ts, cols, nulls: {
+            k: v.tolist() for k, v in tasks._session_columns(
+                cols, frozenset({"device"})).items()},
+        "dictionary_key_ids": key_ids,
+        "columnar_key_ids": general_key_ids,
+    }
+
+
+@pytest.mark.parametrize("consumer", list(_consumers()))
+def test_every_consumer_reads_a_lazy_dictionary_as_it_read_the_list(
+        native, monkeypatch, consumer):
+    n = 12
+    ts = BASE + np.arange(n, dtype=np.int64)
+    payload = columnar.encode_columnar(
+        ts, {"device": [f"dev-{i % 5}" for i in range(n)],
+             "v": np.arange(n, dtype=np.float32),
+             "note": [f"n{i}" for i in range(n)]},
+        nulls={"device": np.arange(n) == 4})
+    fn = _consumers()[consumer]
+    lazy = columnar.decode_columnar_nulls(payload)
+    assert isinstance(lazy[1]["device"][2], columnar.LazyDictionary)
+    got = fn(*lazy)
+    monkeypatch.setattr(columnar, "load_native", lambda: None)
+    whole = columnar.decode_columnar_nulls(payload)
+    assert type(whole[1]["device"][2]) is list
+    assert got == fn(*whole)
+    if consumer == "session_columns":
+        # the plan names `device` alone: `note` is never built
+        assert lazy[1]["device"][2].built
+        assert not lazy[1]["note"][2].built
+
+
+def test_a_subscription_expands_a_lazily_read_record_as_before(
+        native, monkeypatch):
+    from hstream_tpu.server.subscriptions import _expand_columnar
+
+    ts = BASE + np.arange(3, dtype=np.int64)
+    record = rec.build_columnar_record(
+        ts, {"k": ["a", "b", "c"], "v": np.arange(3, dtype=np.float32)}
+    ).SerializeToString()
+    got = _expand_columnar(record)
+    monkeypatch.setattr(columnar, "load_native", lambda: None)
+    assert got == _expand_columnar(record)
+    assert [rec.record_to_dict(rec.parse_record(r))["k"] for r in got] \
+        == ["a", "b", "c"]
+    assert columnar.payload_rows(
+        rec.parse_record(record).payload)[1] == {"k": "b", "v": 1}

@@ -239,6 +239,11 @@ def _golden_holder() -> StatsHolder:
         stats.stream_stat_add(name, "q1", v)
     stats.gauge_set("keys_live", "q1", 600)
     stats.gauge_set("key_capacity", "q1", 1024)
+    # how the append door read its blocks' headers, and the string
+    # dictionaries a query built (ISSUE 34)
+    stats.stat_add("append_headers_lazy", "s1", 5.0, now=BASE / 1000)
+    stats.stat_add("append_headers_eager", "s1", 1.0, now=BASE / 1000)
+    stats.stat_add("dictionaries_built", "q1", 2.0, now=BASE / 1000)
     return stats
 
 
