@@ -249,6 +249,14 @@ TRACE_STAGES = frozenset({
     "session_key_codes", "session_mirror", "session_pack",
     "session_close", "session_close_fetch", "session_close_decode",
     "session_remap",
+    # the device join inside `step` (engine/join.py): the join-key
+    # dictionary and the batch's sort, the host shadow that sizes the
+    # match buffer, packing the batch, the wait for the device's match
+    # count (fused window join) or match buffer, decoding that buffer,
+    # and a window join's eviction of closed windows with the codes'
+    # reclamation (`dispatch:join`, `dispatch:join_evict` nest there)
+    "join_key_codes", "join_shadow", "join_pack", "join_fetch",
+    "join_decode", "join_evict",
     # a pull, on its gRPC thread: asking for tasks.state -> holding it
     # -> released, then filter/project/sort outside the lock
     "pull_state_wait", "pull_hold", "pull_serve",
@@ -274,12 +282,16 @@ TRACE_PARENT = {
     "session_close_fetch": "session_close",
     "session_close_decode": "session_close",
     "session_remap": "session_key_codes",
+    "join_key_codes": "step", "join_shadow": "step", "join_pack": "step",
+    "join_fetch": "step", "join_decode": "step", "join_evict": "step",
 }
 
 # kernel dispatch families (per-family dispatch histograms + recompile
 # attribution) — also cross-checked by the analyzer registry pass.
 # `peek` is the read plane's batched extract, on a pull's thread.
-KERNEL_FAMILIES = frozenset({"step", "close", "probe", "session", "peek"})
+# `join` / `join_evict`: a window join's fused step and its eviction.
+KERNEL_FAMILIES = frozenset({"step", "close", "probe", "session", "peek",
+                             "join", "join_evict"})
 
 
 def new_span_id() -> str:

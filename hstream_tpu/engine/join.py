@@ -1,4 +1,4 @@
-"""Stream-stream interval JOIN execution.
+"""Stream-stream JOIN execution: interval joins and window joins.
 
 Reference semantics (hstream-processing Stream.hs:222-300 /
 joinStreamProcessor): each record is inserted into its side's
@@ -29,6 +29,13 @@ Design: two execution paths with identical semantics.
 
 Join state is pruned by within + downstream grace, bounding memory
 where the reference's in-memory store grows forever.
+
+A WINDOW join (`JOIN ... WITHIN WINDOW`: a pair joins where both records
+fall in the same window of the statement's tumbling window) runs on the
+same two paths, stores and fused step; `JoinExecutor.__init__` lists
+what differs: the probe's bounds, event time as the minimum over the
+sources, eviction by closed windows with the join codes' reclamation,
+and inner key ids that are dated and retire.
 """
 
 from __future__ import annotations
@@ -36,16 +43,19 @@ from __future__ import annotations
 from dataclasses import replace
 from typing import Any, Mapping, Sequence
 
+import contextlib
+from collections import deque
+
 import numpy as np
 
 from hstream_tpu.common.errors import SQLCodegenError
 from hstream_tpu.common.logger import get_logger
-from hstream_tpu.common.tracing import kernel_family
+from hstream_tpu.common.tracing import kernel_family, trace_span
 from hstream_tpu.engine.expr import BinOp, Col, Expr, eval_host
 from hstream_tpu.engine.plan import AggregateNode
 from hstream_tpu.engine.statestore import LastValueStore
-from hstream_tpu.engine.types import canon_key, round_up_pow2
-from hstream_tpu.engine.window import DEFAULT_GRACE_MS
+from hstream_tpu.engine.types import ColumnType, canon_key, round_up_pow2
+from hstream_tpu.engine.window import DEFAULT_GRACE_MS, TumblingWindow
 
 log = get_logger("join")
 
@@ -161,6 +171,7 @@ class _JoinBase:
         self._inner = None
         self._inner_plan = replace(plan, join=None)
         self._initial_keys = initial_keys
+        self._inner_keys_floor = 0  # (a window join starts it higher)
         self._batch_capacity = batch_capacity
         # deferred-change tuning proxied onto the (lazily created) inner
         # executor, so the server's _tune_executor and bench harnesses
@@ -216,7 +227,8 @@ class _JoinBase:
 
             self._inner = make_executor(
                 self._inner_plan, sample_rows=joined,
-                initial_keys=self._initial_keys,
+                initial_keys=max(self._initial_keys,
+                                 self._inner_keys_floor),
                 batch_capacity=self._batch_capacity,
                 mesh=self.mesh)
             self._apply_inner_tuning()
@@ -516,20 +528,67 @@ class JoinExecutor(_JoinBase):
                          batch_capacity=batch_capacity, mesh=mesh,
                          data_axis=data_axis, key_axis=key_axis)
         join = plan.join
-        self.within = join.within.ms
+        node = plan.node
+        # a window join (JOIN ... WITHIN WINDOW): a pair joins where
+        # both records fall in the same window of the statement's own
+        # tumbling window. The same stores, kernels and fused step as
+        # the interval join; what differs is stated where it does:
+        #   * a probe spans its record's window [start, start + size),
+        #     and `within` (the kernels' operand) is that size;
+        #   * event time is the MINIMUM over both sources' progress
+        #     (`_src_hi`): the probe mask, the eviction and the inner
+        #     executor's watermark follow it, so a batch is never lost
+        #     because the other log was read further;
+        #   * eviction drops whole closed windows from both stores at
+        #     every close and frees the join-key codes no stored row
+        #     names any more (`_close_join_windows`);
+        #   * the GROUP BY key may hold, beside the join key, further
+        #     columns of the same side that the join key determines
+        #     (`_window_kids`); inner key ids are dated like any other
+        #     query's, so the inner executor retires them.
+        self.window_join = bool(getattr(join, "window", False))
+        if self.window_join:
+            if not (isinstance(node, AggregateNode)
+                    and isinstance(node.window, TumblingWindow)):
+                raise SQLCodegenError(
+                    "JOIN ... WITHIN WINDOW needs a TUMBLING GROUP BY "
+                    "window")
+            if self.emit_changes:
+                raise SQLCodegenError(
+                    "JOIN ... WITHIN WINDOW with EMIT CHANGES is not "
+                    "supported")
+            self.within = node.window.size_ms
+            self.mesh = None  # single-chip (plan.single_chip_reason)
+            # the inner key table starts where a window's keys would
+            # take it anyway: every doubling on the way is a rebuild of
+            # the fused step (the table's size is part of its program),
+            # and 2^18 rows of a count plane are three megabytes
+            self._inner_keys_floor = self.WINDOW_INNER_KEYS
+        else:
+            self.within = join.within.ms
 
         # retention: a future in-grace record can probe back `within`;
         # grace defaults to the downstream window's (or the SQL default)
-        node = plan.node
         grace = DEFAULT_GRACE_MS
         if isinstance(node, AggregateNode) and node.window is not None:
             grace = node.window.grace_ms
+        self._grace_ms = grace
         self.retention_ms = self.within + grace
 
         # shared join-key code space across both sides
         self._jcode: dict[tuple, int] = {}
         self._jcode_rev: list[tuple] = []
         self._kid_lut = np.full(1024, -1, np.int32)  # code -> inner key id
+        # window join: free codes (reused before new ones are minted),
+        # the newest window each code's inner key id was named in (the
+        # id is good while that window is open), each source's newest
+        # event time, and the start of the oldest window still open
+        self._jcode_free: list[int] = []
+        self._kid_win = np.full(1024, -1, np.int64)
+        self._src_hi = {"l": -1, "r": -1}
+        self._open_from: int | None = None
+        self.tracer = None  # QueryTracer of the owning task (spans)
+        self._input_cols: dict | None = None  # input_columns' memo
         self._stores = {"l": _FlatIntervalStore(self._jcode_rev),
                         "r": _FlatIntervalStore(self._jcode_rev)}
         self.watermark: int = -1
@@ -558,6 +617,7 @@ class JoinExecutor(_JoinBase):
         # the barrier). The fused close's deferred-fetch idiom.
         self.match_drain_depth = 1
         self._pending_matches: list[tuple] = []
+        self._inflight: deque = deque()  # fused window-join batches
         # probe-path dispatch accounting: the device-join contract is
         # ONE probe dispatch per micro-batch (and fetches <= batches);
         # tests and bench assert probe_dispatches == probe_batches
@@ -566,6 +626,11 @@ class JoinExecutor(_JoinBase):
             "probe_fetches": 0, "match_redispatches": 0,
             "evict_dispatches": 0, "rebase_dispatches": 0,
             "store_grows": 0, "fused_batches": 0,
+            # window join: rows that arrived behind the eviction bound
+            # (their window had closed: 0 is a deployment's guarantee),
+            # join-key codes freed at a close, matched pairs
+            "rows_past_retention": 0, "codes_reclaimed": 0,
+            "matches": 0,
         }
         # device activations that failed and degraded (permanently, for
         # this executor) to the retained host reference path; the query
@@ -574,6 +639,15 @@ class JoinExecutor(_JoinBase):
         # dispatches that ran under shard_map (probe/fused/evict); the
         # query task mirrors deltas into the sharded_dispatches family
         self._sharded_dispatches = 0
+
+    @property
+    def late_drops(self) -> int:
+        """Rows this executor itself dropped as late: a window join's
+        rows behind its eviction bound (`rows_past_retention`; their
+        window had closed). The task folds it with the inner
+        aggregate's count into `late_drops` (`engine_total`), so a
+        deployment that holds `late_drops` to 0 holds this too."""
+        return self.join_stats["rows_past_retention"]
 
     @property
     def sharded_dispatches(self) -> int:
@@ -648,12 +722,23 @@ class JoinExecutor(_JoinBase):
             else:
                 kidx = None
                 bts = ts
+            if self.window_join and len(codes):
+                fresh = self._in_open_windows(bts)
+                if fresh is not None:
+                    codes, bts = codes[fresh], bts[fresh]
+                    kidx = (np.nonzero(fresh)[0] if kidx is None
+                            else kidx[fresh])
             if len(codes):
                 order = np.lexsort((bts, codes))
                 codes = codes[order]
                 bts = bts[order]
                 ridx = order if kidx is None else kidx[order]
-                if self._device_ready():
+                ready = self._device_ready()
+                if self.window_join and self._fast_info() is not None:
+                    self._window_kids(
+                        side, codes, bts,
+                        lambda c: [rows[j].get(c) for j in ridx.tolist()])
+                if ready:
                     lay = self._dev["lay"][side]
                     flags, vals = self._encode_join_cols(
                         lay, [rows[j] for j in ridx.tolist()])
@@ -662,9 +747,8 @@ class JoinExecutor(_JoinBase):
                 else:
                     out = self._host_batch(side, mine, other, codes,
                                            bts, rows, ridx)
-        self._advance_watermark(max((int(t) for t in ts_ms),
-                                    default=self.watermark))
-        return out
+        return self._after_batch(
+            side, out, max((int(t) for t in ts_ms), default=-1))
 
     def process_columnar(self, ts_ms, cols: Mapping[str, np.ndarray],
                          nulls: Mapping[str, np.ndarray] | None = None,
@@ -689,17 +773,46 @@ class JoinExecutor(_JoinBase):
         if self._device_ready():
             my_keys = (self.left_keys if side == "l"
                        else self.right_keys)
-            enc = self._columnar_batch(side, my_keys, ts, cols, nulls)
+            with trace_span(self.tracer, "join_key_codes"):
+                enc = self._columnar_batch(side, my_keys, ts, cols,
+                                           nulls)
         if enc is not None:
             codes, bts, flags, vals = enc
             if len(codes):
                 out = self._device_batch(side, codes, bts, flags, vals)
-            self._advance_watermark(int(ts.max()))
-            return out
+            return self._after_batch(side, out, int(ts.max()))
         # fallback: materialize rows once (pre-activation, non-Col ON
         # keys, or untyped columns) and run the row path
         return self.process(self._rows_from_cols(cols, nulls, n),
                             ts.tolist(), stream=stream)
+
+    def _after_batch(self, side: str, out, batch_hi: int):
+        """A batch is done: event time moves, and with it retention.
+        A window join also closes what its new event time closes (the
+        stores' windows here, the inner executor's after them) and
+        gives those rows behind the batch's own."""
+        if batch_hi < 0:
+            return out
+        if not self.window_join:
+            self._advance_watermark(batch_hi)
+            return out
+        from hstream_tpu.common.columnar import extend_rows
+
+        if batch_hi > self._src_hi[side]:
+            self._src_hi[side] = batch_hi
+        new_wm = min(self._src_hi.values())
+        if new_wm <= self.watermark:
+            return out
+        self.watermark = new_wm
+        self._close_join_windows()
+        inner = self._inner
+        if inner is not None and getattr(inner, "watermark_abs",
+                                         None) is not None:
+            if self.watermark > inner.watermark_abs:
+                inner.watermark_abs = self.watermark
+            out = extend_rows(out if out else None,
+                              inner.close_due_windows())
+        return out if out is not None else []
 
     def _advance_watermark(self, new_wm: int) -> None:
         if new_wm <= self.watermark:
@@ -713,6 +826,201 @@ class JoinExecutor(_JoinBase):
                 self._stores["l"].prune(cutoff)
                 self._stores["r"].prune(cutoff)
 
+    # ---- window join: event time, retention, keys --------------------------
+
+    def _cutoff_abs(self) -> int | None:
+        """The retention bound in absolute ms: a stored row older than
+        it is invisible to probes and due for eviction. Interval join:
+        `watermark - retention`. Window join: the start of the oldest
+        window still open at the watermark (the minimum over both
+        sources), so whole closed windows go. None before event time
+        has a value."""
+        if self.watermark < 0:
+            return None
+        if not self.window_join:
+            return self.watermark - self.retention_ms
+        t = self.watermark - self._grace_ms
+        return t - t % self.within
+
+    def _in_open_windows(self, bts: np.ndarray) -> np.ndarray | None:
+        """Window join: which rows of a batch lie in a window still
+        open (None: all of them). A row behind the eviction bound has
+        no window to join in: it is counted (`rows_past_retention`)
+        and goes no further, as the inner executor would drop the
+        pairs it made as late."""
+        cutoff = self._cutoff_abs()
+        if cutoff is None or int(bts.min()) >= cutoff:
+            return None
+        fresh = bts >= cutoff
+        self.join_stats["rows_past_retention"] += int(
+            len(bts) - np.count_nonzero(fresh))
+        return fresh
+
+    def _grow_code_luts(self) -> None:
+        n = len(self._jcode_rev)
+        if len(self._kid_lut) < n:
+            size = max(n, 2 * len(self._kid_lut))
+            lut = np.full(size, -1, np.int32)
+            lut[:len(self._kid_lut)] = self._kid_lut
+            self._kid_lut = lut
+        if self.window_join and len(self._kid_win) < len(self._kid_lut):
+            win = np.full(len(self._kid_lut), -1, np.int64)
+            win[:len(self._kid_win)] = self._kid_win
+            self._kid_win = win
+
+    def _window_kids(self, side: str, codes: np.ndarray,
+                     bts: np.ndarray, column) -> None:
+        """Window join, fast path: the inner key ids of a batch of the
+        side that carries the GROUP BY columns (the `det` side: the
+        join key and whatever further columns of that side the join
+        key determines). New codes get their ids in one piece
+        (`key_ids_for`), every id the batch names is dated
+        (`note_key_use`), so the inner executor retires it once its
+        window has closed; `_kid_win` says up to which window a code's
+        id is good. A row whose GROUP BY columns differ from its
+        code's key breaks the dependency the statement rests on and is
+        refused by name. `column(name)` gives a source column of the
+        batch in the order of `codes`."""
+        self._grow_code_luts()
+        det_side, det_cols = self._fast["det"]
+        if side != det_side:
+            return
+        inner = self._inner
+        lut = self._kid_lut
+        vals = [np.asarray(column(c), object) for c in det_cols]
+        kid = lut[codes]
+        new = np.flatnonzero(kid < 0)
+        if len(new):
+            ucodes, first = np.unique(codes[new], return_index=True)
+            at = new[first]
+            keys = list(zip(*(v[at].tolist() for v in vals)))
+            lut[ucodes] = inner.key_ids_for(keys)
+            kid = lut[codes]
+        for g, (name, v) in enumerate(zip(det_cols, vals)):
+            have = inner._key_cols[g][kid]
+            if not (np.array_equal(have, v) or have.tolist() == [
+                    canon_key((x,))[0] for x in v.tolist()]):
+                raise SQLCodegenError(
+                    f"window join: GROUP BY column {name!r} is not "
+                    "determined by the join key (two records of one "
+                    "join key differ in it)")
+        win = bts - bts % self.within
+        np.maximum.at(self._kid_win, codes, win)
+        inner.note_key_use(kid, int(bts.max()))
+
+    def _close_join_windows(self) -> None:
+        """Window join: event time has moved. Once it opens a new
+        window, every older one is closed for good: drop their rows
+        from both stores (one eviction dispatch on the device path),
+        forget the inner key ids that were good for them, and free the
+        join-key codes no stored row names any more, so the code
+        dictionary, `_kid_lut` and the stores are bounded by the open
+        windows."""
+        cutoff = self._cutoff_abs()
+        if cutoff is None or (self._open_from is not None
+                              and cutoff <= self._open_from):
+            return
+        self._open_from = cutoff
+        with trace_span(self.tracer, "join_evict"):
+            dev = self._dev
+            if dev is not None:
+                for st in dev["shadow"].values():
+                    st.prune(cutoff)
+                if dev["t0"] is not None and (dev["n"]["l"]
+                                              or dev["n"]["r"]):
+                    self._dispatch_evict(cutoff, 0)
+                stores = dev["shadow"]
+            else:
+                for st in self._stores.values():
+                    st.prune(cutoff)
+                stores = self._stores
+            self._grow_code_luts()
+            n = len(self._jcode_rev)
+            stale = np.flatnonzero(self._kid_win[:n] < cutoff)
+            self._kid_lut[stale] = -1
+            live = np.zeros(n, np.bool_)
+            for st in stores.values():
+                live[st.code] = True
+            rev = self._jcode_rev
+            dead = [c for c in np.flatnonzero(~live).tolist()
+                    if rev[c] is not None]
+            if dead:
+                jcode = self._jcode
+                for c in dead:
+                    del jcode[rev[c]]
+                    rev[c] = None
+                self._kid_win[dead] = -1
+                self._jcode_free.extend(reversed(dead))
+                self.join_stats["codes_reclaimed"] += len(dead)
+
+    def _mint_code(self, key) -> int:
+        """A code for a join key the dictionary does not hold: a freed
+        one first (a window join frees them at every close)."""
+        rev = self._jcode_rev
+        if self._jcode_free:
+            c = self._jcode_free.pop()
+            rev[c] = key
+        else:
+            c = len(rev)
+            rev.append(key)
+        self._jcode[key] = c
+        return c
+
+    def input_columns(self, stream: str) -> frozenset | None:
+        """The columns of `stream`'s records this join reads: its ON
+        keys and what the plan above it names (GROUP BY, aggregate
+        arguments, WHERE); a bare name may be either side's. None
+        where that cannot be said from the plan (a stateless plan
+        forwards whole records, a key that is an expression)."""
+        if self._input_cols is None:
+            self._input_cols = self._plan_columns() or {}
+        return self._input_cols.get(self._side_of(stream))
+
+    def _plan_columns(self) -> dict | None:
+        from hstream_tpu.engine.expr import columns_of
+        from hstream_tpu.engine.plan import FilterNode
+
+        node = self.plan.node
+        keys = {"l": self.left_keys, "r": self.right_keys}
+        if not isinstance(node, AggregateNode) or not all(
+                isinstance(e, Col) for ks in keys.values() for e in ks):
+            return None
+        names: set[str] = set()
+        for g in node.group_keys:
+            names |= columns_of(g)
+        for a in node.aggs:
+            if a.input is not None:
+                names |= columns_of(a.input)
+        child = node.child
+        while isinstance(child, FilterNode):
+            names |= columns_of(child.predicate)
+            child = child.child
+        out = {s: {e.name for e in ks} for s, ks in keys.items()}
+        for name in names:
+            pre, _, col = name.partition(".")
+            side = self._aliases.get(pre) if col else None
+            if side is not None:
+                out[side].add(col)
+            else:
+                out["l"].add(name)
+                out["r"].add(name)
+        return {s: frozenset(v) for s, v in out.items()}
+
+    def join_gauges(self) -> dict[str, int]:
+        """The join's own counts for `admin stats queries` and
+        /metrics: `join_stats`, the live join-key codes and each
+        side's stored rows inside retention (on the device path by the
+        host shadow, which counts them exactly). Host ints, no
+        dispatch."""
+        out = dict(self.join_stats)
+        out["codes_live"] = len(self._jcode)
+        dev = self._dev
+        for side, name in (("l", "left"), ("r", "right")):
+            out[f"store_rows_{name}"] = len(
+                dev["shadow"][side] if dev is not None
+                else self._stores[side])
+        return out
+
     def _host_batch(self, side, mine, other, codes, bts, rows,
                     ridx) -> list[dict[str, Any]]:
         """The host reference path: batch searchsorted probe over the
@@ -723,7 +1031,7 @@ class JoinExecutor(_JoinBase):
         # probe the other side BEFORE inserting: the reference
         # loop probes only the opposite store, which this batch
         # never mutates, so insert/probe need no interleaving
-        pr = other.probe(codes, bts - self.within, bts + self.within)
+        pr = other.probe(codes, *self._probe_span(bts))
         mine.insert_sorted(codes, bts, brows)
         if pr is None:
             return []
@@ -732,6 +1040,7 @@ class JoinExecutor(_JoinBase):
         tot = int(cnt.sum())
         if not tot:
             return []
+        self.join_stats["matches"] += tot
         start = np.cumsum(cnt) - cnt
         oidx = (np.arange(tot, dtype=np.int64)
                 - np.repeat(start, cnt)
@@ -740,6 +1049,18 @@ class JoinExecutor(_JoinBase):
         jts = np.maximum(bts[rep], other.ts[oidx])
         return self._emit_matches(side, brows, rep, codes[rep], other,
                                   oidx, jts)
+
+    def _probe_span(self, bts: np.ndarray) -> tuple:
+        """The closed span [lo, hi] of event time a record's partners
+        lie in, per record: `within` around it, or (window join) its
+        own window; never older than the retention bound."""
+        if self.window_join:
+            lo = bts - bts % self.within
+            hi = lo + (self.within - 1)
+        else:
+            lo, hi = bts - self.within, bts + self.within
+        cutoff = self._cutoff_abs()
+        return (lo if cutoff is None else np.maximum(lo, cutoff)), hi
 
     def _batch_codes(self, my_keys, rows) -> np.ndarray:
         """Dense join-key code per row (-1 = null key, skipped). One
@@ -755,16 +1076,12 @@ class JoinExecutor(_JoinBase):
                     "join key cardinality within the retention window "
                     f"exceeds {1 << 22} distinct keys")
         jcode = self._jcode
-        rev = self._jcode_rev
+        mint = self._mint_code
         out = np.empty(len(rows), np.int64)
 
         def code_of(k) -> int:
             c = jcode.get(k)
-            if c is None:
-                c = len(rev)
-                jcode[k] = c
-                rev.append(k)
-            return c
+            return mint(k) if c is None else c
 
         if all(isinstance(e, Col) for e in my_keys):
             names = [e.name for e in my_keys]
@@ -904,12 +1221,13 @@ class JoinExecutor(_JoinBase):
     def _match_key_ids(self, mcodes: np.ndarray) -> np.ndarray:
         """Inner-executor key ids per match via a code-indexed LUT (the
         GROUP BY key IS the join key on this path)."""
+        self._grow_code_luts()
         lut = self._kid_lut
-        if len(lut) < len(self._jcode_rev):
-            grown = np.full(max(len(self._jcode_rev), 2 * len(lut)),
-                            -1, np.int32)
-            grown[:len(lut)] = lut
-            self._kid_lut = lut = grown
+        if self.window_join:
+            # `_window_kids` named every id a pair can carry: a pair
+            # holds a stored or probing row of the det side, whose
+            # window is open
+            return lut[mcodes]
         need = np.unique(mcodes[lut[mcodes] < 0])
         for c in need.tolist():
             lut[c] = self._inner.key_id_for(self._jcode_rev[c])
@@ -922,6 +1240,8 @@ class JoinExecutor(_JoinBase):
         columnar batch from either half stays a ColumnarEmit."""
         from hstream_tpu.common.columnar import extend_rows
 
+        while self._inflight:
+            self._settle_fused()
         out = self._drain_matches() if self._pending_matches else None
         out = extend_rows(out, self._drain_staged(keep_tail=False))
         return out if out is not None else []
@@ -1002,7 +1322,9 @@ class JoinExecutor(_JoinBase):
         """Enable the columnar match path when (a) the inner executor
         has one, (b) its GROUP BY columns are exactly the join key (so
         inner key ids broadcast per probe group), and (c) every column
-        the inner step needs resolves to one side."""
+        the inner step needs resolves to one side. A window join's
+        GROUP BY may hold, beside every join key column of one side,
+        further columns of that side (`_window_kids`)."""
         inner = self._inner
         self._fast = False
         if inner is None or not hasattr(inner, "process_columnar"):
@@ -1023,9 +1345,12 @@ class JoinExecutor(_JoinBase):
             return
         gs = [s for s, _ in resolved]
         gcols = [c for _, c in resolved]
-        if not (len(set(gs)) == 1
-                and ((gs[0] == "l" and gcols == knames_l)
-                     or (gs[0] == "r" and gcols == knames_r))):
+        if len(set(gs)) != 1:
+            return
+        knames = knames_l if gs[0] == "l" else knames_r
+        if knames is None or not (
+                gcols == knames
+                or (self.window_join and set(knames) <= set(gcols))):
             return
         need = {}
         for name in inner._needed_cols:
@@ -1043,7 +1368,17 @@ class JoinExecutor(_JoinBase):
                 need[name] = ("both", name)
             else:
                 return
-        self._fast = {"need": need}
+        self._fast = {"need": need, "det": (gs[0], gcols)}
+        if self.window_join:
+            # rows stored before this plan existed carry no inner key
+            # id yet: name them now, from the rows themselves
+            st = self._stores[gs[0]]
+            self._grow_code_luts()
+            bare = np.flatnonzero(self._kid_lut[st.code] < 0)
+            if len(bare):
+                self._window_kids(
+                    gs[0], st.code[bare], st.ts[bare],
+                    lambda c: [r.get(c) for r in st.rows[bare]])
 
     def _match_cols(self, fast, side, brows, rep, other,
                     oidx) -> tuple[dict, dict | None]:
@@ -1116,6 +1451,13 @@ class JoinExecutor(_JoinBase):
     # (use_device_join=False).
 
     DEVICE_STORE_CAPACITY = 1 << 14   # initial per-side slots (grows)
+    # a window join holds a whole window of both streams and its fused
+    # step is rebuilt (a minute on the chip) for every size on the way:
+    # it starts larger and grows in larger strides; all of it is small
+    # beside a chip's memory
+    WINDOW_STORE_CAPACITY = 1 << 16   # initial per-side slots (grows x16)
+    WINDOW_MATCH_CAPACITY = 1 << 14   # initial match buffer width
+    WINDOW_INNER_KEYS = 1 << 18       # the inner key table, at least
     REBASE_REL_MS = 1 << 30           # re-anchor epoch past this
 
     def _device_ready(self) -> bool:
@@ -1160,14 +1502,16 @@ class JoinExecutor(_JoinBase):
         if max(len(lay["l"]), len(lay["r"])) > lattice.JOIN_MAX_COLS:
             self.use_device_join = False  # flags word out of bits
             return False
-        cap = self.DEVICE_STORE_CAPACITY
+        cap = (self.WINDOW_STORE_CAPACITY if self.window_join
+               else self.DEVICE_STORE_CAPACITY)
         need = max(len(self._stores["l"]), len(self._stores["r"])) * 2
         cap = round_up_pow2(need, lo=cap)
         cands = [int(st.ts.min()) for st in self._stores.values()
                  if len(st)]
         if self.watermark >= 0:
             cands.append(self.watermark)
-        t0 = (min(cands) - self.retention_ms) if cands else None
+        t0 = (self._anchor(min(cands) - self.retention_ms)
+              if cands else None)
         sjl = None
         if (self.mesh is not None
                 and self.key_axis in self.mesh.axis_names
@@ -1191,7 +1535,10 @@ class JoinExecutor(_JoinBase):
             # sizes them EXACTLY per batch, so they never overflow):
             # a buffer sized to batch_capacity would make every fetch
             # pay for a worst case that never happens
-            "match_cap": 4096,
+            # (a window join starts where a frame of a one-to-many join
+            # ends: each width on the way is a fused step rebuilt)
+            "match_cap": self.WINDOW_MATCH_CAPACITY if self.window_join
+            else 4096,
             "bcaps": set(),
             "evict_cutoff": -(1 << 62),
             "stores": {
@@ -1217,6 +1564,12 @@ class JoinExecutor(_JoinBase):
         for s in ("l", "r"):
             self._stores[s] = _FlatIntervalStore(self._jcode_rev)
         return True
+
+    def _anchor(self, t: int) -> int:
+        """A join epoch at or before `t`: a window join keeps it a
+        multiple of the window size, so the kernels cut relative time
+        where absolute time cuts."""
+        return t - t % self.within if self.window_join else t
 
     def _build_feed_plans(self) -> dict | None:
         """Hashable per-side plans mapping the inner step's needed
@@ -1401,8 +1754,14 @@ class JoinExecutor(_JoinBase):
             return None
         flags, vals = enc
         keep = codes >= 0
-        if not keep.all():
-            kidx = np.nonzero(keep)[0]
+        kidx = None if keep.all() else np.nonzero(keep)[0]
+        if self.window_join and keep.any():
+            fresh = self._in_open_windows(ts if kidx is None
+                                          else ts[kidx])
+            if fresh is not None:
+                kidx = (np.nonzero(fresh)[0] if kidx is None
+                        else kidx[fresh])
+        if kidx is not None:
             codes = codes[kidx]
             bts = ts[kidx]
             flags = flags[kidx]
@@ -1412,8 +1771,12 @@ class JoinExecutor(_JoinBase):
         if not len(codes):
             return codes, bts, flags, vals
         order = np.lexsort((bts, codes))
-        return (codes[order], bts[order], flags[order],
-                vals[:, order])
+        codes, bts = codes[order], bts[order]
+        if self.window_join:
+            src = order if kidx is None else kidx[order]
+            self._window_kids(side, codes, bts,
+                              lambda c: np.asarray(cols[c])[src])
+        return codes, bts, flags[order], vals[:, order]
 
     def _batch_codes_columnar(self, my_keys, cols, nulls,
                               n: int) -> np.ndarray | None:
@@ -1430,15 +1793,11 @@ class JoinExecutor(_JoinBase):
                     "join key cardinality within the retention window "
                     f"exceeds {1 << 22} distinct keys")
         jcode = self._jcode
-        rev = self._jcode_rev
+        mint = self._mint_code
 
         def code_of(k) -> int:
             c = jcode.get(k)
-            if c is None:
-                c = len(rev)
-                jcode[k] = c
-                rev.append(k)
-            return c
+            return mint(k) if c is None else c
 
         col_vals: list[np.ndarray] = []
         col_codes: list[np.ndarray] = []
@@ -1574,6 +1933,18 @@ class JoinExecutor(_JoinBase):
         """Sticky pow2 batch capacity (each distinct shape is its own
         XLA compile; varying batch sizes converge on a few)."""
         caps = self._dev["bcaps"]
+        if self.window_join:
+            # one shape, the largest met: a window join's fused step
+            # sorts a whole store with the batch, which takes the chip
+            # a minute to build and which a few thousand padded rows do
+            # not slow; so a source's smaller frames (and the one-row
+            # closers) ride in the larger source's program. It starts
+            # at twice the capacity the task sized to the first frame
+            # it read: that frame was one source's
+            cap = max(round_up_pow2(n, lo=1024), max(caps, default=0),
+                      2 * self._batch_capacity)
+            caps.add(cap)
+            return cap
         for c in sorted(caps):
             if n <= c <= 8 * max(n, 1):
                 return c
@@ -1595,66 +1966,83 @@ class JoinExecutor(_JoinBase):
 
         dev = self._dev
         n = len(codes)
+        cutoff_abs = self._cutoff_abs()
         if dev["t0"] is None:
-            dev["t0"] = int(bts.min()) - self.retention_ms
+            dev["t0"] = self._anchor(int(bts.min()) - self.retention_ms)
         self._maybe_rebase(int(bts.min()), int(bts.max()))
-        if dev["n"][side] + n > dev["cap"]:
-            self._refresh_counts()  # upper bound -> exact
-        if dev["n"][side] + n > dev["cap"]:
-            # capacity pressure: evict with the PRE-batch watermark
-            # cutoff — the probe below must still see every entry the
-            # host reference would (it prunes only after the batch)
-            self._dispatch_evict(self.watermark - self.retention_ms, 0)
-            self._refresh_counts()
-            if dev["n"][side] + n > dev["cap"]:
+        if self.window_join:
+            # a window join's stores hold what its host shadows hold,
+            # row for row (both lose a closed window at its close), so
+            # the shadow's length is the store's, exactly and for free.
+            # Grown with a quarter to spare, and as soon as a quarter
+            # is not to spare: the capacity is then final within the
+            # first window, not at whichever later close a source was
+            # read a few frames further than the other (a recompile).
+            # By sixteen: every capacity on the way is a fused step
+            # built, and the stores are small
+            live = len(dev["shadow"][side]) + n
+            if live * 5 // 4 > dev["cap"]:
                 self._grow_device(round_up_pow2(
-                    dev["n"][side] + n, lo=dev["cap"] * 2))
-            elif max(dev["n"].values()) + n > dev["cap"] // 2:
-                # hysteresis: an eviction that leaves the store more
-                # than half full would force another sort within a few
-                # batches — grow once instead of evicting every batch
-                self._grow_device(dev["cap"] * 2)
+                    live * 5 // 4, lo=dev["cap"] * 16))
+        elif dev["n"][side] + n > dev["cap"]:
+            self._refresh_counts()  # upper bound -> exact
+            if dev["n"][side] + n > dev["cap"]:
+                # capacity pressure: evict with the PRE-batch watermark
+                # cutoff — the probe below must still see every entry
+                # the host reference would (it prunes only after the
+                # batch)
+                self._dispatch_evict(self.watermark - self.retention_ms,
+                                     0)
+                self._refresh_counts()
+                if dev["n"][side] + n > dev["cap"]:
+                    self._grow_device(round_up_pow2(
+                        dev["n"][side] + n, lo=dev["cap"] * 2))
+                elif max(dev["n"].values()) + n > dev["cap"] // 2:
+                    # hysteresis: an eviction that leaves the store
+                    # more than half full would force another sort
+                    # within a few batches — grow once instead of
+                    # evicting every batch
+                    self._grow_device(dev["cap"] * 2)
         # exact match total from the host shadow (code/ts only): sizes
         # the padded match width so the kernel can never truncate
         other_side = "r" if side == "l" else "l"
-        cutoff_abs = (self.watermark - self.retention_ms
-                      if self.watermark >= 0 else None)
         shadow_o = dev["shadow"][other_side]
-        lo_ts = bts - self.within
-        if cutoff_abs is not None:
-            lo_ts = np.maximum(lo_ts, cutoff_abs)
-        pr = shadow_o.probe(codes, lo_ts, bts + self.within)
-        sjl = dev.get("sjl")
-        if pr is None:
-            total = 0
-        elif sjl is not None:
-            # the match buffer is PER SHARD: size it to the worst
-            # shard's total (each shard packs its own segment)
-            per = np.bincount((codes % sjl.n_shards).astype(np.int64),
-                              weights=(pr[1] - pr[0]).astype(np.float64),
-                              minlength=sjl.n_shards)
-            total = int(per.max())
-        else:
-            total = int((pr[1] - pr[0]).sum())
-        dev["shadow"][side].insert_sorted(codes, bts,
-                                          np.empty(n, object))
-        if cutoff_abs is not None and cutoff_abs > 0:
-            dev["shadow"][side].prune(cutoff_abs)
-            shadow_o.prune(cutoff_abs)
+        with trace_span(self.tracer, "join_shadow"):
+            pr = shadow_o.probe(codes, *self._probe_span(bts))
+            sjl = dev.get("sjl")
+            if pr is None:
+                total = 0
+            elif sjl is not None:
+                # the match buffer is PER SHARD: size it to the worst
+                # shard's total (each shard packs its own segment)
+                per = np.bincount((codes % sjl.n_shards).astype(np.int64),
+                                  weights=(pr[1] - pr[0]).astype(np.float64),
+                                  minlength=sjl.n_shards)
+                total = int(per.max())
+            else:
+                total = int((pr[1] - pr[0]).sum())
+            self.join_stats["matches"] += total
+            dev["shadow"][side].insert_sorted(codes, bts,
+                                              np.empty(n, object))
+            if (cutoff_abs is not None and cutoff_abs > 0
+                    and not self.window_join):  # (pruned at every close)
+                dev["shadow"][side].prune(cutoff_abs)
+                shadow_o.prune(cutoff_abs)
         if total > dev["match_cap"]:
             dev["match_cap"] = round_up_pow2(total,
                                              lo=dev["match_cap"] * 2)
-        kid = self._match_key_ids(codes)
-        lay = dev["lay"][side]
-        bcap = self._dev_bcap(n)
-        buf = np.zeros((4 + len(lay), bcap), np.int32)
-        buf[0, :n] = codes
-        buf[0, n:] = lattice.JOIN_SENT_CODE
-        buf[1, :n] = (bts - dev["t0"]).astype(np.int32)
-        buf[2, :n] = kid
-        buf[3, :n] = flags
-        if len(lay):
-            buf[4:, :n] = vals
+        with trace_span(self.tracer, "join_pack"):
+            kid = self._match_key_ids(codes)
+            lay = dev["lay"][side]
+            bcap = self._dev_bcap(n)
+            buf = np.zeros((4 + len(lay), bcap), np.int32)
+            buf[0, :n] = codes
+            buf[0, n:] = lattice.JOIN_SENT_CODE
+            buf[1, :n] = (bts - dev["t0"]).astype(np.int32)
+            buf[2, :n] = np.maximum(kid, 0)
+            buf[3, :n] = flags
+            if len(lay):
+                buf[4:, :n] = vals
         other = dev["stores"][other_side]
         # the probe-visible retention cutoff mirrors the host
         # reference's prune-before-this-batch state: the device store
@@ -1666,7 +2054,8 @@ class JoinExecutor(_JoinBase):
         self.join_stats["probe_batches"] += 1
         self.join_stats["probe_dispatches"] += 1
         if dev.get("feed") is not None and self._fuse_ok(bts):
-            return self._fused_batch(side, other_side, buf, n, cutoff)
+            return self._fused_batch(side, other_side, buf, n, cutoff,
+                                     total)
         if sjl is not None:
             with kernel_family("probe", self.dispatch_observer,
                                ready=self._device_values):
@@ -1678,8 +2067,8 @@ class JoinExecutor(_JoinBase):
         else:
             kern = lattice.join_probe_insert(
                 dev["cap"], bcap, dev["match_cap"], len(lay),
-                len(dev["lay"][other_side]))
-            with kernel_family("probe", self.dispatch_observer,
+                len(dev["lay"][other_side]), self.window_join)
+            with kernel_family(self._family, self.dispatch_observer,
                                ready=self._device_values):
                 dev["stores"][side], packed = kern(
                     dev["stores"][side], other, buf, np.int32(n),
@@ -1711,8 +2100,7 @@ class JoinExecutor(_JoinBase):
         if inner.epoch is not None and int(bts.min()) < inner.epoch:
             return False  # pre-epoch joined ts: row path handles
         adv = w.advance_ms
-        lo = int(bts.min())
-        hi = int(bts.max()) + self.within
+        lo, hi = self._joined_span(int(bts.min()), int(bts.max()))
         span = (hi - hi % adv - (lo - lo % adv)) // adv + 1
         back = w.windows_per_record - 1
         if span + back > min(inner.spec.n_slots, 64):
@@ -1732,9 +2120,24 @@ class JoinExecutor(_JoinBase):
             by_res[r] = s
         return True
 
+    def _joined_span(self, lo: int, hi: int) -> tuple[int, int]:
+        """The span of event time the pairs of a batch spanning
+        [lo, hi] can lie in (a pair's time is the later of its two):
+        up to `within` past the batch, or (window join) inside the
+        batch's own windows."""
+        if self.window_join:
+            return lo, hi - hi % self.within + self.within - 1
+        return lo, hi + self.within
+
+    @property
+    def _family(self) -> str:
+        """The kernel family of the probe dispatch: `join` for a window
+        join (`dispatch:join`), `probe` for the interval join."""
+        return "join" if self.window_join else "probe"
+
     # contract: dispatches<=1 fetches<=0
-    def _fused_batch(self, side, other_side, buf, n, cutoff
-                     ) -> list[dict[str, Any]]:
+    def _fused_batch(self, side, other_side, buf, n, cutoff,
+                     expected: int) -> list[dict[str, Any]]:
         """Dispatch the probe+insert+inner-scatter kernel: the matched
         pairs aggregate on device, so the batch costs ZERO D2H — the
         changelog extract (already deferred/batched) is the only fetch
@@ -1744,8 +2147,9 @@ class JoinExecutor(_JoinBase):
 
         dev = self._dev
         inner = self._inner
-        lo = int(buf[1, :n].min()) + dev["t0"]
-        hi = int(buf[1, :n].max()) + dev["t0"] + self.within
+        bmax = int(buf[1, :n].max()) + dev["t0"]  # the batch's newest
+        lo, hi = self._joined_span(int(buf[1, :n].min()) + dev["t0"],
+                                   bmax)
         inner._ensure_epoch(lo)
         inner._maybe_rebase(hi)
         # watermark forwarding: the joined stream's watermark is the
@@ -1776,8 +2180,8 @@ class JoinExecutor(_JoinBase):
                 dev["cap"], buf.shape[1], dev["match_cap"],
                 len(dev["lay"][side]), len(dev["lay"][other_side]),
                 inner.spec, inner.schema, inner._filter_expr, feed,
-                nulls_plan, filter_nulls)
-            with kernel_family("probe", self.dispatch_observer,
+                nulls_plan, filter_nulls, self.window_join)
+            with kernel_family(self._family, self.dispatch_observer,
                                ready=self._device_values):
                 dev["stores"][side], inner.state, _total = kern(
                     dev["stores"][side], dev["stores"][other_side], buf,
@@ -1785,14 +2189,24 @@ class JoinExecutor(_JoinBase):
                     inner.state, wm_rel, ts_off)
         self._note_insert(side, n)
         self.join_stats["fused_batches"] += 1
+        if self.window_join:
+            # the pairs never leave the device; their count does, a few
+            # batches late: the one wait for the device on this path
+            # (the task thread runs at most FUSED_DEPTH batches ahead
+            # of it), and the check that the host shadow, which sized
+            # the match buffer, counted what the kernel matched
+            self._inflight.append((_total, expected))
+            while len(self._inflight) > self.FUSED_DEPTH:
+                self._settle_fused()
         # inner host bookkeeping over the conservative ts range (the
         # overapproximated window set is semantics-free: empty windows
         # close without emitting via the count>0 filter)
         try:
             if inner.window is not None:
                 inner._track_windows(np.asarray([lo, hi], np.int64))
-            bmax = hi - self.within  # this batch's max record ts
-            if bmax > inner.watermark_abs:
+            # (a window join's event time is the minimum over both
+            # sources, `_after_batch`: one batch does not move it)
+            if not self.window_join and bmax > inner.watermark_abs:
                 inner.watermark_abs = bmax
             out = None
             if inner.emit_changes:
@@ -1804,6 +2218,19 @@ class JoinExecutor(_JoinBase):
         finally:
             inner._no_close.clear()
             inner._touched_this_call.clear()
+
+    FUSED_DEPTH = 2   # fused window-join batches in flight
+
+    # contract: dispatches<=0 fetches<=1
+    def _settle_fused(self) -> None:
+        total, expected = self._inflight.popleft()
+        with trace_span(self.tracer, "join_fetch"):
+            got = int(np.asarray(total))
+        self.join_stats["probe_fetches"] += 1
+        if got != expected:
+            raise RuntimeError(
+                f"window join: the device matched {got} pairs where "
+                f"the host shadow counted {expected}")
 
     # contract: dispatches<=0 fetches<=1
     def _drain_matches(self) -> list[dict[str, Any]]:
@@ -1822,22 +2249,23 @@ class JoinExecutor(_JoinBase):
         # host upper bound stays fresh without hot-loop blocking
         self._refresh_counts()
         host: list[tuple] = []
-        if len(pending) == 1:
-            packed, *rest = pending[0]
-            self.join_stats["probe_fetches"] += 1
-            host.append((np.asarray(packed), *rest))
-        else:
-            by_shape: dict[tuple, list] = {}
-            for ent in pending:
-                by_shape.setdefault(tuple(ent[0].shape), []).append(ent)
-            groups: dict[int, tuple] = {}
-            for group in by_shape.values():
+        with trace_span(self.tracer, "join_fetch"):
+            if len(pending) == 1:
+                packed, *rest = pending[0]
                 self.join_stats["probe_fetches"] += 1
-                stacked = np.asarray(stack_pow2([e[0] for e in group]))
-                for ent, hbuf in zip(group, stacked):
-                    groups[id(ent)] = (hbuf, *ent[1:])
-            # preserve submission order across shape groups
-            host = [groups[id(ent)] for ent in pending]
+                host.append((np.asarray(packed), *rest))
+            else:
+                by_shape: dict[tuple, list] = {}
+                for ent in pending:
+                    by_shape.setdefault(tuple(ent[0].shape), []).append(ent)
+                groups: dict[int, tuple] = {}
+                for group in by_shape.values():
+                    self.join_stats["probe_fetches"] += 1
+                    stacked = np.asarray(stack_pow2([e[0] for e in group]))
+                    for ent, hbuf in zip(group, stacked):
+                        groups[id(ent)] = (hbuf, *ent[1:])
+                # preserve submission order across shape groups
+                host = [groups[id(ent)] for ent in pending]
         from hstream_tpu.common.columnar import extend_rows
 
         out = None
@@ -1883,7 +2311,8 @@ class JoinExecutor(_JoinBase):
                 cutoff, match_cap))
         kern = lattice.join_probe_only(
             other["code"].shape[0], buf.shape[1], match_cap,
-            len(dev["lay"][side]), len(dev["lay"][other_side]))
+            len(dev["lay"][side]), len(dev["lay"][other_side]),
+            self.window_join)
         return np.asarray(kern(other, buf, np.int32(n),
                                np.int32(self.within), cutoff))
 
@@ -1906,6 +2335,13 @@ class JoinExecutor(_JoinBase):
         m = len(kid)
         if m == 0:
             return []
+        with trace_span(self.tracer, "join_decode"):
+            key_ids, jts_abs, cols, nulls = self._match_columns(
+                side, t0, kid, jts, mflags, oflags, mcols, ocols)
+        return self._feed_inner_columnar(key_ids, jts_abs, cols, nulls)
+
+    def _match_columns(self, side, t0, kid, jts, mflags, oflags,
+                       mcols, ocols) -> tuple:
         dev = self._dev
         other_side = "r" if side == "l" else "l"
         lidx = {name: j for j, (name, _c)
@@ -1941,9 +2377,8 @@ class JoinExecutor(_JoinBase):
             msk = nb.astype(np.bool_)
             if msk.any():
                 nulls[name] = msk
-        return self._feed_inner_columnar(
-            kid.astype(np.int32), jts.astype(np.int64) + t0, cols,
-            nulls or None)
+        return (kid.astype(np.int32), jts.astype(np.int64) + t0, cols,
+                nulls or None)
 
     def _maybe_rebase(self, min_ts: int, max_ts: int) -> None:
         """Keep device-relative time inside int32: re-anchor the join
@@ -1955,12 +2390,13 @@ class JoinExecutor(_JoinBase):
         # the eviction riding the rebase runs BEFORE this batch's
         # probe, so its cutoff is the PRE-batch watermark's — exactly
         # the prune state the host reference would probe against
-        cutoff_abs = ((self.watermark - self.retention_ms)
-                      if self.watermark >= 0 else dev["t0"])
+        cutoff_abs = self._cutoff_abs()
+        if cutoff_abs is None:
+            cutoff_abs = dev["t0"]
         if min_ts - dev["t0"] < 0:
-            delta = (min_ts - self.retention_ms) - dev["t0"]
+            delta = self._anchor(min_ts - self.retention_ms) - dev["t0"]
         elif max_ts - dev["t0"] >= self.REBASE_REL_MS:
-            delta = max(cutoff_abs - dev["t0"], 0)
+            delta = max(self._anchor(cutoff_abs) - dev["t0"], 0)
         else:
             return
         if max_ts - (dev["t0"] + delta) >= (1 << 31):
@@ -2011,11 +2447,14 @@ class JoinExecutor(_JoinBase):
             self._sharded_dispatches += 1
         else:
             kern = lattice.join_evict(dev["cap"], len(dev["lay"]["l"]),
-                                      len(dev["lay"]["r"]))
-            left, right, narr = kern(
-                dev["stores"]["l"], dev["stores"]["r"],
-                np.int32(min(cutoff_rel, (1 << 31) - 1)),
-                np.int32(delta))
+                                      len(dev["lay"]["r"]),
+                                      self.window_join)
+            with (kernel_family("join_evict", self.dispatch_observer)
+                  if self.window_join else contextlib.nullcontext()):
+                left, right, narr = kern(
+                    dev["stores"]["l"], dev["stores"]["r"],
+                    np.int32(min(cutoff_rel, (1 << 31) - 1)),
+                    np.int32(delta))
         dev["stores"]["l"] = left
         dev["stores"]["r"] = right
         # the deferred count snapshot reflects the store AT THIS
@@ -2124,11 +2563,8 @@ class JoinExecutor(_JoinBase):
             return self._stores
         import jax
 
-        from hstream_tpu.engine.types import ColumnType
-
         self._refresh_counts()
         out: dict[str, _FlatIntervalStore] = {}
-        inner = self._inner
         # the device store evicts lazily (capacity only) and hides
         # expired entries from probes via the cutoff mask; the view
         # applies the same retention filter so it matches the host
@@ -2178,40 +2614,141 @@ class JoinExecutor(_JoinBase):
                 if n == 0:
                     out[side] = st
                     continue
-                lay = self._dev["lay"][side]
-                decoded: list[tuple[str, list]] = []
-                flags = arrs["flags"][:n]
-                for j, (name, col) in enumerate(lay):
-                    want = inner.schema.type_of(name)
-                    raw = arrs["cols"][j, :n]
-                    nullm = ((flags >> (2 * j)) & 1).astype(np.bool_)
-                    presm = ((flags >> (2 * j + 1)) & 1).astype(
-                        np.bool_)
-                    if want == ColumnType.FLOAT:
-                        vv = np.ascontiguousarray(raw).view(np.float32)
-                        py = [float(x) for x in vv]
-                    elif want == ColumnType.BOOL:
-                        py = [bool(x) for x in raw]
-                    elif want == ColumnType.STRING:
-                        dec = inner.dicts[name].decode
-                        py = [dec(int(x)) if not nl else None
-                              for x, nl in zip(raw, nullm)]
-                    else:
-                        py = [int(x) for x in raw]
-                    decoded.append((col, [
-                        (_MISS if not p else (None if nl else v))
-                        for v, nl, p in zip(py, nullm, presm)]))
-                rows = np.empty(n, object)
-                for i in range(n):
-                    row = {}
-                    for col, vals in decoded:
-                        if vals[i] is not _MISS:
-                            row[col] = vals[i]
-                    rows[i] = row
                 st.insert_sorted(
                     arrs["code"][:n].astype(np.int64),
                     arrs["ts"][:n].astype(np.int64) + self._dev["t0"],
-                    rows)
+                    self._rows_from_planes(self._dev["lay"][side],
+                                           arrs, n))
             out[side] = st
         return out
+
+    def _rows_from_planes(self, lay, arrs: dict, n: int) -> np.ndarray:
+        """The first `n` entries of a fetched store as host rows: each
+        holds the packed needed columns, which are the only fields a
+        future match can emit on the fast path."""
+        from hstream_tpu.engine.types import ColumnType
+
+        inner = self._inner
+        decoded: list[tuple[str, list]] = []
+        flags = arrs["flags"][:n]
+        for j, (name, col) in enumerate(lay):
+            want = inner.schema.type_of(name)
+            raw = arrs["cols"][j, :n]
+            nullm = ((flags >> (2 * j)) & 1).astype(np.bool_)
+            presm = ((flags >> (2 * j + 1)) & 1).astype(np.bool_)
+            if want == ColumnType.FLOAT:
+                vv = np.ascontiguousarray(raw).view(np.float32)
+                py = [float(x) for x in vv]
+            elif want == ColumnType.BOOL:
+                py = [bool(x) for x in raw]
+            elif want == ColumnType.STRING:
+                dec = inner.dicts[name].decode
+                py = [dec(int(x)) if not nl else None
+                      for x, nl in zip(raw, nullm)]
+            else:
+                py = [int(x) for x in raw]
+            decoded.append((col, [
+                (_MISS if not p else (None if nl else v))
+                for v, nl, p in zip(py, nullm, presm)]))
+        rows = np.empty(n, object)
+        for i in range(n):
+            row = {}
+            for col, vals in decoded:
+                if vals[i] is not _MISS:
+                    row[col] = vals[i]
+            rows[i] = row
+        return rows
+
+    # ---- a window join's snapshot, as columns ------------------------------
+    #
+    # The row-by-row host view above costs a second for every hundred
+    # thousand stored rows, under the task's lock. A window join on the
+    # device is captured as what it is: the two stores' planes by
+    # reference (the fetch is phase 2's, off the lock), the code
+    # dictionary as one array a key column where the keys are integers,
+    # and the two code-indexed tables. Restore rebuilds the host stores
+    # and the device path re-activates and re-migrates on the next
+    # batch, like every other join's.
+
+    def pinned_planes(self) -> dict:
+        """The device arrays a snapshot of this executor captures by
+        reference (the task copies them on the device, one program a
+        shape: it builds that program when the shapes move, not at
+        whichever snapshot comes next). {} where a snapshot takes the
+        host view."""
+        dev = self._dev
+        if not self.window_join or dev is None or dev.get("sjl"):
+            return {}
+        return {f"j/{side}.{plane}": v for side in ("l", "r")
+                for plane, v in dev["stores"][side].items()}
+
+    def capture_device(self) -> tuple[dict, dict]:
+        dev = self._dev
+        rev = self._jcode_rev
+        n = len(rev)
+        self._grow_code_luts()
+        held = np.fromiter((k is not None for k in rev), np.bool_, n)
+        meta = {"t0": dev["t0"], "lay": dev["lay"], "codes": None}
+        arrays: dict[str, Any] = {
+            "j/held": held,
+            "j/kid_lut": self._kid_lut[:n].copy(),
+            "j/kid_win": self._kid_win[:n].copy(),
+        }
+        keys = None
+        try:
+            keys = np.array([k for k in rev if k is not None])
+        except ValueError:
+            pass
+        if keys is not None and keys.dtype == np.int64 and (
+                keys.ndim == 2 or n == 0 or not held.any()):
+            arrays["j/keys"] = keys.reshape(int(held.sum()), -1)
+        else:
+            from hstream_tpu.engine.snapshot import _enc
+
+            meta["codes"] = [None if k is None else _enc(k) for k in rev]
+        arrays.update(self.pinned_planes())
+        return meta, arrays
+
+    def restore_device(self, meta: dict, arrays: dict) -> None:
+        """Install what `capture_device` took, into the host stores
+        (the inner executor is already restored)."""
+        from hstream_tpu.engine import lattice
+        from hstream_tpu.engine.snapshot import _dec
+
+        held = np.asarray(arrays["j/held"], np.bool_)
+        if meta["codes"] is not None:
+            rev = [None if k is None else tuple(_dec(k))
+                   for k in meta["codes"]]
+        else:
+            rev = [None] * len(held)
+            keys = np.asarray(arrays["j/keys"]).tolist()
+            for c, k in zip(np.flatnonzero(held).tolist(), keys):
+                rev[c] = tuple(k)
+        self._jcode_rev[:] = rev
+        self._jcode.clear()
+        self._jcode.update({k: c for c, k in enumerate(rev)
+                            if k is not None})
+        self._jcode_free = [c for c in range(len(rev) - 1, -1, -1)
+                            if rev[c] is None]
+        self._grow_code_luts()
+        self._kid_lut[:len(rev)] = arrays["j/kid_lut"]
+        self._kid_win[:len(rev)] = arrays["j/kid_win"]
+        cutoff = self._cutoff_abs()
+        for side in ("l", "r"):
+            arrs = {plane: np.asarray(arrays[f"j/{side}.{plane}"])
+                    for plane in ("code", "ts", "flags", "cols")}
+            ts = arrs["ts"].astype(np.int64) + (meta["t0"] or 0)
+            keep = arrs["code"] < lattice.JOIN_SENT_CODE
+            if cutoff is not None:
+                keep &= ts >= cutoff
+            n = int(keep.sum())
+            if n == 0:
+                continue
+            arrs = {"code": arrs["code"][keep], "ts": ts[keep],
+                    "flags": arrs["flags"][keep],
+                    "cols": arrs["cols"][:, keep]}
+            lay = [tuple(x) for x in meta["lay"][side]]
+            self._stores[side].insert_sorted(
+                arrs["code"].astype(np.int64), arrs["ts"],
+                self._rows_from_planes(lay, arrs, n))
 

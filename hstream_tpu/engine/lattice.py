@@ -68,6 +68,12 @@ PEEK_PROGRAM = "jit_peek_slots"              # build_extract_slots: it alone
 SESSION_STEP_PROGRAM = "jit_session_step"        # session_step_kernel
 SESSION_EXTRACT_PROGRAM = "jit_session_extract"  # session_extract_kernel
 SESSION_REMAP_PROGRAM = "jit_session_remap"      # session_remap_kernel
+# the window join's two (JOIN ... WITHIN WINDOW, engine/join.py): the
+# fused probe + insert + inner step of a batch, and the eviction of
+# closed windows from both stores. The interval join's programs keep
+# their names (jit_probe_insert_step, jit_evict)
+WINDOW_JOIN_STEP_PROGRAM = "jit_window_join_step"    # join_probe_insert_step
+WINDOW_JOIN_EVICT_PROGRAM = "jit_window_join_evict"  # join_evict
 
 # `jax.named_scope` of each aggregate's scatter inside the step (op
 # metadata only: the computation and its cache key do not change)
@@ -1080,10 +1086,14 @@ def _join_bounds(store_code, store_ts, qcode, lo_ts, hi_ts):
 
 
 def _join_match_arrays(other, batch, n, within, cutoff, bcap: int,
-                       match_cap: int, owned=None):
+                       match_cap: int, owned=None, window: bool = False):
     """Shared probe core: expand the per-record [lower, upper) spans
     into padded match index arrays. Returns (total, rec, oidx, mvalid,
-    jts) — rec indexes the probing batch, oidx the probed store."""
+    jts) — rec indexes the probing batch, oidx the probed store.
+    `window` (static): a window join, whose `within` operand is the
+    window's size W and whose probe spans the record's own window
+    [ts - ts % W, ts - ts % W + W); the host keeps the join epoch a
+    multiple of W, so relative time cuts where absolute time does."""
     cap = other["code"].shape[0]
     bcode = batch[0]
     bts = batch[1]
@@ -1091,9 +1101,13 @@ def _join_match_arrays(other, batch, n, within, cutoff, bcap: int,
     if owned is not None:
         bvalid = bvalid & owned
     qcode = jnp.where(bvalid, bcode, JOIN_SENT_CODE)
+    if window:
+        lo_ts = bts - bts % within       # floor: relative ts may be < 0
+        hi_ts = lo_ts + (within - 1)
+    else:
+        lo_ts, hi_ts = bts - within, bts + within
     lo_i, hi_i = _join_bounds(other["code"], other["ts"], qcode,
-                              jnp.maximum(bts - within, cutoff),
-                              bts + within)
+                              jnp.maximum(lo_ts, cutoff), hi_ts)
     cnt = jnp.where(bvalid, jnp.maximum(hi_i - lo_i, 0), 0)
     ccnt = jnp.cumsum(cnt)
     total = ccnt[-1]
@@ -1107,7 +1121,8 @@ def _join_match_arrays(other, batch, n, within, cutoff, bcap: int,
 
 
 def _join_probe(other, batch, n, within, cutoff, bcap: int,
-                match_cap: int, n_cols_mine: int, owned=None):
+                match_cap: int, n_cols_mine: int, owned=None,
+                window: bool = False):
     """Probe `other` with the batch; emit the packed match buffer (see
     module comment). `cutoff` masks entries past retention out of the
     probe (the lower bound is max(ts - within, cutoff)): the host
@@ -1117,7 +1132,7 @@ def _join_probe(other, batch, n, within, cutoff, bcap: int,
     (bool[bcap] or None) additionally masks which batch records this
     shard probes/inserts (key-sharded mirror)."""
     total, rec, oidx, mvalid, jts = _join_match_arrays(
-        other, batch, n, within, cutoff, bcap, match_cap, owned)
+        other, batch, n, within, cutoff, bcap, match_cap, owned, window)
     header = jnp.zeros((match_cap,), jnp.int32).at[0].set(total)
     rows = [header,
             jnp.where(mvalid, batch[2][rec], 0),                 # kid
@@ -1153,7 +1168,8 @@ def _join_insert(mine, batch, n, bcap: int, n_cols: int, owned=None):
 
 @functools.lru_cache(maxsize=256)
 def join_probe_insert(cap: int, bcap: int, match_cap: int,
-                      n_cols_mine: int, n_cols_other: int):
+                      n_cols_mine: int, n_cols_other: int,
+                      window: bool = False):
     """The fused per-micro-batch kernel: probe the other side, insert
     into mine — ONE device dispatch; the match buffer is the one D2H
     fetch. (state_mine, state_other, batch, n, within, cutoff) ->
@@ -1162,7 +1178,7 @@ def join_probe_insert(cap: int, bcap: int, match_cap: int,
     @jax.jit
     def probe_insert(mine, other, batch, n, within, cutoff):
         packed = _join_probe(other, batch, n, within, cutoff, bcap,
-                             match_cap, n_cols_mine)
+                             match_cap, n_cols_mine, window=window)
         return _join_insert(mine, batch, n, bcap, n_cols_mine), packed
 
     return probe_insert
@@ -1170,7 +1186,8 @@ def join_probe_insert(cap: int, bcap: int, match_cap: int,
 
 @functools.lru_cache(maxsize=256)
 def join_probe_only(cap: int, bcap: int, match_cap: int,
-                    n_cols_mine: int, n_cols_other: int):
+                    n_cols_mine: int, n_cols_other: int,
+                    window: bool = False):
     """Probe without insert: the match-overflow redo path (the batch is
     already inserted; the other side is unchanged, so re-probing at a
     wider match_cap is exact)."""
@@ -1178,14 +1195,14 @@ def join_probe_only(cap: int, bcap: int, match_cap: int,
     @jax.jit
     def probe(other, batch, n, within, cutoff):
         return _join_probe(other, batch, n, within, cutoff, bcap,
-                           match_cap, n_cols_mine)
+                           match_cap, n_cols_mine, window=window)
 
     return probe
 
 
 def _join_match_feed(other, batch, n, within, cutoff, bcap: int,
                      match_cap: int, feed_plan, nulls_plan,
-                     filter_nulls, owned=None):
+                     filter_nulls, owned=None, window: bool = False):
     """Probe + inner-feed core shared by the fused single-chip kernel
     and the key-sharded mirror (parallel.ShardedJoinLattice): expand
     the match spans and resolve every inner-step column straight from
@@ -1194,7 +1211,7 @@ def _join_match_feed(other, batch, n, within, cutoff, bcap: int,
     records already masked out. `owned` (bool[bcap] or None) restricts
     which batch records this shard probes."""
     total, rec, oidx, mvalid, jts = _join_match_arrays(
-        other, batch, n, within, cutoff, bcap, match_cap, owned)
+        other, batch, n, within, cutoff, bcap, match_cap, owned, window)
     mflags = batch[3][rec]
     oflags = other["flags"][oidx]
 
@@ -1254,9 +1271,10 @@ def join_probe_insert_step(cap: int, bcap: int, match_cap: int,
                            n_cols_mine: int, n_cols_other: int,
                            inner_spec: "LatticeSpec", schema,
                            filter_expr, feed_plan, nulls_plan,
-                           filter_nulls):
-    """The FULLY fused interval-join kernel: probe the other side,
-    insert into mine, and scatter the matched pairs straight into the
+                           filter_nulls, window: bool = False):
+    """The FULLY fused interval-join kernel (`window`: the window
+    join's, under WINDOW_JOIN_STEP_PROGRAM, see _join_match_arrays):
+    probe the other side, insert into mine, and scatter the matched pairs straight into the
     downstream aggregate lattice — matches never leave the device, so
     the per-micro-batch D2H cost drops to zero (the changelog extract
     is the only remaining fetch, already batched/deferred).
@@ -1283,24 +1301,28 @@ def join_probe_insert_step(cap: int, bcap: int, match_cap: int,
                  if filter_expr is not None else None)
     base_step = build_step_fn(inner_spec, agg_inputs, filter_fn)
 
-    @jax.jit
     def probe_insert_step(mine, other, batch, n, within, cutoff,
                           inner_state, wm_rel, ts_off):
         total, kid, jts, valid, cols = _join_match_feed(
             other, batch, n, within, cutoff, bcap, match_cap,
-            feed_plan, nulls_plan, filter_nulls)
+            feed_plan, nulls_plan, filter_nulls, window=window)
         ts_inner = jts + ts_off
         new_inner = base_step(inner_state, wm_rel, kid, ts_inner,
                               valid, cols)
         new_mine = _join_insert(mine, batch, n, bcap, n_cols_mine)
         return new_mine, new_inner, total
 
-    return probe_insert_step
+    if window:  # WINDOW_JOIN_STEP_PROGRAM
+        probe_insert_step.__name__ = "window_join_step"
+    return jax.jit(probe_insert_step)
 
 
 @functools.lru_cache(maxsize=256)
-def join_evict(cap: int, n_cols_l: int, n_cols_r: int):
-    """Vmapped two-sided eviction + epoch rebase: drop entries past the
+def join_evict(cap: int, n_cols_l: int, n_cols_r: int,
+               window: bool = False):
+    """Vmapped two-sided eviction + epoch rebase (`window`: the same
+    pass under WINDOW_JOIN_EVICT_PROGRAM, whose cutoff is the start of
+    the oldest open window, so whole closed windows go): drop entries past the
     retention cutoff from BOTH stores and shift surviving timestamps by
     -delta (0 outside a rebase), in one dispatch. The (code, ts) core
     compaction is vmapped over the side axis; the per-side column
@@ -1316,7 +1338,6 @@ def join_evict(cap: int, n_cols_l: int, n_cols_r: int):
         scode, sts, order = jax.lax.sort((code2, ts2, idx), num_keys=2)
         return scode, sts, order, jnp.sum(alive.astype(jnp.int32))
 
-    @jax.jit
     def evict(left, right, cutoff, delta):
         code = jnp.stack([left["code"], right["code"]])
         ts = jnp.stack([left["ts"], right["ts"]])
@@ -1329,7 +1350,9 @@ def join_evict(cap: int, n_cols_l: int, n_cols_r: int):
                         "cols": st["cols"][:, order[s]]})
         return out[0], out[1], n
 
-    return evict
+    if window:  # WINDOW_JOIN_EVICT_PROGRAM
+        evict.__name__ = "window_join_evict"
+    return jax.jit(evict)
 
 
 def unpack_join_matches(packed: np.ndarray, n_cols_mine: int):

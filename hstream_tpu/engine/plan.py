@@ -115,10 +115,15 @@ def plan_source(node: PlanNode) -> SourceNode:
     return node
 
 
-def single_chip_reason(node: AggregateNode) -> str | None:
-    """Why an aggregate cannot execute over the device mesh (None: it
-    shards). One predicate for EXPLAIN, the task's gate, the executor
-    factory and a snapshot's restore."""
+def single_chip_reason(node: AggregateNode, join=None) -> str | None:
+    """Why an aggregate (under `join`, the statement's JOIN clause, if
+    it has one) cannot execute over the device mesh (None: it shards).
+    One predicate for EXPLAIN, the task's gate, the executor factory
+    and a snapshot's restore."""
+    if join is not None and getattr(join, "window", False):
+        return ("a window join (JOIN ... WITHIN WINDOW) probes and "
+                "evicts whole windows over both stores on one chip; "
+                "the query runs single-chip")
     if any(a.kind in (AggKind.TOPK, AggKind.TOPK_DISTINCT)
            for a in node.aggs):
         return ("TOPK/TOPK_DISTINCT planes have no elementwise shard "

@@ -273,18 +273,23 @@ def _keys_state(ex) -> tuple[list | None, dict[str, np.ndarray]]:
     goes as arrays instead (None, {`k/held`: which ids hold a key,
     `k/c<g>`: their values of group column g}): NEXmark's auction ids
     are a million entries, and a million JSON objects encoded under the
-    executor's lock stop the query for seconds. Every other dictionary
-    (string keys, bools) goes as JSON, its values unread."""
+    executor's lock stop the query for seconds. Beside a numeric
+    column, a column whose values are all strings goes the same way (a
+    window join's `id, name` keys are a few hundred thousand). Every
+    other dictionary (string keys alone, a null, a bool, a fraction)
+    goes as JSON, its values unread."""
     from hstream_tpu.engine.executor import _KEY_FREE
 
     types = dict(ex.schema.fields)
-    if ex.group_cols and all(
-            types.get(c) in (ColumnType.FLOAT, ColumnType.INT)
-            for c in ex.group_cols):
+    kinds = [types.get(c) for c in ex.group_cols]
+    if (any(k in (ColumnType.FLOAT, ColumnType.INT) for k in kinds)
+            and all(k in (ColumnType.FLOAT, ColumnType.INT,
+                          ColumnType.STRING) for k in kinds)):
         n = len(ex._key_rev)
         held = ex._key_last[:n] > _KEY_FREE
         cols = [np.array(c[:n][held].tolist()) for c in ex._key_cols]
-        if all(c.dtype == np.int64 for c in cols):
+        if all(c.dtype == np.int64 or c.dtype.kind == "U"
+               for c in cols):
             out = {f"k/c{g}": c for g, c in enumerate(cols)}
             out["k/held"] = held
             return None, out
@@ -535,16 +540,28 @@ def _join_state(ex) -> tuple[dict, dict[str, np.ndarray]]:
     # (fetch + row reconstruction from the packed needed columns);
     # restore refills the host stores and the device re-activates and
     # re-migrates lazily on the next probe
-    stores = (ex._host_store_view() if hasattr(ex, "_host_store_view")
-              else ex._stores)
     meta = {
         "kind": "join",
         "batch_capacity": ex._batch_capacity,
         "watermark": ex.watermark,
-        "stores": {side: dump_store(st)
-                   for side, st in stores.items()},
     }
     arrays = {}
+    dev = getattr(ex, "_dev", None)
+    if (getattr(ex, "window_join", False) and dev is not None
+            and dev.get("sjl") is None and ex._inner is not None):
+        # a window join's device stores go as columns (planes by
+        # reference, the fetch is phase 2's): engine/join.py
+        # capture_device
+        meta["stores"] = {}
+        meta["device"], arrays = ex.capture_device()
+    else:
+        stores = (ex._host_store_view()
+                  if hasattr(ex, "_host_store_view") else ex._stores)
+        meta["stores"] = {side: dump_store(st)
+                          for side, st in stores.items()}
+    if getattr(ex, "window_join", False):
+        meta["window"] = {"src_hi": dict(ex._src_hi),
+                          "open_from": ex._open_from}
     if ex._inner is not None:
         inner_blob = snapshot_executor(ex._inner)
         arrays["i/blob"] = np.frombuffer(inner_blob, dtype=np.uint8)
@@ -597,4 +614,10 @@ def _restore_join(plan, meta, arrays, *, initial_keys: int,
                                     batch_capacity=batch_capacity,
                                     mesh=mesh)
         ex._inner = inner
+    if meta.get("window") is not None:
+        ex._src_hi = {s: int(v)
+                      for s, v in meta["window"]["src_hi"].items()}
+        ex._open_from = meta["window"]["open_from"]
+    if meta.get("device") is not None:
+        ex.restore_device(meta["device"], arrays)
     return ex

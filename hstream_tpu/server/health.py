@@ -38,6 +38,10 @@ from typing import Any
 
 from hstream_tpu.server.persistence import TaskStatus
 
+# a join query's gauges, as `QueryTask.engine_gauges` names them
+JOIN_GAUGES = ("join_codes_live", "join_store_rows_left",
+               "join_store_rows_right")
+
 # default thresholds; ServerContext carries per-server overrides
 # (--health-degraded-ms / --health-stalled-ms)
 DEGRADED_AFTER_MS = 5_000
@@ -336,9 +340,15 @@ def sample_health(ctx) -> None:
             stats.gauge_set("key_capacity", qid, keys["key_capacity"])
             live.add(("keys_live", qid))
             live.add(("key_capacity", qid))
+        # a device join's dictionary and stores: absent for an engine
+        # that has none
+        for name in JOIN_GAUGES:
+            if name in keys:
+                stats.gauge_set(name, qid, keys[name])
+                live.add((name, qid))
     for metric in ("query_watermark_ms", "query_watermark_lag_ms",
                    "query_health_level", "mesh_shards", "keys_live",
-                   "key_capacity"):
+                   "key_capacity", *JOIN_GAUGES):
         for label in stats.gauge_labels(metric):
             if (metric, label) not in live:
                 stats.gauge_drop(metric, label)

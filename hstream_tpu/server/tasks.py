@@ -169,6 +169,7 @@ class QueryTask(threading.Thread):
         self._last_persist_ms = 0.0   # cost of the last state write
         self._last_inline_ms = 0.0    # capture-side stall of last snap
         self._pin_keys_built = 0      # key capacity `_pin` is built for
+        self._pin_join_built: tuple = ()  # a join's pinned plane shapes
         # condition over a traced re-entrant lock: waits release the
         # lock through the wrapper, so the held-set stays truthful
         self._persist_cv = threading.Condition(
@@ -194,6 +195,7 @@ class QueryTask(threading.Thread):
         # the key dictionary's and the top close's counts (ISSUE 33),
         # mirrored the same way: what `key_gauges` last said
         self._key_counts_seen: dict[str, int] = {}
+        self._join_counts_seen: dict[str, int] = {}
         self._h2d_seen = 0
         self._d2h_seen = 0
         # multi-chip plane (ISSUE 16): shard_map dispatch mirror (a
@@ -402,6 +404,16 @@ class QueryTask(threading.Thread):
             fn = getattr(ex, "key_gauges", None)
             if fn is not None:
                 return {k: int(v) for k, v in fn().items()}
+            fn = getattr(ex, "join_gauges", None)
+            if fn is not None:
+                # a join: its own counts, `join_<what>`, and beside
+                # them its inner aggregate's key dictionary
+                out = {f"join_{k}": int(v) for k, v in fn().items()}
+                fn = getattr(getattr(ex, "_inner", None), "key_gauges",
+                             None)
+                if fn is not None:
+                    out.update({k: int(v) for k, v in fn().items()})
+                return out
         except Exception:  # noqa: BLE001 — a half-built executor must
             pass           # not kill the stats sweep
         return {}
@@ -754,8 +766,13 @@ class QueryTask(threading.Thread):
                                       d2h - self._d2h_seen)
                 self._d2h_seen = d2h
             kg = getattr(ex, "key_gauges", None)
+            if kg is None and inner is not None:
+                kg = getattr(inner, "key_gauges", None)
             if kg is not None:
                 self._mirror_key_counts(stats, kg())
+            js = getattr(ex, "join_stats", None)
+            if js is not None:
+                self._mirror_join_counts(stats, js)
             # shard_map dispatches (ISSUE 16): read the executor attr
             # directly — JoinExecutor.sharded_dispatches is a property
             # that already folds its inner aggregate, so engine_total
@@ -780,6 +797,19 @@ class QueryTask(threading.Thread):
             if delta > 0:
                 stats.stream_stat_add(name, self.info.query_id, delta)
         self._key_counts_seen = now
+
+    def _mirror_join_counts(self, stats, now: dict) -> None:
+        """A device join's counts since the last chunk, into the
+        query-labelled counters of /metrics."""
+        seen = self._join_counts_seen
+        for name, metric in (
+                ("rows_past_retention", "join_rows_past_retention"),
+                ("codes_reclaimed", "join_codes_reclaimed"),
+                ("matches", "join_matches")):
+            delta = now.get(name, 0) - seen.get(name, 0)
+            if delta > 0:
+                stats.stream_stat_add(metric, self.info.query_id, delta)
+                seen[name] = now[name]
 
     # ---- operator-state checkpointing --------------------------------------
 
@@ -931,6 +961,17 @@ class QueryTask(threading.Thread):
         snapshot builds nothing."""
         with self.state_lock:
             ex = self.executor
+            planes = getattr(ex, "pinned_planes", None)
+            if planes is not None:
+                # a join whose snapshot pins its stores' planes: their
+                # capacity moves as the key table's does
+                planes = planes()
+                shapes = tuple(sorted((k, v.shape)
+                                      for k, v in planes.items()))
+                if shapes != self._pin_join_built:
+                    self._pin_join_built = shapes
+                    _pin(planes)
+                return
             if getattr(ex, "key_gauges", None) is None:
                 return  # no window lattice: no key table that doubles
             if ex.spec.n_keys != self._pin_keys_built:
@@ -1222,8 +1263,7 @@ class QueryTask(threading.Thread):
                     # join packs device entries straight from the
                     # arrays (null-masked cells = absent fields, the
                     # drop_null row shape) — no row dicts on this path
-                    out = self._run_join_cols(
-                        ex, ts, _plain_columns(cols), nulls, logid)
+                    out = self._run_join_cols(ex, ts, cols, nulls, logid)
                 else:
                     with trace_span(self.tracer, "decode"):
                         # drop_null: a record never mentions columns it
@@ -1401,8 +1441,7 @@ class QueryTask(threading.Thread):
             if self.is_join or not hasattr(ex, "process_columnar"):
                 if self.is_join and getattr(ex, "supports_columnar_join",
                                             False):
-                    out = self._run_join_cols(
-                        ex, ts, _plain_columns(cols), nulls, logid)
+                    out = self._run_join_cols(ex, ts, cols, nulls, logid)
                 else:
                     # stateless: row materialization
                     with trace_span(self.tracer, "decode"):
@@ -1450,12 +1489,16 @@ class QueryTask(threading.Thread):
             return ex.process_columnar(
                 ts, _session_columns(cols, ex.input_columns()), nulls)
 
-    def _run_join_cols(self, ex, ts, plain, nulls, logid):
-        """Columnar dispatch into a stream-stream join executor."""
+    def _run_join_cols(self, ex, ts, cols, nulls, logid):
+        """Columnar dispatch into a stream-stream join executor: the
+        columns the plan reads of this source (a wide record's other
+        strings are never built)."""
         self._note_consumed(len(ts))
+        stream = self._sources[logid]
         with trace_span(self.tracer, "step"):
             out = ex.process_columnar(
-                ts, plain, nulls, stream=self._sources[logid])
+                ts, _plain_columns(cols, ex.input_columns(stream)),
+                nulls, stream=stream)
         self._note_join_stats(ex, logid)
         return out
 
@@ -1534,12 +1577,15 @@ def _session_columns(cols: dict, wanted: frozenset) -> dict:
     return out
 
 
-def _plain_columns(cols: dict) -> dict:
+def _plain_columns(cols: dict, wanted: frozenset | None = None) -> dict:
     """Decoded payload columns (kind, arr, dict) -> plain numpy arrays
     for the join's columnar ingest: string columns gather through their
-    payload dictionary (one vectorized fancy-index, no per-row Python)."""
+    payload dictionary (one vectorized fancy-index, no per-row Python).
+    `wanted`: the columns the plan reads (None: all of them)."""
     out = {}
     for name, (kind, arr, d) in cols.items():
+        if wanted is not None and name not in wanted:
+            continue
         if kind == "str":
             out[name] = np.asarray(d, object)[arr]
         else:

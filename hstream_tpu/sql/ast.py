@@ -121,8 +121,11 @@ class JoinClause:
     join_type: str           # INNER / LEFT / OUTER
     right: StreamRef
     within: Interval | None  # None = stream-table join (JOIN TABLE(x))
+                             # or a window join (WITHIN WINDOW)
     on: Expr
     table: bool = False      # right side is a keyed last-value table
+    window: bool = False     # WITHIN WINDOW: pairs of the same window of
+                             # the statement's GROUP BY window
 
 
 @dataclass(frozen=True)

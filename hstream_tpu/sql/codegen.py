@@ -385,7 +385,7 @@ def mesh_exclusion_reason(plan: plans.Plan) -> str | None:
     node = plan.node
     if not isinstance(node, AggregateNode):
         return "stateless plans have no device state to shard"
-    return single_chip_reason(node)
+    return single_chip_reason(node, plan.join)
 
 
 def explain_text(plan: plans.Plan) -> str:
@@ -427,6 +427,13 @@ def explain_text(plan: plans.Plan) -> str:
             if getattr(plan.join, "table", False):
                 lines.insert(0, f"JOIN TABLE({plan.join.right.name}) "
                                 "[keyed last-value]")
+            elif getattr(plan.join, "window", False):
+                w = getattr(node, "window", None)
+                lines.insert(0, f"JOIN {plan.join.right.name} "
+                                "WITHIN WINDOW [window join: pairs of "
+                                f"one TUMBLING {getattr(w, 'size_ms', '?')}"
+                                "ms window; event time = the minimum "
+                                "over both sources]")
             else:
                 lines.insert(0, f"JOIN {plan.join.right.name} "
                                 f"WITHIN {plan.join.within.ms}ms")

@@ -3,7 +3,8 @@
 Grammar parity with the reference's BNFC grammar (hstream-sql/etc/SQL.cf):
 statements SELECT / CREATE (STREAM [AS] | VIEW | SINK CONNECTOR) / INSERT
 (fields, 'json', "binary") / SHOW / DROP [IF EXISTS] / TERMINATE /
-EXPLAIN; SELECT with FROM + [JOIN ... WITHIN(...) ON ...] + WHERE +
+EXPLAIN; SELECT with FROM + [JOIN ... WITHIN (...) | WITHIN WINDOW,
+ON ...] + WHERE +
 GROUP BY [, window] + HAVING + [QUALIFY agg >= MAX(agg) OVER (PARTITION
 BY winStart, winEnd)] + [EMIT CHANGES]; value expressions with
 || && arithmetic, scalar functions, set functions, BETWEEN, NOT;
@@ -293,6 +294,9 @@ class Parser:
         join = None
         if self.at_kw("INNER", "LEFT", "OUTER", "JOIN"):
             join = self.parse_join()
+            if self.at_kw("INNER", "LEFT", "OUTER", "JOIN"):
+                self.err("a second JOIN is not supported: a statement "
+                         "joins two streams")
         where = None
         if self.try_kw("WHERE"):
             where = self.parse_cond()
@@ -413,6 +417,14 @@ class Parser:
             return ast.JoinClause(jt, right, None, on, table=True)
         right = self.parse_stream_ref()
         self.eat_kw("WITHIN")
+        # WITHIN WINDOW: a pair joins where both records fall in the
+        # same window of the statement's own GROUP BY window (which
+        # windows that may be is refine's to say); WITHIN (<interval>)
+        # is the interval join
+        if self.try_kw("WINDOW"):
+            self.eat_kw("ON")
+            on = self.parse_cond()
+            return ast.JoinClause(jt, right, None, on, window=True)
         self.eat_sym("(")
         within = self.parse_interval()
         self.eat_sym(")")

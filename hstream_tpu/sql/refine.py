@@ -202,7 +202,9 @@ def _validate_group_consistency(sel: ast.Select) -> None:
 
 def _validate_join(sel: ast.Select) -> None:
     join = sel.join
-    if not join.table:
+    if join.window:
+        _validate_window_join(sel)
+    elif not join.table:
         _validate_interval(join.within, "JOIN WITHIN")
     left_names = {sel.source.name, sel.source.alias} - {None}
     right_names = {join.right.name, join.right.alias} - {None}
@@ -241,6 +243,32 @@ def _validate_join(sel: ast.Select) -> None:
         if (sa <= left_names) == (sb <= left_names):
             raise SQLValidateError(
                 "each JOIN ON equality must relate both sides")
+
+
+def _validate_window_join(sel: ast.Select) -> None:
+    """`JOIN ... WITHIN WINDOW`: a pair joins where both records fall
+    in the same window of the statement's own GROUP BY window. One
+    shape runs (INNER, TUMBLING, a closed window's rows); every other
+    is refused here, by name."""
+    join = sel.join
+    if join.join_type not in ("INNER", "JOIN"):
+        raise SQLValidateError(
+            f"{join.join_type} JOIN ... WITHIN WINDOW is not supported: "
+            "a window join is INNER (a record without a partner in its "
+            "window gives no row)")
+    if sel.window is None:
+        raise SQLValidateError(
+            "JOIN ... WITHIN WINDOW needs the statement's GROUP BY "
+            "window: GROUP BY ..., TUMBLING (INTERVAL ...)")
+    if sel.window.kind != ast.WindowKind.TUMBLING:
+        raise SQLValidateError(
+            f"JOIN ... WITHIN WINDOW over a {sel.window.kind.name} "
+            "window is not supported: pairs join within one TUMBLING "
+            "window")
+    if sel.emit_changes:
+        raise SQLValidateError(
+            "JOIN ... WITHIN WINDOW with EMIT CHANGES is not supported: "
+            "a window join gives a window's rows when it closes")
 
 
 def _qualifiers(e: Expr) -> set[str]:

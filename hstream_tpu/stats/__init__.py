@@ -114,6 +114,15 @@ PER_STREAM_COUNTERS = [
                                # the survivors' buffer holds: a second
                                # fetch, of the full column (label:
                                # query id)
+    "join_rows_past_retention",  # rows a window join met behind its
+                               # eviction bound (their window had
+                               # closed); 0 where event time is the
+                               # minimum over the sources (label:
+                               # query id)
+    "join_codes_reclaimed",    # join-key codes a window join freed at
+                               # a window's close (label: query id)
+    "join_matches",            # pairs a device join matched (label:
+                               # query id)
     "read_extracts",           # pull-query serves that actually ran an
                                # executor peek (read-plane contract:
                                # ~one per view per close cycle, not one
@@ -154,6 +163,10 @@ GAUGES = [
                               # that hold a key id
     "key_capacity",           # per window-lattice query: key ids the
                               # device planes have rows for
+    "join_codes_live",        # per join query: join-key codes that
+                              # name a stored row
+    "join_store_rows_left",   # per join query: rows in the left /
+    "join_store_rows_right",  # right side's store
     "query_watermark_ms",     # per query: event-time watermark
                               # (absolute ms) of the query's executor
     "query_watermark_lag_ms", # per query: wall clock - watermark (the

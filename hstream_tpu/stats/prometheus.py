@@ -46,7 +46,10 @@ QUERY_LABEL_COUNTERS = frozenset({"query_restarts", "snapshot_fallbacks",
                                   "queries_adopted", "key_retirements",
                                   "keys_retired", "key_ids_reused",
                                   "close_rows_kept", "close_groups",
-                                  "close_tie_refetches"})
+                                  "close_tie_refetches",
+                                  "join_rows_past_retention",
+                                  "join_codes_reclaimed",
+                                  "join_matches"})
 
 # counters whose label is a closed vocabulary outside both the stream
 # and query namespaces (kernel families): never liveness-filtered
@@ -102,6 +105,12 @@ _HELP = {
     "close_tie_refetches": "top closes that fetched the full column "
                            "because more rows tied than the survivors' "
                            "buffer holds",
+    "join_rows_past_retention": "rows a window join met behind its "
+                                "eviction bound (their window had "
+                                "closed)",
+    "join_codes_reclaimed": "join-key codes a window join freed at a "
+                            "window's close",
+    "join_matches": "pairs a device join matched",
     "device_h2d_bytes": "host-to-device bytes on the staging path",
     "device_d2h_bytes": "device-to-host bytes on the close/changelog "
                         "drain paths",
@@ -132,6 +141,12 @@ _HELP = {
     "keys_live": "group keys that hold a key id in the query's "
                  "window lattice",
     "key_capacity": "key ids the query's device planes have rows for",
+    "join_codes_live": "join-key codes that name a stored row of the "
+                       "query's join",
+    "join_store_rows_left": "rows in the left side's store of the "
+                            "query's join",
+    "join_store_rows_right": "rows in the right side's store of the "
+                             "query's join",
     "query_watermark_ms": "event-time watermark of the query's "
                           "executor (absolute ms)",
     "query_watermark_lag_ms": "wall clock minus the query's event-time "

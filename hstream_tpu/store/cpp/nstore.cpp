@@ -113,6 +113,7 @@ struct Reader {
   // logid -> {next, until}
   std::map<uint64_t, std::pair<int64_t, int64_t>> cursors;
   int64_t timeout_ms = -1;
+  uint64_t last_served = UINT64_MAX;  // the log whose item was read last
 };
 
 struct Store {
@@ -938,56 +939,79 @@ int64_t ns_reader_read(void* rh, int64_t max_records, uint8_t* out,
   Store* st = r->store;
   std::unique_lock<std::mutex> lk(st->mu);
 
+  // One item (a gap or a batch) of one log, appended at `off`. Returns
+  // 1 if it was emitted, 0 if the log has nothing to give now, -1 if the
+  // item does not fit in what is left of `out` (`off` is unchanged).
+  auto emit_one = [&](uint64_t logid, std::pair<int64_t, int64_t>& cur,
+                      size_t& off) -> int {
+    auto& [nxt, until] = cur;
+    if (nxt > until) return 0;
+    Log* log = st->get(logid);
+    if (!log) return 0;
+    if (log->trim_lsn >= nxt) {
+      int64_t hi = std::min(log->trim_lsn, until);
+      size_t need = emit_gap(out, cap, off, logid, 0, nxt, hi);
+      if (off + need > (size_t)cap) return -1;
+      off += need;
+      nxt = hi + 1;
+      return 1;
+    }
+    auto it = std::lower_bound(
+        log->index.begin(), log->index.end(), nxt,
+        [](const IndexEntry& e, int64_t v) { return e.lsn < v; });
+    if (it == log->index.end() || it->lsn > until) return 0;
+    std::string stored;
+    std::vector<uint32_t> lens;
+    int64_t tm;
+    uint32_t flags, raw_len;
+    if (!st->read_frame(*log, *it, &stored, &lens, &tm, &flags, &raw_len))
+      return 0;
+    std::string raw;
+    if (flags == COMP_ZLIB) {
+      raw.resize(raw_len);
+      uLongf dlen = raw_len;
+      if (uncompress(reinterpret_cast<Bytef*>(&raw[0]), &dlen,
+                     reinterpret_cast<const Bytef*>(stored.data()),
+                     stored.size()) != Z_OK)
+        return 0;
+    } else {
+      raw = std::move(stored);
+    }
+    size_t need = emit_batch(out, cap, off, logid, it->lsn, tm, lens, raw);
+    if (off + need > (size_t)cap) return -1;
+    off += need;
+    nxt = it->lsn + 1;
+    return 1;
+  };
+
+  // The logs are served in turn, one item each, from the log after the
+  // one served last (across calls): a reader of several logs under a
+  // backlog hands out their batches interleaved, where draining one log
+  // before looking at the next ran one source whole windows of event
+  // time ahead of the other. A reader of one log reads as it always did.
   auto poll = [&](size_t* produced) -> size_t {
     size_t off = 0;
     *produced = 0;
-    for (auto& [logid, cur] : r->cursors) {
-      auto& [nxt, until] = cur;
-      if (nxt > until) continue;
-      Log* log = st->get(logid);
-      if (!log) continue;
-      if (log->trim_lsn >= nxt) {
-        int64_t hi = std::min(log->trim_lsn, until);
-        size_t need = emit_gap(out, cap, off, logid, 0, nxt, hi);
-        if (off + need > (size_t)cap)
-          return *produced == 0 ? (size_t)-1 : off;
-        off += need;
-        nxt = hi + 1;
-        (*produced)++;
+    std::vector<uint64_t> ids;
+    ids.reserve(r->cursors.size());
+    for (auto& kv : r->cursors) ids.push_back(kv.first);
+    size_t n = ids.size(), first = 0;
+    for (size_t i = 0; i < n; i++)
+      if (ids[i] > r->last_served) { first = i; break; }
+    bool progressed = n > 0;
+    while (progressed) {
+      progressed = false;
+      for (size_t k = 0; k < n; k++) {
         if ((int64_t)*produced >= max_records) return off;
-      }
-      auto it = std::lower_bound(
-          log->index.begin(), log->index.end(), nxt,
-          [](const IndexEntry& e, int64_t v) { return e.lsn < v; });
-      for (; it != log->index.end(); ++it) {
-        if (it->lsn > until || (int64_t)*produced >= max_records) break;
-        std::string stored;
-        std::vector<uint32_t> lens;
-        int64_t tm;
-        uint32_t flags, raw_len;
-        if (!st->read_frame(*log, *it, &stored, &lens, &tm, &flags,
-                            &raw_len))
-          break;
-        std::string raw;
-        if (flags == COMP_ZLIB) {
-          raw.resize(raw_len);
-          uLongf dlen = raw_len;
-          if (uncompress(reinterpret_cast<Bytef*>(&raw[0]), &dlen,
-                         reinterpret_cast<const Bytef*>(stored.data()),
-                         stored.size()) != Z_OK)
-            break;
-        } else {
-          raw = std::move(stored);
+        uint64_t logid = ids[(first + k) % n];
+        int got = emit_one(logid, r->cursors[logid], off);
+        if (got < 0) return *produced == 0 ? (size_t)-1 : off;
+        if (got > 0) {
+          (*produced)++;
+          progressed = true;
+          r->last_served = logid;
         }
-        size_t need = emit_batch(out, cap, off, logid, it->lsn, tm, lens,
-                                 raw);
-        if (off + need > (size_t)cap)
-          return *produced == 0 ? (size_t)-1 : off;
-        off += need;
-        nxt = it->lsn + 1;
-        (*produced)++;
       }
-      if ((int64_t)*produced >= max_records) break;
     }
     return off;
   };

@@ -170,6 +170,7 @@ class MemLogReader(LogReader):
         # logid -> [next_lsn_to_read, until_lsn]
         self._cursors: dict[int, list[int]] = {}
         self._timeout_ms = -1
+        self._last_served = -1  # the log whose item was read last
 
     def start_reading(self, logid: int, from_lsn: int = LSN_MIN,
                       until_lsn: int = LSN_MAX) -> None:
@@ -186,34 +187,52 @@ class MemLogReader(LogReader):
         self._timeout_ms = timeout_ms
 
     def _poll_once(self, max_records: int) -> list[ReadResult]:
+        """Up to `max_records` items. Several logs are served in turn,
+        one item each, from the log after the one served last (across
+        calls), so that a backlog does not run one source whole windows
+        of event time ahead of another (the native reader does the
+        same); one log reads as it always did."""
         out: list[ReadResult] = []
         with self._store._lock:
-            for logid, cursor in self._cursors.items():
-                nxt, until = cursor
-                if nxt > until:
-                    continue
-                try:
-                    log = self._store._get(logid)
-                except LogNotFound:
-                    continue
-                # Surface a trim gap once if the cursor fell below trim point.
-                if log.trim_lsn >= nxt:
-                    hi = min(log.trim_lsn, until)
-                    out.append(GapRecord(logid, GapType.TRIM, nxt, hi))
-                    cursor[0] = nxt = hi + 1
+            ids = sorted(self._cursors)
+            first = next((i for i, logid in enumerate(ids)
+                          if logid > self._last_served), 0)
+            progressed = bool(ids)
+            while progressed and len(out) < max_records:
+                progressed = False
+                for k in range(len(ids)):
                     if len(out) >= max_records:
                         break
-                i = bisect.bisect_left(log.lsns, nxt)
-                while i < len(log.lsns) and len(out) < max_records:
-                    lsn = log.lsns[i]
-                    if lsn > until:
-                        break
-                    out.append(log.batches[lsn])
-                    cursor[0] = lsn + 1
-                    i += 1
-                if len(out) >= max_records:
-                    break
+                    logid = ids[(first + k) % len(ids)]
+                    item = self._next_item(logid)
+                    if item is not None:
+                        out.append(item)
+                        progressed = True
+                        self._last_served = logid
         return out
+
+    def _next_item(self, logid: int) -> ReadResult | None:
+        """One item (a gap or a batch) of one log, or None (caller
+        holds the store's lock)."""
+        cursor = self._cursors[logid]
+        nxt, until = cursor
+        if nxt > until:
+            return None
+        try:
+            log = self._store._get(logid)
+        except LogNotFound:
+            return None
+        # Surface a trim gap once if the cursor fell below trim point.
+        if log.trim_lsn >= nxt:
+            hi = min(log.trim_lsn, until)
+            cursor[0] = hi + 1
+            return GapRecord(logid, GapType.TRIM, nxt, hi)
+        i = bisect.bisect_left(log.lsns, nxt)
+        if i >= len(log.lsns) or log.lsns[i] > until:
+            return None
+        lsn = log.lsns[i]
+        cursor[0] = lsn + 1
+        return log.batches[lsn]
 
     def read(self, max_records: int) -> list[ReadResult]:
         if FAULTS.active:  # chaos probe; one branch when disarmed

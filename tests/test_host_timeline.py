@@ -323,11 +323,14 @@ def test_each_child_lies_inside_its_parent(run):
                     (child, parent)
                 checked.add(child)
     # a session query alone shows the session path's stages
-    # (tests/test_session_served.py holds their nesting), and a query
+    # (tests/test_session_served.py holds their nesting), a join query
+    # alone the join's (tests/test_new_users_served.py), and a query
     # whose keys churn alone retires any (tests/test_key_retire.py)
     assert checked == {c for c in parents
-                       if not c.startswith("session_")
+                       if not c.startswith(("session_", "join_"))
                        and c != "key_retire"}
+    assert not [n for _name, evs in run["lines"] for n, _a, _b in evs
+                if n.startswith(("join_", "dispatch:join"))]
 
 
 def test_top_level_stages_cover_the_task_threads_wall(run):
