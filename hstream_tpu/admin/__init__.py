@@ -77,7 +77,8 @@ def cmd_stats(stub, args) -> list[dict]:
         print(json.dumps({r.pop("key"): r for r in out}, indent=2,
                          sort_keys=True))
         return []
-    label = {"streams": "stream", "subscriptions": "subscription",
+    label = {"streams": "stream", "views": "view",
+             "subscriptions": "subscription",
              "queries": "query"}.get(args.entity, "key")
     return [{label: r.pop("key"), **r} for r in out]
 
@@ -487,7 +488,8 @@ def main(argv=None) -> int:
                        help="per-entity rate-family tables off the "
                             "multi-level ladders (1min/10min/1h)")
     p.add_argument("entity", nargs="?", default="streams",
-                   choices=["streams", "subscriptions", "queries"])
+                   choices=["streams", "views", "subscriptions",
+                            "queries"])
     p.add_argument("--interval", default="1min",
                    choices=["1min", "10min", "1h"],
                    help="trailing ladder window the rates cover")

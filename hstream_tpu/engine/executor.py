@@ -53,7 +53,13 @@ from hstream_tpu.engine.expr import (
     eval_host_vec,
 )
 from hstream_tpu.engine.keytable import KeyTable
-from hstream_tpu.engine.plan import AggKind, AggregateNode, AggSpec, WindowTop
+from hstream_tpu.engine.plan import (
+    AggKind,
+    AggregateNode,
+    AggSpec,
+    WindowTop,
+    emitted_group_cols,
+)
 from hstream_tpu.engine.types import (
     ColumnType,
     HostBatch,
@@ -159,6 +165,16 @@ class QueryExecutor:
             if not isinstance(k, Col):
                 raise SQLCodegenError("GROUP BY supports plain columns")
             self.group_cols.append(k.name)
+
+        # the names a reader may pin the group key by (`peek_key`): an
+        # emitted row carries each group column's own value under them,
+        # in `group_cols` order. None where a later select item takes
+        # one of the names for something else (`SELECT k, COUNT(*) AS
+        # k`: the emitted `k` is the count)
+        taken = [name for name, _e in node.post_projections or []]
+        names = emitted_group_cols(node)
+        self.emitted_key_cols: list[str] | None = names if all(
+            taken.count(n) <= 1 for n in names) else None
 
         self.window: FixedWindow | None = node.window
         self.dicts: dict[str, StringDictionary] = {
@@ -1582,20 +1598,23 @@ class QueryExecutor:
         return self._key_cols
 
     def _decode_extract_batch(self, packed: np.ndarray,
-                              starts: Sequence[int | None]
+                              starts: Sequence[int | None],
+                              kids: np.ndarray | None = None
                               ) -> "ColumnarEmit | list[dict[str, Any]]":
         """Vectorized decode of a batched extract buffer [P, 2+rows, K]
         into a ColumnarEmit: key decode is a cached reverse-index
         gather, agg finalization is columnar numpy, HAVING evaluates
         columnwise — no per-kid Python loop. `starts[p]` is window p's
-        absolute start (None when windowless)."""
+        absolute start (None when windowless). `kids` are the key ids
+        of the buffer's last axis where it holds some keys' rows and
+        not the whole table's (a keyed peek)."""
         count = packed[:, 0, :]
-        widx, kids = np.nonzero(count > 0)
+        widx, at = np.nonzero(count > 0)
         if len(widx) == 0:
             return []
         return self._finish_extract(
-            widx, kids,
-            lattice.gather_extract_batch(self.spec, packed, widx, kids),
+            widx, at if kids is None else kids[at],
+            lattice.gather_extract_batch(self.spec, packed, widx, at),
             starts)
 
     def _finish_extract(self, widx: np.ndarray, kids: np.ndarray,
@@ -1706,17 +1725,66 @@ class QueryExecutor:
         nothing, and the view's closed rows are the whole answer."""
         if self._top is not None:
             return []
-        if self.window is None:
-            starts, slots = [None], [0]
-        else:
-            starts = sorted(self._open)
-            if not starts:
-                return []
-            slots = [self._open[s].slot for s in starts]
+        starts, slots = self._live_slots()
+        if not starts:
+            return []
         with kernel_family("peek", self.dispatch_observer):
             packed = np.asarray(self._extract_slots(
                 self.state, self._pad_slots(slots)))
             return self._decode_extract_batch(packed, starts)
+
+    def _live_slots(self) -> tuple[list[int | None], list[int]]:
+        """(absolute starts, slots) of the windows a peek reads, oldest
+        first: every open window, or the one slot of a windowless
+        plan."""
+        if self.window is None:
+            return [None], [0]
+        starts = sorted(self._open)
+        return starts, [self._open[s].slot for s in starts]
+
+    # contract: dispatches<=1 fetches<=1
+    def peek_key(self, key: tuple
+                 ) -> "ColumnarEmit | list[dict[str, Any]] | None":
+        """`peek()`'s rows of ONE group key (`key`: its values in
+        `group_cols` order), for a pull whose WHERE pins the key: that
+        key's cells of every open window are extracted, fetched and
+        decoded ([n_slots, 2+rows, 1], one shape a plan whatever is
+        open) and not the table's [P, 2+rows, K]. The key is found as
+        a batch's is, by `==` and `hash` in the key dictionary. None
+        where nothing was dispatched: the dictionary has no such key
+        (no batch named it, or its id was retired), no window is open,
+        or the plan keeps a window's top (`peek()` has no rows)."""
+        if self._top is not None:
+            return None
+        kid = self._key_ids.get(key)
+        starts, slots = self._live_slots()
+        if kid is None or not starts:
+            return None
+        kids = np.array([kid], np.int32)
+        with kernel_family("peek", self.dispatch_observer):
+            packed = np.asarray(self._extract_slots(
+                self.state, self._pad_key_slots(slots), kids))
+            return self._decode_extract_batch(packed, starts, kids)
+
+    def _pad_key_slots(self, slots: list[int]) -> np.ndarray:
+        """A keyed peek's slot vector: padded (with -1) to the plan's
+        slot count, which bounds the open windows, so the program has
+        one shape a key capacity."""
+        padded = np.full(self.spec.n_slots, -1, np.int32)
+        padded[:len(slots)] = slots
+        return padded
+
+    # contract: dispatches<=1 fetches<=0
+    def build_peek_key(self) -> None:
+        """Build `peek_key`'s program for the table's capacity before a
+        reader needs it (the task does, where it pins a snapshot's
+        copy: server/tasks.py `_build_pin`): one run over padding
+        alone, nothing fetched. A plan no view peeks by key builds
+        nothing."""
+        if (self.peek_key is not None and self._top is None
+                and not self.emit_changes):
+            self._extract_slots(self.state, self._pad_key_slots([]),
+                                np.zeros(1, np.int32))
 
     # contract: dispatches<=0 fetches<=1
     def block_until_ready(self) -> None:

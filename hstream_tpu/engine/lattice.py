@@ -638,15 +638,18 @@ def _reset_slots_tree(spec: LatticeSpec, state, rs):
     return out
 
 
-def _extract_slots_packed(spec: LatticeSpec, state, slots):
+def _extract_slots_packed(spec: LatticeSpec, state, slots, kids=None):
     """Vmapped extract of the slot columns named by `slots` (padding
     entries < 0 produce all-zero packed rows, so the host decode's
-    count>0 filter skips them) -> packed int32 [P, 2+rows, K]."""
+    count>0 filter skips them) -> packed int32 [P, 2+rows, K]. With
+    `kids` (i32[Q]) only those keys' rows are read and finalized:
+    packed int32 [P, 2+rows, Q], cell q the key `kids[q]`."""
     valid = slots >= 0
     safe = jnp.where(valid, slots, 0)
+    keys = slice(None) if kids is None else kids
 
     def one(slot):
-        col = {k: v[:, slot] for k, v in state.items()
+        col = {k: v[keys, slot] for k, v in state.items()
                if k not in ("slot_start", "touched")}
         with jax.named_scope("finalize"):
             outs = finalize_column(spec, col)
@@ -818,11 +821,14 @@ def unpack_top_rows(spec: LatticeSpec, top: np.ndarray):
 def build_extract_slots(spec: LatticeSpec):
     """peek_slots(state, slots i32[P]) -> packed i32[P, 2+rows, K]: the
     read-only half of the fused close — one dispatch serves a pull
-    query / view peek over every open window."""
+    query / view peek over every open window. With `kids` i32[Q], the
+    peek of a pull that pins its group key: packed i32[P, 2+rows, Q],
+    those keys' rows alone. One function, so both are PEEK_PROGRAM in a
+    trace."""
 
     @jax.jit
-    def peek_slots(state, slots):  # PEEK_PROGRAM
-        return _extract_slots_packed(spec, state, slots)
+    def peek_slots(state, slots, kids=None):  # PEEK_PROGRAM
+        return _extract_slots_packed(spec, state, slots, kids)
 
     return peek_slots
 

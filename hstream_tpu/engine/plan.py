@@ -12,7 +12,7 @@ from __future__ import annotations
 import enum
 from dataclasses import dataclass, field
 
-from hstream_tpu.engine.expr import Expr
+from hstream_tpu.engine.expr import Col, Expr
 from hstream_tpu.engine.types import Schema
 from hstream_tpu.engine.window import WindowSpec
 
@@ -113,6 +113,27 @@ def plan_source(node: PlanNode) -> SourceNode:
         else:
             raise ValueError(f"no single source under {type(node).__name__}")
     return node
+
+
+def emitted_group_cols(node: AggregateNode) -> list[str]:
+    """Names under which the group-key columns appear in EMITTED rows.
+
+    Without post projections rows carry the plan column names; with them
+    (any aliased/computed select item) a key column emits under the name
+    of the first projected item that is exactly that column — e.g.
+    `SELECT city AS c ... GROUP BY city` emits the key as "c". Consumers
+    keying on emitted rows (materialized views) must use these names."""
+    out = []
+    for g in node.group_keys:
+        if not isinstance(g, Col):
+            continue
+        name = g.name
+        for out_name, e in (node.post_projections or []):
+            if isinstance(e, Col) and e.name == g.name:
+                name = out_name
+                break
+        out.append(name)
+    return out
 
 
 def single_chip_reason(node: AggregateNode, join=None) -> str | None:

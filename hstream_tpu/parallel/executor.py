@@ -30,6 +30,10 @@ class ShardedQueryExecutor(QueryExecutor):
     # the sharded drain path fetches synchronously (one transfer of
     # the per-shard stack); the deferral flag would be a silent no-op
     supports_deferred_changes = False
+    # no keyed peek: a key's cells are partials spread over the data
+    # shards of one key shard; a pull that pins its key takes the whole
+    # peek and the read plane's filter (server/views.py)
+    peek_key = None
 
     def __init__(self, node: AggregateNode, schema: Schema, *, mesh,
                  data_axis: str = "data", key_axis: str = "key",

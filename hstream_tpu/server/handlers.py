@@ -1300,14 +1300,18 @@ class HStreamApiServicer:
             from hstream_tpu.stats.timeseries import INTERVAL_NAMES
 
             entity = str(args.get("entity") or "streams")
+            # `views`: the stream scope's rows of the views alone, each
+            # with the read plane's counters beside its families
+            views = entity in ("views", "view")
             scope = {"streams": "stream", "stream": "stream",
+                     "views": "stream", "view": "stream",
                      "subscriptions": "subscription",
                      "subscription": "subscription",
                      "queries": "query", "query": "query"}.get(entity)
             if scope is None:
                 raise ServerError(
                     f"unknown stats entity {entity!r} "
-                    f"(streams|subscriptions|queries)")
+                    f"(streams|views|subscriptions|queries)")
             interval = str(args.get("interval") or "1min")
             if interval not in INTERVAL_NAMES:
                 raise ServerError(
@@ -1331,6 +1335,8 @@ class HStreamApiServicer:
             live = live_entity_keys(ctx, scope)
             keys = {k for k in keys
                     if k in live or k == TS_OVERFLOW_LABEL}
+            if views:
+                keys = set(ctx.views.names())
             for key in sorted(keys):
                 row = {"interval": interval}
                 for f in fams:
@@ -1341,6 +1347,10 @@ class HStreamApiServicer:
                     task = ctx.running_queries.get(key)
                     if task is not None:
                         row.update(task.engine_gauges())
+                if views:
+                    for c in ("read_extracts", "read_keyed_pulls",
+                              "read_scanned_pulls"):
+                        row[c] = ctx.stats.stream_stat_get(c, key)
                 out[key] = row
         elif cmd == "cluster-stats":
             # federation (ISSUE 15): fan the ClusterStats RPC out to
@@ -1537,16 +1547,20 @@ class HStreamApiServicer:
         """Pull-query serve through the read plane (ISSUE 20): the
         snapshot cache collapses N concurrent readers onto ONE executor
         extract per close cycle; `read_out_records` / `read_extracts`
-        carry the serve rates per view."""
+        carry the serve rates per view, `read_keyed_pulls` /
+        `read_scanned_pulls` how its computed pulls read it."""
         ctx = self.ctx
         cache = getattr(ctx, "read_cache", None)
         if cache is None:
             return serve_select_view(mat, select)
-        rows, _how, extracted = cache.serve_view(name, mat, select, sql)
+        rows, _how, extracted, read = cache.serve_view(name, mat, select,
+                                                       sql)
         try:
             ctx.stats.stat_add("read_out_records", name, float(len(rows)))
             if extracted:
                 ctx.stats.stream_stat_add("read_extracts", name)
+            if read is not None:
+                ctx.stats.stream_stat_add(f"read_{read}_pulls", name)
         except Exception:  # noqa: BLE001 — metrics must not fail reads
             pass
         return rows

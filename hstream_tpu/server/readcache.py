@@ -39,7 +39,7 @@ from collections import OrderedDict
 from typing import Any, Callable
 
 from hstream_tpu.common import locktrace
-from hstream_tpu.server.views import serve_parts, serve_select_view
+from hstream_tpu.server.views import serve_parts
 
 
 class _Entry:
@@ -102,6 +102,11 @@ class ReadCache:
         self.misses = 0          # snapshot recomputes
         self.bypasses = 0        # unversioned executors (never cached)
         self.extracts = 0        # serves that actually peeked the engine
+        # computed pulls (a hit is neither): those whose WHERE pinned
+        # the view's group key and read that key, and those that read
+        # every row of both halves
+        self.keyed_pulls = 0
+        self.scanned_pulls = 0
         self.evictions = 0
         self.invalidations = 0
         self.expand_hits = 0
@@ -119,7 +124,10 @@ class ReadCache:
     def stats(self) -> dict[str, int | float]:
         return {"hits": self.hits, "shared": self.shared,
                 "misses": self.misses, "bypasses": self.bypasses,
-                "extracts": self.extracts, "evictions": self.evictions,
+                "extracts": self.extracts,
+                "keyed_pulls": self.keyed_pulls,
+                "scanned_pulls": self.scanned_pulls,
+                "evictions": self.evictions,
                 "invalidations": self.invalidations,
                 "expand_hits": self.expand_hits,
                 "expand_misses": self.expand_misses,
@@ -133,24 +141,50 @@ class ReadCache:
             return True
         return (now - ent.t) * 1000.0 <= self.max_staleness_ms
 
+    def _compute(self, mat, select
+                 ) -> tuple[list[dict[str, Any]], tuple | None, bool, str]:
+        """One computed pull: the cut, then the serve outside the
+        task's lock. (rows, version of the cut, whether it peeked,
+        "keyed" | "scanned": how the cut read its halves)."""
+        closed, live, version, peeked, keyed = mat.snapshot_parts(select)
+        rows = serve_parts(closed, live, select, mat.tracer)
+        return rows, version, peeked, "keyed" if keyed else "scanned"
+
+    def _count_computed(self, peeked: bool, read: str) -> None:
+        """Caller holds `self._lock`."""
+        if peeked:
+            self.extracts += 1
+        if read == "keyed":
+            self.keyed_pulls += 1
+        else:
+            self.scanned_pulls += 1
+
+    def _bypass(self, mat, select
+                ) -> tuple[list[dict[str, Any]], str, bool, str]:
+        """An unversioned executor: correctness cannot be proven, so
+        its view never caches (and never goes stale)."""
+        rows, _version, peeked, read = self._compute(mat, select)
+        with self._lock:
+            self.bypasses += 1
+            self._count_computed(peeked, read)
+        return rows, "bypass", peeked, read
+
     # contract: dispatches<=1 fetches<=1
     def serve_view(self, name: str, mat, select, sql: str
-                   ) -> tuple[list[dict[str, Any]], str, bool]:
+                   ) -> tuple[list[dict[str, Any]], str, bool,
+                              str | None]:
         """Serve a pull query through the cache. Returns (rows, how,
-        extracted) with how in {"hit", "shared", "miss", "bypass"};
-        `extracted` is True only when THIS call ran an executor peek.
-        At most ONE extract runs per (view, statement, version) — the
+        extracted, read) with how in {"hit", "shared", "miss",
+        "bypass"}; `extracted` is True only when THIS call ran an
+        executor peek; `read` says how a computed pull read the view:
+        "keyed" (its WHERE pinned the group key), "scanned", or None
+        where nothing was computed (a hit, a shared flight). At most
+        ONE extract runs per (view, statement, version) — the
         close-cycle read contract."""
         key = ("snap", name, sql)
         version = mat.version()
         if version is None:
-            # unversioned executor: correctness cannot be proven, so
-            # this view never caches (and never goes stale)
-            rows = serve_select_view(mat, select)
-            with self._lock:
-                self.bypasses += 1
-                self.extracts += 1
-            return rows, "bypass", True
+            return self._bypass(mat, select)
         now = self._clock()
         while True:
             with self._lock:
@@ -159,7 +193,7 @@ class ReadCache:
                         and self._fresh(ent, now):
                     self._entries.move_to_end(key)
                     self.hits += 1
-                    return list(ent.value), "hit", False
+                    return list(ent.value), "hit", False, None
                 flight = self._flights.get(key)
                 if flight is None:
                     flight = _Flight()
@@ -171,29 +205,23 @@ class ReadCache:
             if flight.ok:
                 with self._lock:
                     self.shared += 1
-                return list(flight.rows), "shared", False
+                return list(flight.rows), "shared", False, None
             # leader failed or timed out: retry (probe again / lead)
             version = mat.version()
             if version is None:
-                rows = serve_select_view(mat, select)
-                with self._lock:
-                    self.bypasses += 1
-                    self.extracts += 1
-                return rows, "bypass", True
+                return self._bypass(mat, select)
             now = self._clock()
         try:
-            closed, live, got_version, peeked = mat.snapshot_parts(select)
-            rows = serve_parts(closed, live, select, mat.tracer)
+            rows, got_version, peeked, read = self._compute(mat, select)
             flight.rows = rows
             flight.ok = True
             with self._lock:
                 self.misses += 1
-                if peeked:
-                    self.extracts += 1
+                self._count_computed(peeked, read)
                 if got_version is not None:
                     self._store(key, rows, got_version,
                                 _rows_nbytes(rows), self._clock())
-            return list(rows), "miss", peeked
+            return list(rows), "miss", peeked, read
         finally:
             with self._lock:
                 self._flights.pop(key, None)

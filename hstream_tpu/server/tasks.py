@@ -958,7 +958,8 @@ class QueryTask(threading.Thread):
         table has grown since it last ran, run it now, beside the step
         and the close the growth rebuilt, and not at whichever snapshot
         comes next: once the table has the size the live set needs, a
-        snapshot builds nothing."""
+        snapshot builds nothing. Nor does a pull that pins its key: a
+        window lattice's keyed peek is built here too."""
         with self.state_lock:
             ex = self.executor
             planes = getattr(ex, "pinned_planes", None)
@@ -977,6 +978,7 @@ class QueryTask(threading.Thread):
             if ex.spec.n_keys != self._pin_keys_built:
                 self._pin_keys_built = ex.spec.n_keys
                 _pin(lattice_planes(ex))
+                ex.build_peek_key()
 
     def _snapshot_now(self, *, sync: bool = False) -> None:
         # pipeline barrier FIRST: _pending_ckps covers every submitted

@@ -62,6 +62,10 @@ class Materialization:
         # the read cache. Bumped under self._lock; probed lock-free (a
         # torn probe can only cause a spurious cache miss).
         self._version = 0
+        # the window starts the store holds, as of `_starts_version`:
+        # what a keyed pull probes (`_closed_rows`)
+        self._starts: list = []
+        self._starts_version = 0
 
     @property
     def tracer(self):
@@ -147,22 +151,67 @@ class Materialization:
         # analyze: ok lock-guard — deliberate lock-free monotone probe
         return (self._version, exv)
 
+    def _closed_rows(self, key: tuple | None) -> list[dict[str, Any]]:
+        """The closed half of a cut (caller holds `self._lock`): every
+        row, or with `key` (a pull that pins the group key) the rows
+        the store holds under `(winStart, key)`, one probe a window
+        start and no row walked. The set of window starts is read off
+        the store's own keys the first time a keyed pull meets a store
+        version, so it is never stale and `add_closed` pays nothing
+        for it; a pull pays one pass over the keys (no row, no
+        predicate) after a close. The hits come in the set's order,
+        not the store's: of one key they differ in winStart, which
+        `serve_parts` sorts by."""
+        if key is None:
+            return list(self._closed.values())
+        if self._starts_version != self._version:
+            self._starts = list(dict.fromkeys(k[0] for k in self._closed))
+            self._starts_version = self._version
+        closed = self._closed
+        return [closed[k] for k in ((ws, key) for ws in self._starts)
+                if k in closed]
+
+    def _peek(self, ex, key: tuple | None) -> tuple[Any, bool]:
+        """The live half of a cut and whether the executor was
+        dispatched for it: the pinned key's rows where the executor
+        peeks by key and emits the key under the view's names (the
+        window lattice on one device), else every live row (a session
+        or join executor, the mesh's; the WHERE filters them)."""
+        if key is not None:
+            peek_key = getattr(ex, "peek_key", None)
+            if peek_key is not None and getattr(
+                    ex, "emitted_key_cols", None) == self._group_cols:
+                live = peek_key(key)
+                return ([], False) if live is None else (live, True)
+        return ex.peek(), True
+
     def snapshot_parts(self, select: ast.Select | None = None
                        ) -> tuple[list[dict[str, Any]], Any,
-                                  tuple | None, bool]:
+                                  tuple | None, bool, bool]:
         """One consistent cut of (closed rows, live batch, version,
-        peeked) under the task's state lock — the read cache stores the
-        version alongside the served result so hits are exact.
+        peeked, keyed) under the task's state lock — the read cache
+        stores the version alongside the served result so hits are
+        exact.
 
-        With `select`, the closed-only fast path applies (ISSUE 20
-        satellite): a WHERE that bounds winEnd strictly below every
-        live window's earliest possible winEnd is served from the
-        materialization store alone — zero executor dispatches — which
-        in device mode means the arena is never extracted at all."""
+        With `select`, two things are pushed down into the cut. The
+        closed-only fast path (ISSUE 20 satellite): a WHERE that bounds
+        winEnd strictly below every live window's earliest possible
+        winEnd is served from the materialization store alone — zero
+        executor dispatches — which in device mode means the arena is
+        never extracted at all. And the keyed read (`keyed` True): a
+        WHERE that pins every group column to a literal (`_pinned_key`)
+        reads that key — the closed rows by the store's own key, the
+        live rows by the key's id (`peek_key`, where the executor has
+        one and emits the key under the view's names) — and not every
+        row of both halves. Either way the halves are a superset of the
+        statement's answer, chosen by `==` and `hash` as `eval_host`'s
+        `=` is; `serve_parts` applies the whole WHERE to them."""
+        key = _pinned_key(select, self._group_cols)
         task = self.task
         if task is None:
             with self._lock:
-                return list(self._closed.values()), [], None, False
+                return (self._closed_rows(key), [], None, False,
+                        key is not None)
         # a pull's two costs to ingest, named by who pays: the wait
         # for the task's lock, and the hold (closed-row copy + peek)
         # during which the task's `state_wait` runs
@@ -172,21 +221,20 @@ class Materialization:
             wait.end()
             with trace_span(tracer, "pull_hold"):
                 with self._lock:
-                    closed = list(self._closed.values())
+                    closed = self._closed_rows(key)
                     mver = self._version
                 ex = task.executor
                 live: Any = []
                 peeked = False
                 if ex is not None and hasattr(ex, "peek"):
                     if not _skip_live(ex, select):
-                        live = ex.peek()
-                        peeked = True
+                        live, peeked = self._peek(ex, key)
                     rv = getattr(ex, "read_version", None)
                     exv = rv() if rv is not None else None
                     version = None if exv is None else (mver, exv)
                 else:
                     version = (mver, None)
-        return closed, live, version, peeked
+        return closed, live, version, peeked, key is not None
 
 
 class ViewRegistry:
@@ -251,6 +299,48 @@ def _closed_only_bound(select: ast.Select | None
                                                          not best[1]):
                 best = cand
     return best
+
+
+def _pinned_key(select: ast.Select | None,
+                group_cols: list[str] | None) -> tuple | None:
+    """The group key a WHERE pins: the literal of every column of
+    `group_cols`, in their order, when for EACH of them some AND-level
+    conjunct is `col = literal` (either operand order; an unqualified
+    column, which is what `eval_host` reads a row's `col` by and
+    `Materialization._row_key` keys it by; a literal that is not
+    NULL, which `=` holds true of a NULL cell). None otherwise — no
+    WHERE, an OR above the equality, a group column left free, a view
+    without group columns — and the pull scans. Any row the WHERE
+    holds true of has `row[col] == literal` for every group column, so
+    it is among the rows a lookup of the returned tuple finds."""
+    if select is None or select.where is None or not group_cols:
+        return None
+    pins: dict[str, Any] = {}
+    stack = [select.where]
+    while stack:
+        e = stack.pop()
+        if isinstance(e, BinOp) and e.op == "AND":
+            stack.extend((e.left, e.right))
+            continue
+        if not isinstance(e, BinOp) or e.op != "=":
+            continue
+        col, lit = e.left, e.right
+        if isinstance(col, Lit):
+            col, lit = lit, col
+        if (isinstance(col, Col) and col.stream is None
+                and isinstance(lit, Lit) and lit.value is not None
+                and col.name in group_cols):
+            # two conjuncts on one column: either literal selects a
+            # superset of what both hold true of
+            pins.setdefault(col.name, lit.value)
+    if len(pins) != len(group_cols):
+        return None
+    key = tuple(pins[c] for c in group_cols)
+    try:
+        hash(key)
+    except TypeError:  # an array literal: no store key, no key id
+        return None
+    return key
 
 
 def _skip_live(ex, select: ast.Select | None) -> bool:
@@ -376,5 +466,5 @@ def serve_select_view(mat: Materialization,
                       select: ast.Select) -> list[dict[str, Any]]:
     """Execute a pull query against a materialization
     (reference Handler.hs:277-325: key filter + fixed-window slicing)."""
-    closed, live, _version, _peeked = mat.snapshot_parts(select)
+    closed, live, _version, _peeked, _keyed = mat.snapshot_parts(select)
     return serve_parts(closed, live, select, mat.tracer)

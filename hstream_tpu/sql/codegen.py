@@ -24,6 +24,7 @@ from hstream_tpu.engine.plan import (
     ProjectNode,
     SourceNode,
     WindowTop,
+    emitted_group_cols,  # noqa: F401 — the view path imports it here
     single_chip_reason,
 )
 from hstream_tpu.engine.types import ColumnType, Schema
@@ -471,27 +472,6 @@ def explain_text(plan: plans.Plan) -> str:
     if isinstance(plan, plans.CreateViewPlan):
         return f"CREATE VIEW {plan.view} AS\n" + explain_text(plan.select)
     return type(plan).__name__
-
-
-def emitted_group_cols(node: AggregateNode) -> list[str]:
-    """Names under which the group-key columns appear in EMITTED rows.
-
-    Without post projections rows carry the plan column names; with them
-    (any aliased/computed select item) a key column emits under the name
-    of the first projected item that is exactly that column — e.g.
-    `SELECT city AS c ... GROUP BY city` emits the key as "c". Consumers
-    keying on emitted rows (materialized views) must use these names."""
-    out = []
-    for g in node.group_keys:
-        if not isinstance(g, Col):
-            continue
-        name = g.name
-        for out_name, e in (node.post_projections or []):
-            if isinstance(e, Col) and e.name == g.name:
-                name = out_name
-                break
-        out.append(name)
-    return out
 
 
 def make_executor(plan: plans.SelectPlan, sample_rows=None, *,

@@ -127,6 +127,13 @@ PER_STREAM_COUNTERS = [
                                # executor peek (read-plane contract:
                                # ~one per view per close cycle, not one
                                # per reader; label: view name)
+    "read_keyed_pulls",        # computed pulls (a cache hit is none)
+                               # whose WHERE pinned the view's group
+                               # key: the closed row read by the store's
+                               # key, the live row by the key's id
+                               # (label: view name)
+    "read_scanned_pulls",      # computed pulls that read every row of
+                               # both halves (label: view name)
 ]
 
 # stream-scoped rate families, in the (name, bucket-widths) tuple

@@ -198,6 +198,11 @@ _HELP = {
     "read_extracts": "pull-query serves that actually ran an executor "
                      "peek (~one per view per close cycle, not one "
                      "per reader)",
+    "read_keyed_pulls": "computed pulls (a cache hit is none) whose "
+                        "WHERE pinned the view's group key and read "
+                        "that key alone",
+    "read_scanned_pulls": "computed pulls that read every closed and "
+                          "live row of the view",
     "read_cache_hit_ratio": "snapshot-cache hit ratio over all "
                             "versioned pull-query serves",
     "read_cache_bytes": "bytes held by the read-plane snapshot + "

@@ -228,6 +228,9 @@ def _golden_holder() -> StatsHolder:
     # read plane (ISSUE 20): view-labeled extract counter, the
     # read_out_records rate ladder, and the cache gauges
     stats.stream_stat_add("read_extracts", "v1", 2)
+    # how its computed pulls read the view (ISSUE 36)
+    stats.stream_stat_add("read_keyed_pulls", "v1", 5)
+    stats.stream_stat_add("read_scanned_pulls", "v1", 1)
     stats.stat_add("read_out_records", "v1", 9.0, now=BASE / 1000)
     stats.gauge_set("read_cache_hit_ratio", "", 0.75)
     stats.gauge_set("read_cache_bytes", "", 16384)

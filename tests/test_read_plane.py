@@ -96,9 +96,9 @@ def test_cache_hit_is_byte_identical_and_close_invalidates():
     sel = _pull("SELECT * FROM v;")
     cache = ReadCache()
 
-    r1, how1, x1 = cache.serve_view("v", mat, sel, "q1")
+    r1, how1, x1, _read = cache.serve_view("v", mat, sel, "q1")
     assert (how1, x1, ex.peeks) == ("miss", True, 1)
-    r2, how2, x2 = cache.serve_view("v", mat, sel, "q1")
+    r2, how2, x2, _read = cache.serve_view("v", mat, sel, "q1")
     assert (how2, x2, ex.peeks) == ("hit", False, 1)  # no second peek
     assert _canon(r1) == _canon(r2)
     # byte-identical to the uncached pipeline at the same version
@@ -109,7 +109,7 @@ def test_cache_hit_is_byte_identical_and_close_invalidates():
                      "winEnd": BASE + 10_000}])
     ex.live_rows = []
     ex.ver += 1
-    r3, how3, _ = cache.serve_view("v", mat, sel, "q1")
+    r3, how3, _, _read = cache.serve_view("v", mat, sel, "q1")
     assert how3 == "miss"  # version advanced -> stale entry invalid
     assert _canon(r3) == _canon(serve_select_view(mat, sel))
     assert any(r["c"] == 7 for r in r3)
@@ -118,7 +118,7 @@ def test_cache_hit_is_byte_identical_and_close_invalidates():
     ex.live_rows = [{"k": "a", "c": 1, "winStart": BASE + 10_000,
                      "winEnd": BASE + 20_000}]
     ex.ver += 1
-    r4, how4, _ = cache.serve_view("v", mat, sel, "q1")
+    r4, how4, _, _read = cache.serve_view("v", mat, sel, "q1")
     assert how4 == "miss"
     assert _canon(r4) == _canon(serve_select_view(mat, sel))
     assert cache.hit_ratio() == pytest.approx(1 / 4)
@@ -133,9 +133,9 @@ def test_distinct_statements_cache_separately():
     cache = ReadCache()
     all_sel = _pull("SELECT * FROM v;")
     one_sel = _pull("SELECT * FROM v WHERE k = 'a';")
-    rows_all, _, _ = cache.serve_view("v", mat, all_sel,
+    rows_all, _, _, _read = cache.serve_view("v", mat, all_sel,
                                       "SELECT * FROM v;")
-    rows_one, how, _ = cache.serve_view("v", mat, one_sel,
+    rows_one, how, _, _read = cache.serve_view("v", mat, one_sel,
                                         "SELECT * FROM v WHERE k = 'a';")
     assert how == "miss"  # different statement, different entry
     assert len(rows_all) == 2 and len(rows_one) == 1
@@ -151,8 +151,8 @@ def test_unversioned_executor_bypasses_cache():
                            "winEnd": BASE + 10_000}])
     cache = ReadCache()
     sel = _pull("SELECT * FROM v;")
-    _, how1, x1 = cache.serve_view("v", mat, sel, "q")
-    _, how2, x2 = cache.serve_view("v", mat, sel, "q")
+    _, how1, x1, _read = cache.serve_view("v", mat, sel, "q")
+    _, how2, x2, _read = cache.serve_view("v", mat, sel, "q")
     assert (how1, how2) == ("bypass", "bypass")
     assert x1 and x2 and cache.stats()["bypasses"] == 2
 
@@ -167,15 +167,15 @@ def test_staleness_bound_expires_hits():
                       "winEnd": BASE + 10_000}])
     sel = _pull("SELECT * FROM v;")
     cache = ReadCache(max_staleness_ms=250.0, clock=lambda: now[0])
-    _, how1, _ = cache.serve_view("v", mat, sel, "q")
+    _, how1, _, _read = cache.serve_view("v", mat, sel, "q")
     now[0] += 0.2  # +200ms: inside the bound
-    _, how2, _ = cache.serve_view("v", mat, sel, "q")
+    _, how2, _, _read = cache.serve_view("v", mat, sel, "q")
     now[0] += 0.2  # +400ms total: past the bound, version unchanged
-    r3, how3, _ = cache.serve_view("v", mat, sel, "q")
+    r3, how3, _, _read = cache.serve_view("v", mat, sel, "q")
     assert (how1, how2, how3) == ("miss", "hit", "miss")
     assert _canon(r3) == _canon(serve_select_view(mat, sel))
     # recompute restamps the entry: fresh again
-    _, how4, _ = cache.serve_view("v", mat, sel, "q")
+    _, how4, _, _read = cache.serve_view("v", mat, sel, "q")
     assert how4 == "hit"
 
 
@@ -444,6 +444,30 @@ def test_pull_query_cached_end_to_end(server_stub):
     assert ctx.stats.stat_ladder("read_out_records",
                                  "rpview")["total"] > 0
     assert ctx.stats.stream_stat_get("read_extracts", "rpview") >= 1
+    # how the computed pulls read the view (ISSUE 36): every pull so
+    # far scanned; one that pins the group key reads that key; a hit
+    # is neither. Through the counters, `admin stats views`, /metrics.
+    from hstream_tpu.admin import _admin
+    from hstream_tpu.stats.prometheus import render_metrics
+
+    scanned = ctx.stats.stream_stat_get("read_scanned_pulls", "rpview")
+    assert scanned >= 1
+    assert ctx.stats.stream_stat_get("read_keyed_pulls", "rpview") == 0
+    for _ in range(2):   # computed, then a hit
+        resp = stub.ExecuteQuery(pb.CommandQuery(
+            stmt_text="SELECT * FROM rpview WHERE city = 'la';"))
+        got = [rec.struct_to_dict(s) for s in resp.result_set]
+        assert {r["city"] for r in got} == {"la"} and len(got) >= 1
+    (row,) = [r for r in _admin(stub, "stats", entity="views")
+              if r["key"] == "rpview"]
+    assert row["read_keyed_pulls"] == 1
+    assert row["read_scanned_pulls"] == scanned
+    assert row["read_extracts"] >= 1 and "read_out_records_total" in row
+    assert ctx.read_cache.stats()["keyed_pulls"] == 1
+    text = render_metrics(ctx)
+    assert 'hstream_read_keyed_pulls_total{stream="rpview"} 1' in text
+    assert (f'hstream_read_scanned_pulls_total{{stream="rpview"}} '
+            f'{scanned}') in text
     # late record (GRACE 0: dropped) — the cached serve stays exact vs
     # the uncached pipeline (compared pre-wire, where types match)
     _append(stub, "rpsrc", [{"city": "sf"}], [BASE + 1000])
@@ -451,7 +475,7 @@ def test_pull_query_cached_end_to_end(server_stub):
     sel = _pull("SELECT * FROM rpview;")
     deadline = time.time() + 10
     while time.time() < deadline:
-        cached, _how, _x = ctx.read_cache.serve_view(
+        cached, _how, _x, _read = ctx.read_cache.serve_view(
             "rpview", mat, sel, "SELECT * FROM rpview;")
         direct = serve_select_view(mat, sel)
         if _canon(cached) == _canon(direct):
@@ -482,9 +506,9 @@ def test_steady_state_pulls_compile_nothing(server_stub, retrace_guard):
     """The read-plane retrace gate (ISSUE 20): over a live view whose
     windows keep closing (1 s windows, 200 ms of stream a batch), 50
     steady batches each followed by a version-miss pull (one batched
-    peek extract), the same pull again (a cache hit, no device) and a
-    closed-only pull (the fast path, never peeks) compile ZERO new XLA
-    executables."""
+    peek extract), the same pull again (a cache hit, no device), a
+    closed-only pull (the fast path, never peeks) and a pull that pins
+    its key (the keyed peek) compile ZERO new XLA executables."""
     from hstream_tpu.client.producer import encode_batch
 
     stub, ctx = server_stub
@@ -507,6 +531,13 @@ def test_steady_state_pulls_compile_nothing(server_stub, retrace_guard):
         for sql in ("SELECT * FROM rgview;", "SELECT * FROM rgview;",
                     "SELECT * FROM rgview WHERE winEnd < 1;"):
             stub.ExecuteQuery(pb.CommandQuery(stmt_text=sql))
+        if i >= warm:
+            # a pull that pins the group key is first met under the
+            # guard: its program was built with the snapshot's copy
+            # (`_build_pin`), whatever the key and whatever is open
+            stub.ExecuteQuery(pb.CommandQuery(
+                stmt_text=f"SELECT * FROM rgview WHERE device = "
+                          f"'d{i % 100}';"))
 
     for i in range(warm):
         step(i)
@@ -517,9 +548,11 @@ def test_steady_state_pulls_compile_nothing(server_stub, retrace_guard):
     after = ctx.read_cache.stats()
     # all three kinds of serve ran under the guard: a batch gives one
     # recompute that peeks, hits, and one recompute that does not peek
-    d = {k: after[k] - before[k] for k in ("hits", "misses", "extracts")}
+    d = {k: after[k] - before[k]
+         for k in ("hits", "misses", "extracts", "keyed_pulls")}
     assert d["extracts"] >= steady and d["hits"] > 0
     assert d["misses"] - d["extracts"] >= steady
+    assert before["keyed_pulls"] == 0 and d["keyed_pulls"] == steady
 
 
 # ---- concurrent readers under the lock-order witness ------------------------
@@ -556,7 +589,7 @@ def test_concurrent_readers_exact_and_cycle_free():
 
         def reader():
             while not stop.is_set():
-                rows, how, _ = cache.serve_view("v", mat, sel, "q")
+                rows, how, _, _read = cache.serve_view("v", mat, sel, "q")
                 got = _canon(rows)
                 with canon_lock:
                     ok = got in canonical
@@ -580,3 +613,293 @@ def test_concurrent_readers_exact_and_cycle_free():
         assert st["hits"] + st["shared"] + st["misses"] > 0
     finally:
         LOCKTRACE.disarm()
+
+
+# ---- the keyed read: a WHERE that pins the group key (ISSUE 36) -------------
+
+
+class _KeyedFakeEx(_FakeEx):
+    """`_FakeEx` with a keyed peek: the live rows by their group key, in
+    a dictionary as the executor's key ids are (`==` and `hash`)."""
+
+    def __init__(self, live_rows, key_cols, live_lo):
+        super().__init__(live_rows, live_lo)
+        self.emitted_key_cols = key_cols
+        self.key_peeks = 0
+        self._by_key: dict = {}
+        for r in self.live_rows:
+            self._by_key.setdefault(
+                tuple(r[c] for c in key_cols), []).append(r)
+
+    def peek_key(self, key):
+        rows = self._by_key.get(key)
+        if rows is None:
+            return None  # no such key: nothing dispatched
+        self.key_peeks += 1
+        return list(rows)
+
+
+_STORES = {  # kind -> (group columns, rows the store keeps)
+    "str": (["k"], 100_000), "int": (["n"], 100_000),
+    "two": (["k", "j"], 100_000), "evicted": (["k"], 17),
+    "reclosed": (["k"], 100_000), "nogroup": (None, 100_000),
+}
+
+
+def _seeded_view(kind: str, seed: int):
+    """A view over six closed windows and two open ones, its rows
+    drawn from `seed`: string keys `k` ('a'..'e'), integer keys `n`
+    (0..4), a second column `j` (0..1); closed batches arrive as lists
+    and as columnar emissions in turn. `evicted` keeps 17 rows (the
+    oldest windows are gone, one of them in part); `reclosed` closes
+    window 1 again, later, with other counts."""
+    group_cols, keep = _STORES[kind]
+    rng = np.random.default_rng([seed, len(kind)])
+    mat = Materialization(group_cols=group_cols, max_closed_rows=keep)
+
+    def rows_of(w, bump=0):
+        out = []
+        for k in range(5):
+            for j in range(2):
+                if rng.random() < 0.3:
+                    continue
+                out.append({"k": "abcde"[k], "n": k, "j": j,
+                            "c": int(rng.integers(1, 9)) + bump,
+                            "winStart": BASE + w * 10_000,
+                            "winEnd": BASE + (w + 1) * 10_000})
+        if kind != "two":  # one row a (window, key)
+            out = [r for r in out if r["j"] == 0]
+        return out
+
+    def add(rows, columnar):
+        if columnar and rows:
+            rows = ColumnarEmit(
+                {c: np.array([r[c] for r in rows],
+                             object if c == "k" else np.int64)
+                 for c in rows[0]}, len(rows))
+        mat.add_closed(rows)
+
+    for w in range(6):
+        add(rows_of(w), columnar=w % 2 == 1)
+        if kind == "reclosed" and w == 3:
+            add(rows_of(1, bump=100), columnar=False)
+    live = rows_of(6) + rows_of(7)
+    ex = _KeyedFakeEx(live, group_cols or [], live_lo=BASE + 70_000)
+    mat.task = _FakeTask(ex)
+    return mat, ex
+
+
+def _scanned(mat, sel):
+    """The pull as it was before the keyed read, spelled out: every
+    closed row and the whole peek through the WHERE, the projection
+    and the sort."""
+    ex = mat.task.executor
+    live = [] if views_mod._skip_live(ex, sel) else ex.peek()
+    return views_mod.serve_parts(mat.dump(), live, sel)
+
+
+_KEYED_CASES = [
+    # (id, store, statement, keyed?, rows expected?)
+    ("key_left", "str", "SELECT * FROM v WHERE k = 'b';", True, True),
+    ("key_right", "str", "SELECT * FROM v WHERE 'b' = k;", True, True),
+    ("key_and_win_end_bound", "str",
+     f"SELECT * FROM v WHERE k = 'b' AND winEnd <= {BASE + 40_000};",
+     True, True),
+    ("key_and_aggregate", "str",
+     "SELECT k, c FROM v WHERE k = 'c' AND c > 3;", True, True),
+    ("key_twice", "str",
+     "SELECT * FROM v WHERE k = 'b' AND k = 'c';", True, False),
+    ("or_above_the_equality", "str",
+     "SELECT * FROM v WHERE k = 'b' OR c > 7;", False, True),
+    ("or_under_an_and", "str",
+     "SELECT * FROM v WHERE (k = 'b' OR k = 'c') AND c > 1;", False,
+     True),
+    ("non_group_column", "str", "SELECT * FROM v WHERE c = 3;", False,
+     True),
+    ("null_literal", "str", "SELECT * FROM v WHERE k = NULL;", False,
+     False),
+    ("qualified_column", "str", "SELECT * FROM v WHERE v.k = 'b';",
+     False, True),
+    ("int_key_by_float_literal", "int",
+     "SELECT * FROM v WHERE n = 2.0;", True, True),
+    ("int_key_by_fraction", "int", "SELECT * FROM v WHERE n = 2.5;",
+     True, False),
+    ("two_columns_one_free", "two", "SELECT * FROM v WHERE k = 'b';",
+     False, True),
+    ("two_columns_both_pinned", "two",
+     "SELECT * FROM v WHERE j = 1 AND k = 'b';", True, True),
+    ("key_never_seen", "str", "SELECT * FROM v WHERE k = 'zz';", True,
+     False),
+    ("evicted_windows", "evicted", "SELECT * FROM v WHERE k = 'a';",
+     True, True),
+    ("evicted_windows_projected", "evicted",
+     "SELECT c AS n FROM v WHERE k = 'd';", True, True),
+    ("window_reclosed", "reclosed", "SELECT * FROM v WHERE k = 'b';",
+     True, True),
+    ("no_group_columns", "nogroup", "SELECT * FROM v WHERE k = 'b';",
+     False, True),
+    ("no_where", "str", "SELECT * FROM v;", False, True),
+]
+
+
+@pytest.mark.parametrize(
+    "store,sql,keyed,some", [c[1:] for c in _KEYED_CASES],
+    ids=[c[0] for c in _KEYED_CASES])
+def test_keyed_pull_gives_the_scan_s_rows_in_its_order(store, sql, keyed,
+                                                       some):
+    """Over seeded stores, a pull gives the SAME list (rows, order)
+    whether its WHERE pinned the group key and it read that key, or it
+    read every row: and it is the statement, never a flag, that says
+    which."""
+    sel = _pull(sql)
+    seen = 0
+    for seed in range(6):
+        mat, ex = _seeded_view(store, seed)
+        want = _scanned(mat, sel)
+        ex.peeks = 0
+        got = serve_select_view(mat, sel)
+        assert got == want, (seed, sql)
+        assert _canon(got) == _canon(want)
+        assert mat.snapshot_parts(sel)[4] is keyed
+        if keyed:
+            assert ex.peeks == 0   # no whole peek, and no row walked:
+            closed = mat.snapshot_parts(sel)[0]
+            assert len(closed) <= 6
+        seen += len(want)
+    assert (seen > 0) is some, "the case is vacuous, or was meant to be"
+
+
+def test_keyed_pull_skips_the_peek_under_a_win_end_bound():
+    mat, ex = _seeded_view("str", 0)
+    sel = _pull(f"SELECT * FROM v WHERE k = 'b' AND winEnd <= "
+                f"{BASE + 40_000};")
+    _closed, live, _v, peeked, keyed = mat.snapshot_parts(sel)
+    assert keyed and not peeked and live == []
+    assert ex.peeks == 0 and ex.key_peeks == 0
+
+
+def test_keyed_and_scanned_pulls_are_counted_once_computed():
+    """`keyed_pulls` / `scanned_pulls` count computed pulls: a hit is
+    neither, a key without a live row is keyed and extracts nothing."""
+    mat, ex = _seeded_view("str", 1)
+    cache = ReadCache()
+    for sql in ("SELECT * FROM v WHERE k = 'b';",
+                "SELECT * FROM v WHERE k = 'b';",      # a hit
+                "SELECT * FROM v WHERE k = 'zz';",
+                "SELECT * FROM v WHERE c > 2;"):
+        cache.serve_view("v", mat, _pull(sql), sql)
+    st = cache.stats()
+    assert (st["keyed_pulls"], st["scanned_pulls"], st["hits"]) == (2, 1, 1)
+    assert st["extracts"] == 2 and ex.key_peeks == 1 and ex.peeks == 1
+    # an executor whose rows do not carry the key under the view's
+    # names keeps the whole peek: keyed store, scanned live half
+    ex.emitted_key_cols = None
+    rows, _how, extracted, read = cache.serve_view(
+        "v", mat, _pull("SELECT * FROM v WHERE k = 'c';"), "q")
+    assert read == "keyed" and extracted and ex.peeks == 2
+    assert rows == _scanned(mat, _pull("SELECT * FROM v WHERE k = 'c';"))
+
+
+_PEEK_PLANS = {
+    "tumbling": "SELECT device, COUNT(*) AS c, SUM(temp) AS t FROM s "
+                "GROUP BY device, TUMBLING (INTERVAL 10 SECOND) "
+                "GRACE BY INTERVAL 0 SECOND;",
+    "hop_having_projected":
+        "SELECT device AS d, COUNT(*) AS c, SUM(temp) + 1 AS t, "
+        "APPROX_COUNT_DISTINCT(temp) AS u FROM s GROUP BY device, "
+        "HOPPING (INTERVAL 60 SECOND, INTERVAL 10 SECOND) "
+        "GRACE BY INTERVAL 0 SECOND HAVING COUNT(*) > 2;",
+    "windowless": "SELECT device, COUNT(*) AS c FROM s GROUP BY device;",
+}
+
+
+def _fed_executor(plan_sql: str, mesh=None):
+    """A real executor over a little of a sensor stream: 12 devices,
+    40 s of event time, so a HOP plan has several open slots and some
+    groups fail the HAVING."""
+    from hstream_tpu.sql.codegen import make_executor
+
+    plan = stream_codegen("CREATE VIEW v AS " + plan_sql).select
+    ex = make_executor(plan, sample_rows=[{"device": "d0", "temp": 1.0}],
+                       mesh=mesh, initial_keys=16, batch_capacity=256)
+    rng = np.random.default_rng(7)
+    for i in range(4):
+        n = 60
+        ts = BASE + i * 10_000 + np.sort(rng.integers(0, 10_000, n))
+        dev = rng.integers(0, 12, n) * rng.integers(0, 2, n)  # uneven
+        rows = [{"device": f"d{d}", "temp": float(t % 7)}
+                for d, t in zip(dev.tolist(), ts.tolist())]
+        ex.process(rows, ts.tolist())
+    return plan, ex
+
+
+@pytest.mark.parametrize("mesh", [None, "1x8"])
+@pytest.mark.parametrize("plan", sorted(_PEEK_PLANS))
+def test_keyed_peek_is_the_whole_peek_filtered_by_the_key(plan, mesh,
+                                                          retrace_guard):
+    """`peek_key` gives the whole peek's rows of that key, in its
+    order, through HAVING and the projections; a key without an id
+    dispatches nothing; built at set-up, the first keyed peek compiles
+    nothing; the `[1x8]` executor has no keyed peek and a view over it
+    answers the same rows from the whole one."""
+    from hstream_tpu.sql.codegen import emitted_group_cols
+
+    if mesh is not None:
+        import jax
+
+        from hstream_tpu.parallel import make_mesh
+
+        assert jax.device_count() >= 8, f"{jax.device_count()} devices"
+        mesh = make_mesh(n_data=1, n_key=8)
+    select, ex = _fed_executor(_PEEK_PLANS[plan], mesh)
+    name = emitted_group_cols(select.node)[0]
+    assert ex.emitted_key_cols == [name]
+    whole = list(ex.peek())
+    assert whole, "nothing live: the case is vacuous"
+    mat = Materialization(group_cols=[name])
+    mat.task = _FakeTask(ex)
+    dispatches = []
+    fn = ex._extract_slots
+    ex._extract_slots = lambda *a: (dispatches.append(len(a)), fn(*a))[1]
+    ex.build_peek_key()   # as the task does where it pins (`_build_pin`)
+    built = len(dispatches)
+    with retrace_guard():
+        for d in range(13):   # d12: no batch named it
+            key = f"d{d}"
+            want = [r for r in whole if r.get(name) == key]
+            sel = _pull(f"SELECT * FROM v WHERE {name} = '{key}';")
+            before = len(dispatches)
+            if mesh is None:
+                got = ex.peek_key((key,))
+                if d == 12:
+                    assert got is None and len(dispatches) == before
+                else:
+                    assert list(got) == want, key
+                    assert dispatches[before:] == [3]   # state, slots, kids
+            else:
+                assert ex.peek_key is None
+            # and through the view, keyed or fallen back
+            assert serve_select_view(mat, sel) == \
+                views_mod.serve_parts([], whole, sel), key
+    assert built == (1 if mesh is None else 0)
+    if plan == "hop_having_projected":
+        assert len({r["winStart"] for r in whole}) > 2   # several slots
+        plain = _fed_executor(_PEEK_PLANS[plan].replace(
+            " HAVING COUNT(*) > 2", ""), mesh)[1]
+        assert len(list(plain.peek())) > len(whole)   # HAVING dropped some
+
+
+def test_a_projection_over_the_key_s_name_keeps_the_whole_peek():
+    """`SELECT device, COUNT(*) AS device`: the emitted `device` is the
+    count, so neither the executor nor a view over it reads by key."""
+    select, ex = _fed_executor(
+        "SELECT device, COUNT(*) AS device FROM s GROUP BY device, "
+        "TUMBLING (INTERVAL 10 SECOND) GRACE BY INTERVAL 0 SECOND;")
+    assert ex.emitted_key_cols is None
+    mat = Materialization(group_cols=["device"])
+    mat.task = _FakeTask(ex)
+    whole = list(ex.peek())
+    n = whole[0]["device"]
+    sel = _pull(f"SELECT * FROM v WHERE device = {n};")
+    got = serve_select_view(mat, sel)
+    assert got == views_mod.serve_parts([], whole, sel) and got
